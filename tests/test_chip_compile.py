@@ -1,0 +1,174 @@
+"""The serving path's device programs compile for a TPU v5e at real size.
+
+No chip is attached here: the TPU compiler is installed and compiles for a
+chip that is DESCRIBED (``jax.experimental.topologies``), which refuses what
+the chip's compiler would refuse — a kernel over its scoped VMEM, a program
+over 16 GB of HBM — at no chip time. Nothing runs, so nothing here says
+anything about answers or speed; ``chip_smoke.py`` is the proof on silicon.
+
+The topology is described inside a module-scoped fixture (never at import:
+only one process may load libtpu, and every xdist worker imports this file),
+the compiles run in the test's own process with the persistent cache off (a
+described-device executable cannot be read back from it), and every such
+test lives in THIS file so one worker owns the library.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+HBM_BYTES = 16 * 10 ** 9   # one v5e chip
+N, D, B, K = 1 << 20, 768, 256, 10
+CHUNK = 131072             # FlatIndexConfig.search_chunk_size default
+# graph-walk shapes (ISSUE 22 finding 1)
+GD, M0, EF, MAX_STEPS = 128, 64, 100, 256
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # whatever libtpu raises where it cannot load
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _fits(compiled) -> int:
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes + m.generated_code_size_in_bytes)
+    assert 0 < total < HBM_BYTES, m
+    return total
+
+
+def _flat_args(sharding, corpus_dtype=jnp.float32):
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=sharding)
+    return (s((B, D), jnp.float32), s((N, D), corpus_dtype),
+            s((N,), jnp.bool_), s((N,), jnp.float32))
+
+
+@pytest.mark.parametrize("metric", ["l2-squared", "cosine"])
+def test_flat_search_compiles_at_serving_defaults(one_chip, metric):
+    """The program a default FlatIndexConfig collection serves with: bf16
+    matmul, exact selection, 131072-row chunks over a 1M x 768 fp32 corpus."""
+    from weaviate_tpu.ops.distance import flat_search
+
+    q, corpus, valid, sqnorms = _flat_args(one_chip)
+    compiled = flat_search.lower(
+        q, corpus, k=K, metric=metric, valid_mask=valid,
+        corpus_sqnorms=sqnorms if metric == "l2-squared" else None,
+        chunk_size=CHUNK, precision="bf16", approx_recall=0.0).compile()
+    # the corpus is an argument, not a temporary: >= 3.2 GB resident
+    assert compiled.memory_analysis().argument_size_in_bytes >= N * D * 4
+    _fits(compiled)
+
+
+@pytest.mark.parametrize("corpus_dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_pallas_flat_topk_compiles(one_chip, corpus_dtype):
+    """fp32 is what the serving store hands the kernel (index/store.py keeps
+    float32): a 2048-row fp32 block asked for 17.35 MB of the 16 MB scoped
+    VMEM until the block choice learned the corpus itemsize."""
+    from weaviate_tpu.ops.pallas_flat import bucket_live, pallas_flat_topk
+
+    q, corpus, valid, sqnorms = _flat_args(one_chip, corpus_dtype)
+    mask = jax.ShapeDtypeStruct((N,), jnp.float32, sharding=one_chip)
+    compiled = pallas_flat_topk.lower(
+        q, corpus, sqnorms, mask, k=K, chunk_size=CHUNK,
+        live_rows=bucket_live(N)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+def _graph_args(sharding):
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=sharding)
+    levels, slots, m_upper = 3, N // 32, M0 // 2
+    return dict(
+        queries=s((B, GD), jnp.float32),
+        adjacency=s((N, M0), jnp.int32),
+        present=s((N,), jnp.bool_),
+        eps=s((B,), jnp.int32),
+        upper_adj=s((levels, slots, m_upper), jnp.int32),
+        upper_slots=s((levels, N), jnp.int32),
+    ), s
+
+
+@pytest.mark.parametrize("backend", ["raw", "sq"])
+def test_fused_graph_walk_compiles(one_chip, backend):
+    """The one-dispatch HNSW walk (descent + layer-0 beam) over a 1M-node
+    graph, on the raw corpus and on SQ code planes."""
+    from weaviate_tpu.ops.device_beam import RawScorer, SQScorer, \
+        _fused_search
+
+    g, s = _graph_args(one_chip)
+    if backend == "raw":
+        scorer = RawScorer("l2-squared", "bf16")
+        operands = (s((N, GD), jnp.float32),)
+    else:
+        scorer = SQScorer("l2-squared")
+        operands = (s((N, GD), jnp.uint8), s((N,), jnp.float32),
+                    s((), jnp.float32), s((), jnp.float32))
+    compiled = _fused_search.lower(
+        scorer, g["queries"], operands, g["adjacency"], g["present"],
+        g["eps"], g["upper_adj"], g["upper_slots"], ef=EF,
+        max_steps=MAX_STEPS).compile()
+    _fits(compiled)
+
+
+def test_gather_distance_compiles(one_chip):
+    """The host-driven walk's per-hop program: [256, 256] candidate ids
+    gathered from and scored against a 1M x 128 corpus."""
+    from weaviate_tpu.ops.distance import gather_distance
+
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = jax.jit(
+        gather_distance, static_argnames=("metric", "precision")).lower(
+        s((B, GD), jnp.float32), s((N, GD), jnp.float32),
+        s((B, 256), jnp.int32), metric="l2-squared",
+        precision="bf16").compile()
+    _fits(compiled)
+
+
+def test_mesh_flat_search_compiles_on_four_chips(topo):
+    """``mesh_flat_topk``'s program on the 2x2 host: corpus rows sharded
+    over four chips, per-chip chunked scan, all_gather top-k merge."""
+    from weaviate_tpu.parallel.mesh import SHARD_AXIS
+    from weaviate_tpu.parallel.sharded_search import _sharded_flat_search_jit
+
+    mesh = Mesh(np.array(topo.devices[:4]), (SHARD_AXIS,))
+    row = NamedSharding(mesh, P(SHARD_AXIS, None))
+    flat = NamedSharding(mesh, P(SHARD_AXIS))
+    repl = NamedSharding(mesh, P(None, None))
+    compiled = _sharded_flat_search_jit.lower(
+        jax.ShapeDtypeStruct((N, D), jnp.float32, sharding=row),
+        jax.ShapeDtypeStruct((N,), jnp.bool_, sharding=flat),
+        jax.ShapeDtypeStruct((B, D), jnp.float32, sharding=repl),
+        k=K, metric="l2-squared", mesh=mesh, precision="bf16",
+        sqnorms=jax.ShapeDtypeStruct((N,), jnp.float32, sharding=flat),
+        chunk_size=CHUNK, approx_recall=0.0).compile()
+    # memory_analysis is per device: each chip holds a quarter of the rows
+    per_chip = compiled.memory_analysis().argument_size_in_bytes
+    assert N * D * 4 // 4 <= per_chip < N * D * 4 // 2
+    assert "all-gather" in compiled.as_text()
+    _fits(compiled)

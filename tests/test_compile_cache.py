@@ -33,7 +33,11 @@ def _compile_observations() -> int:
 
 
 @pytest.fixture(autouse=True)
-def _clean_state():
+def _clean_state(monkeypatch):
+    # the suite may run where a harness placed jax's cache from outside;
+    # these tests pin the unplaced behavior (and the one that pins the
+    # placed behavior sets the variable for its own child)
+    monkeypatch.delenv(compile_cache.ENV_JAX_DIR, raising=False)
     compile_cache.reset_for_tests()
     prewarm.reset_for_tests()
     devtime.reset()
@@ -94,6 +98,51 @@ class TestCompileCache:
                 tmp_path / "knob")
         finally:
             COMPILE_CACHE_DIR.clear_override()
+
+    def test_jax_env_dir_is_used_as_is(self, tmp_path):
+        """JAX_COMPILATION_CACHE_DIR places the cache from outside: the
+        server's configure() call writes entries DIRECTLY there — no
+        keyed sub-directory, no other directory set in code."""
+        placed = tmp_path / "placed"
+        child = (
+            "import json, os, jax, jax.numpy as jnp\n"
+            "from weaviate_tpu import server\n"
+            "from weaviate_tpu.utils import compile_cache\n"
+            "used = compile_cache.configure(\n"
+            "    compile_cache.resolve_base_dir()\n"
+            "    or server.DEFAULT_COMPILE_CACHE_DIR)\n"
+            "jax.jit(lambda x: x * 3 + 1)(jnp.ones((5,))).block_until_ready()\n"
+            "print(json.dumps({'used': used,\n"
+            "    'jax': jax.config.jax_compilation_cache_dir,\n"
+            "    'stats': compile_cache.stats()}))\n")
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   JAX_COMPILATION_CACHE_DIR=str(placed),
+                   PYTHONPATH=str(REPO))
+        out = subprocess.run([sys.executable, "-c", child], env=env,
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
+        got = json.loads(out.stdout.strip().splitlines()[-1])
+        assert got["used"] == got["jax"] == got["stats"]["dir"] == str(
+            placed)
+        assert got["stats"]["misses"] >= 1
+        names = os.listdir(placed)
+        assert names and all(
+            os.path.isfile(placed / n) for n in names), names
+
+    def test_server_default_is_fixed_dir_in_checkout(self):
+        """Unplaced, the server's cache base is <checkout>/.jax_cache —
+        never under the data directory, and no component of the keyed
+        path is a temporary name, a pid or a time."""
+        from weaviate_tpu import server
+
+        assert compile_cache.resolve_base_dir() is None
+        assert server.DEFAULT_COMPILE_CACHE_DIR == str(REPO / ".jax_cache")
+        keyed = compile_cache.keyed_dir(server.DEFAULT_COMPILE_CACHE_DIR)
+        assert os.path.dirname(keyed) == str(REPO / ".jax_cache")
+        import re
+
+        assert re.fullmatch(r"jax[\d.]+\w*-jaxlib[\d.]+\w*-cpu-d\d+",
+                            os.path.basename(keyed)), keyed
 
     def test_configure_after_first_compile_engages_cache(self, tmp_path):
         """jax latches its cache check on the FIRST compile of the

@@ -98,9 +98,10 @@ def test_rejects_non_divisible_chunk():
                          chunk_size=512, interpret=True)
 
 
-def test_failure_latches_and_falls_back(monkeypatch):
-    """A backend that cannot lower the kernel disables it once; the
-    serving path keeps answering from the XLA fallback."""
+def test_failure_of_enabled_kernel_propagates(monkeypatch):
+    """An ENABLED kernel that cannot lower raises into the request: the
+    serving path never gives way to the XLA path in silence (a chip run
+    that meant to exercise the kernel must not pass without it)."""
     import tempfile
 
     import weaviate_tpu.ops.pallas_flat as pf
@@ -113,7 +114,6 @@ def test_failure_latches_and_falls_back(monkeypatch):
     from weaviate_tpu.storage.objects import StorageObject
 
     monkeypatch.setenv("WEAVIATE_TPU_PALLAS_FLAT", "on")
-    monkeypatch.setattr(pf, "_disabled", False)
     calls = []
 
     def boom(*a, **kw):
@@ -136,9 +136,13 @@ def test_failure_latches_and_falls_back(monkeypatch):
     # mesh path before the pallas hook; pallas serves single-device
     idx = next(iter(col._shards.values()))._vector_indexes[""]
     idx.store.mesh = None
-    for _ in range(3):
-        hits = col.vector_search(vecs[5], k=2)
-        assert hits[0][0].properties["t"] == "d5"
-    assert len(calls) == 1  # latched after the first failure
-    assert pf._disabled
+    for _ in range(2):
+        with pytest.raises(RuntimeError, match="no pallas lowering"):
+            col.vector_search(vecs[5], k=2)
+    assert len(calls) == 2  # no latch: every request meets the kernel
+    # switched off, the same collection answers from the XLA path
+    monkeypatch.setenv("WEAVIATE_TPU_PALLAS_FLAT", "off")
+    hits = col.vector_search(vecs[5], k=2)
+    assert hits[0][0].properties["t"] == "d5"
+    assert len(calls) == 2
     db.close()

@@ -67,3 +67,53 @@ def test_distributed_step_ingest_then_search(mesh):
     # only the 8 inserted ids are live
     live = np.asarray(jax.device_get(vj)).sum()
     assert live == m
+
+
+def test_make_mesh_raises_when_the_platform_has_too_few_devices():
+    """No substitute devices: a mesh stands on the devices asked for."""
+    have = len(jax.devices())
+    with pytest.raises(ValueError, match=f"has {have}"):
+        make_mesh(have + 1)
+    assert make_mesh().devices.size == have
+
+
+def test_default_mesh_lets_a_failing_platform_raise(monkeypatch):
+    """A platform that does not come up is an error at the first use of
+    the mesh — never "single-host mode" on whatever is left."""
+    from weaviate_tpu.parallel import runtime
+
+    def boom():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.delenv("WEAVIATE_TPU_MESH", raising=False)
+    monkeypatch.setattr(jax, "devices", boom)
+    runtime.reset()
+    try:
+        with pytest.raises(RuntimeError, match="Unable to initialize"):
+            runtime.default_mesh()
+    finally:
+        runtime.reset()
+
+
+def test_device_report_splits_bytes_by_shard(mesh):
+    """The /v1/nodes device block: platform, kind, count and per-device
+    bytes — on the CPU backend summed from the shards of live arrays, so a
+    row-sharded corpus shows up as equal shares, not on device 0."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from weaviate_tpu.parallel.runtime import device_report
+
+    before = device_report()
+    assert before["platform"] == "cpu" and before["count"] == len(
+        jax.devices())
+    assert before["kind"] == jax.devices()[0].device_kind
+    rows = jax.device_put(
+        np.zeros((8 * 1024, 64), np.float32),
+        NamedSharding(mesh, P("shard", None)))
+    one = jax.device_put(np.zeros((1024, 64), np.float32), jax.devices()[0])
+    after = device_report()
+    grew = [a - b for a, b in zip(after["bytes_in_use"],
+                                  before["bytes_in_use"])]
+    share = 1024 * 64 * 4
+    assert grew[0] == 2 * share and grew[1:] == [share] * 7
+    del rows, one

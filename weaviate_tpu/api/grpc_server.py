@@ -464,7 +464,12 @@ class GrpcClient:
         self._metadata = (
             [("authorization", f"Bearer {api_key}")] if api_key else None)
 
-    def _call(self, name: str, request, reply_cls):
+    def _call(self, name: str, request, reply_cls,
+              timeout: Optional[float] = None):
+        """``timeout`` (seconds) becomes the call's gRPC deadline, which
+        the server takes as the request's budget in place of its 30 s
+        default — what a client sends with a request it knows will
+        compile (the first search of a new shape)."""
         m = self._methods.get(name)
         if m is None:
             m = self.channel.unary_unary(
@@ -473,13 +478,17 @@ class GrpcClient:
                 response_deserializer=reply_cls.FromString,
             )
             self._methods[name] = m
-        return m(request, metadata=self._metadata)
+        return m(request, metadata=self._metadata, timeout=timeout)
 
-    def search(self, request: pb.SearchRequest) -> pb.SearchReply:
-        return self._call("Search", request, pb.SearchReply)
+    def search(self, request: pb.SearchRequest,
+               timeout: Optional[float] = None) -> pb.SearchReply:
+        return self._call("Search", request, pb.SearchReply, timeout)
 
-    def batch_objects(self, request: pb.BatchObjectsRequest) -> pb.BatchObjectsReply:
-        return self._call("BatchObjects", request, pb.BatchObjectsReply)
+    def batch_objects(self, request: pb.BatchObjectsRequest,
+                      timeout: Optional[float] = None
+                      ) -> pb.BatchObjectsReply:
+        return self._call("BatchObjects", request, pb.BatchObjectsReply,
+                          timeout)
 
     def batch_delete(self, request: pb.BatchDeleteRequest) -> pb.BatchDeleteReply:
         return self._call("BatchDelete", request, pb.BatchDeleteReply)

@@ -1656,6 +1656,8 @@ class RestAPI:
         return _json_response(full)
 
     def _nodes_dict(self) -> dict:
+        from weaviate_tpu.parallel.runtime import device_report
+
         shards = []
         total = 0
         for name in self.db.collections():
@@ -1673,6 +1675,7 @@ class RestAPI:
             "version": __version__,
             "stats": {"objectCount": total, "shardCount": len(shards)},
             "shards": shards,
+            "device": device_report(),
         }
         if self.cluster is None:
             return {"nodes": [local]}

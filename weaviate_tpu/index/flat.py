@@ -142,7 +142,7 @@ class FlatIndex(VectorIndex):
         from weaviate_tpu.ops import pallas_flat
 
         if (self.metric == "l2-squared" and sqnorms is not None
-                and pallas_flat.usable()
+                and pallas_flat.enabled()
                 and self.config.precision == "bf16"
                 and approx_recall > 0.0 and k <= 64):
             m = valid if allow is None else (valid & allow)
@@ -162,15 +162,14 @@ class FlatIndex(VectorIndex):
                 allow_n = int(np.count_nonzero(
                     np.asarray(allow_list, bool)))
                 live = max(1, live + allow_n - cap)
-            if pallas_flat.fits(cap, csz):
-                out = pallas_flat.try_flat_topk(
+            if pallas_flat.fits(cap, csz,
+                                corpus.shape[1] * corpus.dtype.itemsize):
+                d, ids = pallas_flat.pallas_flat_topk(
                     qj, corpus, sqnorms, m, k, chunk_size=csz,
                     live_rows=pallas_flat.bucket_live(live))
-                if out is not None:
-                    d, ids = out
-                    return SearchResult(
-                        # graftlint: allow[host-sync-in-hot-path] reason=final top-k materialization
-                        ids=np.asarray(ids), dists=np.asarray(d))
+                return SearchResult(
+                    # graftlint: allow[host-sync-in-hot-path] reason=final top-k materialization
+                    ids=np.asarray(ids), dists=np.asarray(d))
         d, ids = flat_search(
             qj,
             corpus,

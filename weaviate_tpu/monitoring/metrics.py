@@ -211,9 +211,10 @@ VECTOR_INDEX_SIZE = REGISTRY.gauge(
     "weaviate_tpu_vector_index_size", "vectors per collection/shard")
 ASYNC_QUEUE_SIZE = REGISTRY.gauge(
     "weaviate_tpu_vector_index_queue_size", "pending async-index vectors")
-NATIVE_BM25_UNAVAILABLE = REGISTRY.gauge(
-    "weaviate_tpu_native_bm25_unavailable",
-    "1 when keyword search degraded to the dense python path")
+NATIVE_LIBRARY = REGISTRY.gauge(
+    "weaviate_tpu_native_library",
+    "1 per loaded native component by implementation in use: "
+    "impl=native (C++ built on this machine) or impl=python (its twin)")
 DIMENSIONS_SUM = REGISTRY.gauge(
     "weaviate_tpu_vector_dimensions_sum",
     "stored vector dimensions per collection (count x dims)")

@@ -30,8 +30,8 @@ METRICS = ("l2-squared", "dot", "cosine", "manhattan", "hamming")
 # ~3.4e38; we stay well below so arithmetic on sentinels can't overflow to inf
 # (inf - inf = nan would poison top-k merges). A plain Python float, NOT a
 # jnp scalar: a device constant here would initialize the default backend at
-# import time (and hang the whole process when the remote TPU runtime is
-# wedged — the CPU-mesh fallback must be reachable without touching it).
+# import time, and whichever process imports the package would take the chip
+# (tests/test_import_no_backend.py).
 MASK_DISTANCE = 1e30
 
 

@@ -12,8 +12,9 @@ bandwidth ceiling at large B.
 Gated OFF by default (``WEAVIATE_TPU_PALLAS_FLAT=on`` to enable in the
 serving path): semantics are validated in interpret mode on CPU, but the
 compiled kernel must prove itself against ``approx_min_k`` on real
-hardware before it takes over the hot path. ``flat.py`` falls back to
-the XLA path on any failure.
+hardware before it takes over the hot path. An ENABLED kernel that
+fails to lower or run raises into the request — it never gives way to
+the XLA path in silence.
 
 Selection inside the kernel is bucketed, the same shape as
 ``approx_min_k``'s PartialReduce: the [B, C] block folds into C/FOLD
@@ -27,11 +28,13 @@ semantics, and the serving path only routes here when approximate
 selection is permitted). This keeps the VPU selection cost ~FOLD× below
 full-width extraction, leaving the kernel HBM-bound on the corpus read.
 
-The corpus is tiled into VMEM-sized blocks of ``_BLOCK_LADDER`` rows
-(~3 MB bf16 at 2048x768) — the r3 version mapped the caller's whole
-131072-row chunk into one VMEM block (~200 MB), which the TPU compiler
-rightly refused; interpret mode on CPU never sees VMEM and validated it
-anyway. Real-silicon compile is the only proof that counts.
+The corpus is tiled into VMEM-sized blocks of ``_BLOCK_LADDER`` rows,
+capped at ``_BLOCK_BYTES`` per buffer at the corpus's OWN dtype (2048
+rows of 768-d bf16, 1024 of the fp32 the serving store holds): the
+block is double-buffered and cast in-kernel, and a 2048x768 fp32 block
+asks for 17.35 MB of the 16 MB scoped VMEM. Interpret mode on CPU never
+sees VMEM; ``tests/test_chip_compile.py`` compiles both dtypes for a
+described v5e.
 """
 
 from __future__ import annotations
@@ -58,15 +61,6 @@ def enabled() -> bool:
         platform=jax.default_backend())
 
 
-# latched after the first trace/compile failure: a backend that cannot
-# lower the kernel must not pay a full trace + exception unwind per query
-_disabled = False
-
-
-def usable() -> bool:
-    return enabled() and not _disabled
-
-
 def bucket_live(live: int) -> int:
     """Coarse power-of-4 bucket of a live-row count. Fold sizing only
     needs the order of magnitude of the candidate population, and the
@@ -77,28 +71,6 @@ def bucket_live(live: int) -> int:
     while b * 4 <= max(1, live):
         b *= 4
     return b
-
-
-def try_flat_topk(queries, corpus, corpus_sqnorms, mask, k,
-                  chunk_size, live_rows=None):
-    """pallas_flat_topk with one-shot failure latching: on the first
-    error the kernel logs and disables itself for the process; callers
-    fall back to the XLA path with no per-query retry tax."""
-    global _disabled
-    if _disabled:
-        return None
-    try:
-        return pallas_flat_topk(queries, corpus, corpus_sqnorms, mask,
-                                k, chunk_size=chunk_size,
-                                live_rows=live_rows)
-    except Exception as e:
-        _disabled = True
-        import logging
-
-        logging.getLogger("weaviate_tpu.pallas").warning(
-            "pallas flat kernel disabled after failure "
-            "(falling back to the XLA path): %s", e)
-        return None
 
 
 def _kernel(q_ref, c_ref, norms_ref, mask_ref, vals_ref, ids_ref, *,
@@ -147,26 +119,31 @@ def _kernel(q_ref, c_ref, norms_ref, mask_ref, vals_ref, ids_ref, *,
     ids_ref[0] = jnp.stack(gs, axis=1)
 
 
-# VMEM block rows, largest-first: 2048x768 bf16 is ~3 MB/buffer, well
-# inside VMEM with double buffering; the ladder walks down for small or
-# oddly-sized (test-scale) corpora
+# VMEM block rows, largest-first; the ladder walks down for small or
+# oddly-sized (test-scale) corpora and for wide rows: one corpus buffer
+# holds at most _BLOCK_BYTES (2048x768 bf16) — double-buffered, plus the
+# in-kernel bf16 copy and the [B, block] score temporaries at B=256,
+# that stays inside the 16 MiB of scoped VMEM
 _BLOCK_LADDER = (2048, 1024, 512, 256, 128)
+_BLOCK_BYTES = 2048 * 768 * 2
 
 
-def _pick_block(n: int, chunk_size: int) -> int:
+def _pick_block(n: int, chunk_size: int, row_bytes: int) -> int:
     for blk in _BLOCK_LADDER:
-        if blk <= chunk_size and n % blk == 0:
+        if (blk <= chunk_size and n % blk == 0
+                and blk * row_bytes <= _BLOCK_BYTES):
             return blk
     raise ValueError(
-        f"corpus rows {n} have no VMEM block divisor <= chunk {chunk_size}")
+        f"corpus rows {n} x {row_bytes} B have no VMEM block divisor "
+        f"<= chunk {chunk_size}")
 
 
-def fits(n: int, chunk_size: int) -> bool:
-    """Whether a corpus of ``n`` rows satisfies the kernel's shape
-    contract — the serving-path gate (``index/flat.py``) must ask THIS,
-    not the pre-rewrite ``n % chunk_size == 0`` rule."""
+def fits(n: int, chunk_size: int, row_bytes: int) -> bool:
+    """Whether a corpus of ``n`` rows of ``row_bytes`` each satisfies the
+    kernel's shape contract — the serving-path gate (``index/flat.py``)
+    must ask THIS, not the pre-rewrite ``n % chunk_size == 0`` rule."""
     try:
-        _pick_block(n, chunk_size)
+        _pick_block(n, chunk_size, row_bytes)
         return True
     except ValueError:
         return False
@@ -199,17 +176,21 @@ def pallas_flat_topk(
 
     n, d_dim = corpus.shape
     b = queries.shape[0]
-    block = _pick_block(n, chunk_size)
+    block = _pick_block(n, chunk_size, d_dim * corpus.dtype.itemsize)
     grid = n // block
     # fold width scales with the live candidate count so the
     # bucket-collision loss is bounded: expected missed candidates
     # ~ C(k,2)*(fold-1)/live, so capping fold at live/(64*k^2) keeps the
     # loss under ~1% at any scale — tiny (test-sized) or heavily masked
     # corpora degrade to fold=1, i.e. exact full-width extraction;
-    # 1M x k=10 serving gets the full 16x VPU saving
+    # 1M x k=10 serving gets the full 16x VPU saving at a 2048-row
+    # block. The [B, fold, block // fold] view must keep whole 128-lane
+    # rows (Mosaic refuses the reshape below that: "unsupported shape
+    # cast"), so the 1024-row block of an fp32 corpus folds at most 8x
     live = live_rows if live_rows else n
     fold = 16
-    while fold > 1 and (block // fold < k or fold * 64 * k * k > live):
+    while fold > 1 and (block // fold < max(k, 128)
+                        or fold * 64 * k * k > live):
         fold //= 2
     if block // fold < k:
         raise ValueError(f"k={k} exceeds block {block} bucket count")

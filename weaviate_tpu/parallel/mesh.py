@@ -9,9 +9,6 @@ replace the clusterapi scatter-gather.
 
 from __future__ import annotations
 
-import logging
-import sys
-import threading
 from typing import Optional
 
 import jax
@@ -37,58 +34,15 @@ def shard_of(ids, capacity: int, n_shards: int):
 
 
 def make_mesh(n_devices: Optional[int] = None, axis: str = SHARD_AXIS) -> Mesh:
-    """Build a 1-D mesh over ``n_devices`` devices.
-
-    When the default platform cannot supply ``n_devices`` (the usual case in
-    this environment: one real TPU chip, or a broken TPU runtime), fall back
-    to the virtual CPU platform (``--xla_force_host_platform_device_count``)
-    so multi-chip sharding can be validated without N real chips.
+    """Build a 1-D mesh over the first ``n_devices`` devices of the default
+    platform (all of them when ``None``). Raises when the platform has
+    fewer: a mesh never stands on devices other than the ones asked for.
     """
-    devices = _probe_default_devices()
-    if n_devices is not None and n_devices > len(devices):
-        cpu = jax.devices("cpu")
-        if n_devices > len(cpu):
-            raise ValueError(
-                f"requested {n_devices} devices; default platform has "
-                f"{len(devices)}, cpu has {len(cpu)} (set "
-                f"--xla_force_host_platform_device_count={n_devices})"
-            )
-        # Loud, not silent: a CPU mesh standing in for real chips must never
-        # be mistaken for a multichip TPU run.
-        print(
-            f"[weaviate_tpu] make_mesh: default platform has only "
-            f"{len(devices)} device(s); using {n_devices} virtual CPU devices",
-            file=sys.stderr,
-        )
-        devices = cpu
+    devices = jax.devices()
     if n_devices is not None:
+        if n_devices > len(devices):
+            raise ValueError(
+                f"requested {n_devices} devices; platform "
+                f"{devices[0].platform!r} has {len(devices)}")
         devices = devices[:n_devices]
     return Mesh(np.array(devices), (axis,))
-
-
-def _probe_default_devices(timeout: float = 60.0) -> list:
-    """jax.devices() guarded by a timeout: a wedged remote TPU runtime must
-    degrade to the CPU fallback, not hang the whole dry run."""
-    out: list = []
-
-    def probe():
-        try:
-            out.append(jax.devices())
-        except Exception:
-            # no usable platform (CPU-only image, wedged PJRT plugin):
-            # expected degradation, logged for mesh-sizing post-mortems
-            logging.getLogger("weaviate_tpu.mesh").info(
-                "default platform probe failed; no mesh", exc_info=True)
-            out.append([])
-
-    t = threading.Thread(target=probe, daemon=True)
-    t.start()
-    t.join(timeout)
-    if not out:
-        print(
-            "[weaviate_tpu] make_mesh: default platform probe timed out "
-            f"after {timeout:.0f}s; treating as unavailable",
-            file=sys.stderr,
-        )
-        return []
-    return out[0]

@@ -13,7 +13,7 @@ Kill switch: ``WEAVIATE_TPU_MESH=off`` forces single-device mode.
 
 from __future__ import annotations
 
-import logging
+import math
 import os
 import threading
 from typing import Optional
@@ -42,14 +42,7 @@ def default_mesh() -> Optional[Mesh]:
 
         from weaviate_tpu.parallel.mesh import make_mesh
 
-        try:
-            devices = jax.devices()
-        except Exception:
-            # a wedged PJRT plugin can raise anything (see mesh.py probe);
-            # any failure here means single-host mode, audibly
-            logging.getLogger("weaviate_tpu.mesh").info(
-                "jax.devices() failed; running single-host", exc_info=True)
-            devices = []
+        devices = jax.devices()
         if len(devices) > 1:
             _mesh = make_mesh(len(devices))
         else:
@@ -72,3 +65,30 @@ def reset() -> None:
     with _lock:
         _mesh = None
         _resolved = False
+
+
+def device_report() -> dict:
+    """What this process runs on, for the boot line and ``/v1/nodes``:
+    platform, device kind and count, and the bytes each device holds —
+    from ``memory_stats()`` where the backend reports it, else summed
+    from the shards of ``jax.live_arrays()`` (the CPU backend)."""
+    import jax
+
+    devices = jax.devices()
+    stats = [d.memory_stats() for d in devices]
+    if all(st and "bytes_in_use" in st for st in stats):
+        in_use = [int(st["bytes_in_use"]) for st in stats]
+    else:
+        held = {d.id: 0 for d in devices}
+        for arr in jax.live_arrays():
+            per_device = (math.prod(arr.sharding.shard_shape(arr.shape))
+                          * arr.dtype.itemsize)
+            for d in arr.sharding.device_set:
+                held[d.id] += per_device
+        in_use = [held[d.id] for d in devices]
+    return {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+        "bytes_in_use": in_use,
+    }

@@ -8,6 +8,10 @@ Reference: ``cmd/weaviate-server/main.go`` + the composition root
 
 Env vars (reference names where they exist):
   PERSISTENCE_DATA_PATH   data directory (default ./weaviate-tpu-data)
+  JAX_PLATFORMS           jax's own: "tpu" makes a chip that does not come
+                          up an error (unset, jax falls back to the CPU)
+  JAX_COMPILATION_CACHE_DIR  jax's own: the persistent compile cache is
+                          exactly this directory; unset, <checkout>/.jax_cache
   DEFAULT_HTTP_PORT       REST port (default 8080)
   GRPC_PORT               gRPC port (default 50051; empty string disables)
   AUTHENTICATION_APIKEY_ENABLED        "true" to require API keys
@@ -24,6 +28,9 @@ import os
 import signal
 import sys
 import threading
+
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
 
 
 def config_from_env() -> dict:
@@ -70,14 +77,22 @@ def main() -> int:
     cfg = config_from_env()
     # persistent compilation cache BEFORE anything can jit (DB open may
     # compile during checkpoint replay): restarted nodes deserialize
-    # yesterday's executables instead of re-paying XLA (ROADMAP item 3,
-    # docs/compile_cache.md). Default base under the data path; env /
-    # runtime knob / kill switch override inside configure().
+    # yesterday's executables instead of re-paying XLA
+    # (docs/compile_cache.md). JAX_COMPILATION_CACHE_DIR, where set, is
+    # used as is; otherwise the default base is one FIXED directory in
+    # the checkout — the path is part of what makes a later run hit, so
+    # it must not move with the data directory.
     from weaviate_tpu.utils import compile_cache
 
     compile_cache.configure(
-        compile_cache.resolve_base_dir()
-        or os.path.join(cfg["data_path"], "compile_cache"))
+        compile_cache.resolve_base_dir() or DEFAULT_COMPILE_CACHE_DIR)
+    # say what this process runs on — and fail here, before the data
+    # directory is touched, when the platform asked for does not come up
+    from weaviate_tpu.parallel.runtime import device_report
+
+    dev = device_report()
+    print(f"devices: {dev['count']} x {dev['kind']} "
+          f"(platform {dev['platform']})", file=sys.stderr)
     db = DB(cfg["data_path"])
     oidc = None
     if cfg["oidc_enabled"]:

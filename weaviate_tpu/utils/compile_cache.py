@@ -9,18 +9,22 @@ compilation cache to a node-local directory so a restarted process
 DESERIALIZES yesterday's executables off disk instead of re-lowering and
 re-optimizing them — seconds of XLA time become a disk read.
 
-Keying. JAX's own cache key already folds in the program HLO, compile
-options, and the backend version; on top of that the cache DIRECTORY is
-keyed on (jax version, jaxlib version, backend platform, device count),
-so an image upgrade or a topology change (v5e-4 -> v5e-8 reslice)
-naturally lands in a fresh keyspace and stale executables are never even
-consulted. Invalidation is directory removal.
-
-Resolution order for the base directory: explicit ``configure()`` arg >
+Where the cache lives. ``JAX_COMPILATION_CACHE_DIR`` is JAX's own
+setting and wins outright: when it is set this module leaves
+``jax_compilation_cache_dir`` exactly as JAX read it — no override, no
+sub-directory — so an operator (or a harness that shares one cache
+between runs) places the cache from outside. Otherwise the base
+directory resolves as explicit ``configure()`` arg >
 ``WEAVIATE_TPU_COMPILE_CACHE_DIR`` env > the ``compile_cache_dir``
-runtime knob > disabled. ``WEAVIATE_TPU_COMPILE_CACHE=off`` is the kill
-switch regardless. Absent any of these the layer is inert — test
-processes and embedded uses pay zero behavior change.
+runtime knob > disabled, and the cache goes to a sub-directory of it
+keyed on (jax version, jaxlib version, backend platform, device count),
+so an image upgrade or a topology change (v5e-4 -> v5e-8 reslice) lands
+in a fresh keyspace and invalidation is directory removal. The server
+passes ``<checkout>/.jax_cache`` as its base (``server.py``): a fixed
+path, because the path is part of what makes a later run hit.
+``WEAVIATE_TPU_COMPILE_CACHE=off`` is the kill switch regardless. Absent
+all of these the layer is inert — test processes and embedded uses pay
+zero behavior change.
 
 Observability: a jax monitoring listener counts cache hits (disk
 deserialize) and misses (true compile) into
@@ -40,13 +44,14 @@ from typing import Optional
 logger = logging.getLogger("weaviate_tpu.compile_cache")
 
 ENV_DIR = "WEAVIATE_TPU_COMPILE_CACHE_DIR"
+ENV_JAX_DIR = "JAX_COMPILATION_CACHE_DIR"
 ENV_SWITCH = "WEAVIATE_TPU_COMPILE_CACHE"
 
 _HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
 _lock = threading.Lock()
-_dir: Optional[str] = None  # resolved keyed directory once configured
+_dir: Optional[str] = None  # the directory in use once configured
 _hits = 0
 _misses = 0
 _listener_installed = False
@@ -109,37 +114,37 @@ def _unlatch_jax_cache() -> None:
     (``_cache``/``_cache_checked`` latch on the first compile), so a
     config update alone is a no-op once anything has compiled — the
     latch must be reset for (re)configuration to take effect."""
-    try:
-        from jax._src.compilation_cache import reset_cache
+    from jax._src.compilation_cache import reset_cache
 
-        reset_cache()
-    except Exception:
-        # private API: drift must degrade to the before-first-compile
-        # contract, audibly, never crash configuration
-        logger.warning("could not unlatch jax's compilation cache state"
-                       " — (re)configure only applies before the first"
-                       " compile", exc_info=True)
+    reset_cache()
 
 
 def configure(cache_dir: Optional[str] = None) -> Optional[str]:
-    """Wire the persistent cache; returns the keyed directory in use, or
-    None when the layer stays disabled. Idempotent; a second call with a
+    """Wire the persistent cache; returns the directory in use, or None
+    when the layer stays disabled. Idempotent; a second call with a
     different base re-points the cache (tests, operator re-config)."""
     global _dir, _listener_installed
-    base = resolve_base_dir(cache_dir)
-    if base is None:
+    if _switched_off():
         return None
     import jax
 
-    path = keyed_dir(base)
-    os.makedirs(path, exist_ok=True)
+    if os.environ.get(ENV_JAX_DIR):
+        # placed from outside: jax read the variable itself and this
+        # module sets no other directory
+        path = jax.config.jax_compilation_cache_dir
+    else:
+        base = resolve_base_dir(cache_dir)
+        if base is None:
+            return None
+        path = keyed_dir(base)
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
+        _unlatch_jax_cache()
     # cache EVERYTHING: the defaults skip sub-second compiles, but the
     # restart proof needs every program in a dispatch to hit (one missed
     # helper jit would classify the whole bracket as a compile)
-    jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    _unlatch_jax_cache()
     with _lock:
         _dir = path
         if not _listener_installed:
@@ -212,7 +217,7 @@ def reset_for_tests() -> None:
         _dir = None
         _hits = 0
         _misses = 0
-    if was is not None:
+    if was is not None and not os.environ.get(ENV_JAX_DIR):
         import jax
 
         jax.config.update("jax_compilation_cache_dir", None)

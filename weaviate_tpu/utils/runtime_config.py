@@ -185,8 +185,8 @@ TIERING_HBM_BUDGET = RUNTIME.register(
 # persistent compilation cache (utils/compile_cache.py): base directory
 # for the node-local keyed cache; "" = disabled unless the
 # WEAVIATE_TPU_COMPILE_CACHE_DIR env or an explicit configure() call
-# names one. The server's composition root defaults it under the data
-# path.
+# names one. The server's composition root defaults it to
+# <checkout>/.jax_cache; JAX_COMPILATION_CACHE_DIR, where set, wins.
 COMPILE_CACHE_DIR = RUNTIME.register("compile_cache_dir", "", cast=str)
 # shape-bucket prewarm driver (utils/prewarm.py): the pow2 row buckets
 # compiled per (shard, target vector) at boot / tenant promotion /
