@@ -1021,7 +1021,7 @@ class TestDiurnalRamp:
             assert sum(e["direction"] == "in" for e in done) >= 3
 
             # every decision is one trace; join and drain legs both ran
-            spans = TRACER.recent(limit=8192)
+            spans = TRACER.recent(limit=TRACER.max_spans)
             roots = {s["spanId"]: s for s in spans
                      if s["name"] == "autoscale.decide"}
             legs = {s["name"] for s in spans

@@ -48,6 +48,222 @@ def test_tracer_bounds_memory():
     assert len(tr.recent(limit=100)) == 10
 
 
+class _FakeAnnotation:
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+
+    def __exit__(self, exc_type, exc, tb):
+        self.log.append(("exit", self.name, exc_type))
+
+
+@pytest.mark.parametrize("rate,raises", [(1.0, False), (1.0, True),
+                                         (0.0, False)],
+                         ids=["sampled", "sampled_error", "unsampled"])
+def test_span_mirrors_itself_onto_the_profilers_clock(monkeypatch, rate,
+                                                      raises):
+    """A sampled span entered with ``with`` opens a TraceAnnotation of its
+    own name on its thread and closes it on exit (an exception included);
+    an unsampled one opens nothing and carries no ``cpu_ms``."""
+    from weaviate_tpu.monitoring import tracing
+
+    monkeypatch.setattr(_FakeAnnotation, "log", [])
+    monkeypatch.setattr(tracing, "TraceAnnotation", _FakeAnnotation)
+    tr = Tracer(sample_rate=rate)
+    created = tr.span("never.entered")      # no ``with``: nothing opens
+    try:
+        with tr.span("outer"):
+            with tr.span("inner"):
+                if raises:
+                    raise KeyError("x")
+    except KeyError:
+        pass
+    assert created.end_ns is None
+    if rate == 0.0:
+        assert _FakeAnnotation.log == [] and tr.recent() == []
+        return
+    exc = KeyError if raises else None
+    assert _FakeAnnotation.log == [
+        ("enter", "outer"), ("enter", "inner"),
+        ("exit", "inner", exc), ("exit", "outer", exc)]
+    spans = tr.recent()
+    assert [s["name"] for s in spans] == ["inner", "outer"]
+    assert tr.open_span_ids() == set()
+
+
+@pytest.mark.parametrize("cheap", [True, False],
+                         ids=["cheap_clock", "dear_clock"])
+def test_span_cpu_ms_counts_the_threads_own_work(monkeypatch, cheap):
+    """``cpu_ms`` where the thread's CPU clock is cheap; where it is not
+    (the sealed machines that hold the chip: 5.9 us a call, 10 ms steps)
+    the clock is never read and the attribute is absent."""
+    import time
+    import types
+
+    from weaviate_tpu.monitoring import tracing
+
+    reads = []
+
+    def thread_time_ns():
+        reads.append(1)
+        return time.thread_time_ns()
+
+    monkeypatch.setattr(tracing, "THREAD_CLOCK", cheap)
+    monkeypatch.setattr(tracing, "time", types.SimpleNamespace(
+        time_ns=time.time_ns, thread_time_ns=thread_time_ns))
+    tr = Tracer()
+    with tr.span("idle"):
+        pass
+    with tr.span("busy"):
+        sum(i * i for i in range(200_000))
+    idle, busy = tr.recent()
+    if cheap:
+        assert len(reads) == 4
+        assert busy["attributes"]["cpu_ms"] > \
+            idle["attributes"]["cpu_ms"] >= 0
+    else:
+        assert reads == []
+        assert "cpu_ms" not in idle["attributes"]
+        assert "cpu_ms" not in busy["attributes"]
+
+
+@pytest.mark.parametrize("costs_ns,cheap", [
+    ([300] * 50, True),                     # a plain Linux host
+    ([5900] * 50, False),                   # the chip's sealed machine
+    ([300] * 10 + [90_000] * 30 + [300] * 10, True),    # preempted rounds
+], ids=["cheap", "dear", "preempted"])
+def test_thread_clock_probe_goes_by_its_cheapest_round(monkeypatch,
+                                                       costs_ns, cheap):
+    import types
+
+    from weaviate_tpu.monitoring import tracing
+
+    clock = {"now": 0, "calls": 0}
+
+    def thread_time_ns():
+        clock["now"] += costs_ns[clock["calls"]]
+        clock["calls"] += 1
+        return 0
+
+    monkeypatch.setattr(tracing, "time", types.SimpleNamespace(
+        perf_counter_ns=lambda: clock["now"],
+        thread_time_ns=thread_time_ns))
+    assert tracing._thread_clock_is_cheap() is cheap
+    assert clock["calls"] == 50
+
+
+def test_child_joins_a_trace_but_never_starts_one():
+    """``Tracer.child``: the deep layer boundaries. Under an active span a
+    real child; with none, nothing is recorded, attributes are taken, the
+    thread's current span stays None and a ``span`` nested inside mints its
+    own root as it always did."""
+    from weaviate_tpu.monitoring.tracing import current_span
+
+    tr = Tracer()
+    with tr.child("index.search", k=3) as orphan:
+        orphan.set(rows=1)
+        assert not orphan.sampled and current_span() is None
+        with tr.span("ingest.drain"):
+            pass
+    assert [s["name"] for s in tr.recent()] == ["ingest.drain"]
+    assert tr.recent()[0]["parentSpanId"] is None
+    with tr.span("grpc.Search") as root:
+        with tr.child("index.search", k=3) as kid:
+            assert kid.sampled and kid.parent_id == root.span_id
+    with Tracer(sample_rate=0.0).span("grpc.Search"):
+        with tr.child("index.search") as kid:
+            assert not kid.sampled
+    assert [s["name"] for s in tr.recent()] == [
+        "ingest.drain", "index.search", "grpc.Search"]
+
+
+def test_lockless_span_path_loses_nothing_under_contention():
+    """The span path takes no lock: finished spans land by ``deque.append``,
+    open ids by ``set.add`` / ``discard``, readers snapshot with
+    ``list(deque)``. More writers than cores, a switch interval of
+    microseconds and a reader hammering every read path: no span lost,
+    none twice, no id left open, no reader error."""
+    import sys
+    import threading
+
+    from weaviate_tpu.monitoring.metrics import TRACE_SPANS
+
+    writers, each = 24, 400
+    tr = Tracer(max_spans=writers * each * 2)
+    before = TRACE_SPANS.value(name="stress.child")
+    errors, stop = [], threading.Event()
+
+    def write():
+        try:
+            for _ in range(each):
+                with tr.span("stress.root", parent=None):
+                    with tr.span("stress.child") as c:
+                        c.set(a=1)
+        except Exception as e:  # noqa: BLE001 — reported below
+            errors.append(repr(e))
+
+    def read():
+        try:
+            while not stop.is_set():
+                tr.traces(limit=50)
+                tr.recent(limit=50)
+                tr.open_span_ids()
+        except Exception as e:  # noqa: BLE001 — reported below
+            errors.append(repr(e))
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        reader = threading.Thread(target=read)
+        threads = [threading.Thread(target=write) for _ in range(writers)]
+        reader.start()
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        stop.set()
+        reader.join(timeout=30)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads) and not reader.is_alive()
+    assert errors == []
+    spans = tr.recent(limit=tr.max_spans)
+    assert len(spans) == 2 * writers * each
+    assert len({s["spanId"] for s in spans}) == len(spans)
+    assert all(s["endTimeUnixNano"] for s in spans)
+    assert tr.open_span_ids() == set()
+    assert TRACE_SPANS.value(name="stress.child") - before == writers * each
+
+
+def test_span_and_trace_ids_keep_the_w3c_shape():
+    from weaviate_tpu.monitoring.tracing import parse_traceparent
+
+    tr = Tracer()
+    seen_spans, seen_traces = set(), set()
+    for _ in range(2000):
+        with tr.span("root", parent=None) as s:
+            pass
+        assert len(s.span_id) == 16 and len(s.trace_id) == 32
+        int(s.span_id, 16), int(s.trace_id, 16)
+        assert parse_traceparent(s.traceparent) == s.context
+        seen_spans.add(s.span_id)
+        seen_traces.add(s.trace_id)
+    assert len(seen_spans) == len(seen_traces) == 2000
+
+
+def test_default_retention_holds_a_traced_segment():
+    """No knob: the constant keeps the ~11,000 spans of the search cell's
+    traced seconds whole (benchmark/run.py read_spans)."""
+    from weaviate_tpu.monitoring.tracing import MAX_SPANS
+
+    assert MAX_SPANS >= 16384
+    assert Tracer().max_spans == TRACER.max_spans == MAX_SPANS
+
+
 def test_traceparent_roundtrip():
     from weaviate_tpu.monitoring.tracing import parse_traceparent
 

@@ -1,0 +1,391 @@
+"""The spans of the served gRPC path, by count and structure (never by
+wall clock): a ``Search`` and a ``BatchObjects`` through ``GrpcAPI`` over a
+real channel each give ONE trace whose root is still ``grpc.<rpc>`` with the
+children ``docs/tracing.md`` lists, inside the span budget; an unsampled
+request opens nothing; the same names land in a ``jax.profiler`` trace's
+host plane (the shared clock ``benchmark/xplane.py`` reads gaps from)."""
+
+import json
+import time
+
+import numpy as np
+import pytest
+
+from weaviate_tpu.api.grpc_server import GrpcAPI, GrpcClient
+from weaviate_tpu.api.proto import pb
+from weaviate_tpu.core.db import DB
+from weaviate_tpu.monitoring import tracing
+from weaviate_tpu.monitoring.tracing import TRACER, Tracer
+from weaviate_tpu.schema.config import CollectionConfig, FlatIndexConfig
+
+D = 16
+
+# span -> parent, as docs/tracing.md's table has them
+SEARCH_TREE = {
+    "grpc.Search": None,
+    "qos.queue": "grpc.Search",
+    "index.search": "grpc.Search",
+    "flat.prepare": "index.search",
+    "flat.dispatch": "index.search",
+    "flat.result": "index.search",
+    "objects.fetch": "grpc.Search",
+    "grpc.encode": "grpc.Search",
+    "grpc.serialize": "grpc.Search",
+}
+BATCH_TREE = {
+    "grpc.BatchObjects": None,
+    "qos.queue": "grpc.BatchObjects",
+    "batch.build": "grpc.BatchObjects",
+    "schema.ensure": "grpc.BatchObjects",
+    "shard.put_batch": "grpc.BatchObjects",
+    "shard.durable": "shard.put_batch",
+    "shard.sync": "shard.put_batch",      # only with group commit
+    "shard.drain_wait": "shard.put_batch",
+    "ingest.drain": "shard.drain_wait",
+    "index.add_batch": "ingest.drain",
+    "grpc.encode": "grpc.BatchObjects",
+    "grpc.serialize": "grpc.BatchObjects",
+}
+SEARCH_BUDGET, BATCH_BUDGET = 10, 16
+
+
+def _serve(tmp_dbdir, sync_writes=False, max_workers=None):
+    db = DB(tmp_dbdir, sync_writes=sync_writes)
+    db.create_collection(CollectionConfig(
+        name="Article",
+        vector_config=FlatIndexConfig(distance="cosine")))
+    api = GrpcAPI(db, max_workers=max_workers)
+    client = GrpcClient(f"127.0.0.1:{api.serve(port=0)}")
+    return db, api, client
+
+
+@pytest.fixture(params=[False, True], ids=["soft", "group_commit"])
+def served(request, tmp_dbdir):
+    db, api, client = _serve(tmp_dbdir, sync_writes=request.param)
+    yield client, request.param
+    client.close()
+    api.shutdown()
+    db.close()
+
+
+def _batch(n, start=0):
+    rng = np.random.default_rng(start)
+    req = pb.BatchObjectsRequest()
+    for i in range(start, start + n):
+        o = req.objects.add()
+        o.uuid = f"00000000-0000-0000-0000-{i:012d}"
+        o.collection = "Article"
+        o.properties_json = json.dumps({"title": f"article {i}"})
+        o.vector.values.extend(rng.standard_normal(D).tolist())
+    return req
+
+
+def _search(vectors=1, limit=10):
+    rng = np.random.default_rng(99)
+    req = pb.SearchRequest(collection="Article", limit=limit)
+    for _ in range(vectors):
+        req.near_vectors.add().values.extend(rng.standard_normal(D).tolist())
+    return req
+
+
+def _one_trace(root: str) -> list[dict]:
+    """The spans of the only trace in the buffer, once its late
+    ``grpc.serialize`` (recorded after the client has its reply) is in."""
+    deadline = time.monotonic() + 5.0
+    while time.monotonic() < deadline:
+        traces = TRACER.traces(limit=10)
+        if len(traces) == 1 and any(
+                s["name"] == "grpc.serialize" for s in traces[0]["spans"]):
+            break
+        time.sleep(0.01)
+    assert len(traces) == 1, [t["root"] for t in traces]
+    assert traces[0]["root"] == root
+    assert not traces[0]["truncated"] and not traces[0]["inFlight"]
+    return traces[0]["spans"]
+
+
+def _check_tree(spans: list[dict], tree: dict, root: str) -> dict:
+    """Every span has the parent the table gives it and lies inside that
+    parent's interval, except the documented late ``grpc.serialize``,
+    which starts once the root has closed. Returns spans by name (lists)."""
+    by_id = {s["spanId"]: s for s in spans}
+    by_name: dict[str, list[dict]] = {}
+    for s in spans:
+        by_name.setdefault(s["name"], []).append(s)
+        assert s["name"] in tree, f"a span the table does not have: {s}"
+        assert s["status"] == "OK"
+        if tracing.THREAD_CLOCK:
+            assert s["attributes"]["cpu_ms"] >= 0
+        if tree[s["name"]] is None:
+            assert s["parentSpanId"] is None
+            continue
+        parent = by_id[s["parentSpanId"]]
+        assert parent["name"] == tree[s["name"]], (s["name"], parent["name"])
+        if s["name"] == "grpc.serialize":
+            assert s["startTimeUnixNano"] >= parent["endTimeUnixNano"]
+        else:
+            assert parent["startTimeUnixNano"] <= s["startTimeUnixNano"]
+            assert s["endTimeUnixNano"] <= parent["endTimeUnixNano"]
+    (root_span,) = by_name[root]
+    attrs = root_span["attributes"]
+    assert attrs["pool_wait_ms"] >= 0 and attrs["decode_ms"] >= 0
+    assert attrs["pool_wait_ms"] >= attrs["decode_ms"]
+    assert attrs["request_bytes"] > 0
+    return by_name
+
+
+@pytest.mark.parametrize("vectors", [1, 3])
+def test_search_gives_one_trace_with_the_tables_children(served, vectors):
+    client, _ = served
+    assert not client.batch_objects(_batch(100)).errors
+    TRACER.clear()
+    reply = client.search(_search(vectors))
+    assert [len(r.hits) for r in reply.results] == [10] * vectors
+    spans = _one_trace("grpc.Search")
+    by_name = _check_tree(spans, SEARCH_TREE, "grpc.Search")
+    # exactly the table's names, one span each: nothing per hit or per row
+    assert {n: len(v) for n, v in by_name.items()} == dict.fromkeys(
+        SEARCH_TREE, 1)
+    assert len(spans) <= SEARCH_BUDGET
+    (index,) = by_name["index.search"]
+    assert index["attributes"]["index_type"] == "FlatIndex"
+    assert index["attributes"]["rows"] == 100
+    assert index["attributes"]["k"] == 10
+    assert by_name["flat.dispatch"][0]["attributes"]["batch"] == vectors
+    assert by_name["flat.dispatch"][0]["attributes"]["capacity"] >= 100
+    assert by_name["objects.fetch"][0]["attributes"]["objects"] == \
+        10 * vectors
+    assert by_name["grpc.encode"][0]["attributes"]["hits"] == 10 * vectors
+    assert by_name["grpc.serialize"][0]["attributes"]["reply_bytes"] > 0
+    # the three index spans follow each other on the request's thread
+    order = [by_name[n][0] for n in
+             ("flat.prepare", "flat.dispatch", "flat.result")]
+    for a, b in zip(order, order[1:]):
+        assert a["endTimeUnixNano"] <= b["startTimeUnixNano"]
+
+
+def test_batch_objects_gives_one_trace_with_the_tables_children(served):
+    client, group_commit = served
+    assert not client.batch_objects(_batch(100)).errors   # schema, dims
+    TRACER.clear()
+    reply = client.batch_objects(_batch(100, start=100))
+    assert not reply.errors and len(reply.uuids) == 100
+    spans = _one_trace("grpc.BatchObjects")
+    by_name = _check_tree(spans, BATCH_TREE, "grpc.BatchObjects")
+    want = dict.fromkeys(BATCH_TREE, 1)
+    # a single writer's 100 rows feed the device as 64 + 32 + 4
+    want["index.add_batch"] = 3
+    if not group_commit:
+        del want["shard.sync"]
+    assert {n: len(v) for n, v in by_name.items()} == want
+    assert len(spans) <= BATCH_BUDGET
+    assert sorted(s["attributes"]["rows"]
+                  for s in by_name["index.add_batch"]) == [4, 32, 64]
+    assert all(s["attributes"]["grew"] is False
+               for s in by_name["index.add_batch"])
+    assert by_name["batch.build"][0]["attributes"]["objects"] == 100
+    (put,) = by_name["shard.put_batch"]
+    assert put["attributes"]["objects"] == 100
+    assert put["attributes"]["lock_wait_ms"] >= 0
+    (durable,) = by_name["shard.durable"]
+    assert durable["attributes"]["wal_ms"] >= 0
+    assert durable["attributes"]["push_ms"] >= 0
+    (drain,) = by_name["ingest.drain"]
+    assert drain["attributes"]["rows"] == 100
+    assert drain["attributes"]["buckets"] == 3
+    assert by_name["grpc.encode"][0]["attributes"]["objects"] == 100
+
+
+def test_a_grow_is_named_on_the_feed_that_paid_for_it(served):
+    """``grew`` comes from ``DeviceVectorStore.ensure_capacity``: the first
+    rows past the store's capacity (4,096 rows a page) say so."""
+    client, _ = served
+    TRACER.clear()
+    for start in range(0, 4200, 700):
+        assert not client.batch_objects(_batch(700, start=start)).errors
+    feeds = [s for s in TRACER.recent(limit=TRACER.max_spans)
+             if s["name"] == "index.add_batch"]
+    assert sum(s["attributes"]["rows"] for s in feeds) == 4200
+    assert sum(1 for s in feeds if s["attributes"]["grew"]) == 1
+
+
+def test_an_aborted_call_leaves_no_root_for_the_next_serializer(tmp_dbdir):
+    """One worker thread: the ``grpc.serialize`` of a reply hangs under its
+    own call's root, never under that of an aborted call before it."""
+    import grpc
+
+    db, api, client = _serve(tmp_dbdir, max_workers=1)
+    try:
+        assert not client.batch_objects(_batch(20)).errors
+        TRACER.clear()
+        bad = _search()
+        bad.collection = "Nowhere"
+        with pytest.raises(grpc.RpcError):
+            client.search(bad)
+        client.search(_search(limit=5))
+        deadline = time.monotonic() + 5.0
+        while time.monotonic() < deadline and not any(
+                s["name"] == "grpc.serialize"
+                for s in TRACER.recent(limit=100)):
+            time.sleep(0.01)
+        spans = TRACER.recent(limit=100)
+        roots = [s for s in spans if s["name"] == "grpc.Search"]
+        assert [r["status"] for r in roots] == ["ERROR", "OK"]
+        (ser,) = [s for s in spans if s["name"] == "grpc.serialize"]
+        assert ser["parentSpanId"] == roots[1]["spanId"]
+        assert ser["traceId"] == roots[1]["traceId"]
+    finally:
+        client.close()
+        api.shutdown()
+        db.close()
+
+
+def test_v1_compat_plane_shares_the_ingress_attributes(tmp_dbdir):
+    """``weaviate.v1`` calls go through the same ``traced_unary_handler``."""
+    from weaviate_tpu.api.grpc_v1_compat import SERVICE_V1
+    from weaviate_tpu.api.proto import weaviate_v1_compat_pb2 as wv
+
+    db, api, client = _serve(tmp_dbdir)
+    try:
+        assert not client.batch_objects(_batch(20)).errors
+        TRACER.clear()
+        call = client.channel.unary_unary(
+            f"/{SERVICE_V1}/Search",
+            request_serializer=lambda m: m.SerializeToString(),
+            response_deserializer=wv.SearchReply.FromString)
+        req = wv.SearchRequest(collection="Article", limit=3)
+        req.near_vector.vector_bytes = np.random.default_rng(
+            5).standard_normal(D).astype(np.float32).tobytes()
+        assert len(call(req).results) == 3
+        spans = _one_trace("grpc.Search")
+        (root,) = [s for s in spans if s["name"] == "grpc.Search"]
+        assert root["attributes"]["plane"] == "v1_compat"
+        assert root["attributes"]["pool_wait_ms"] >= 0
+        assert root["attributes"]["request_bytes"] > 0
+        (ser,) = [s for s in spans if s["name"] == "grpc.serialize"]
+        assert ser["parentSpanId"] == root["spanId"]
+    finally:
+        client.close()
+        api.shutdown()
+        db.close()
+
+
+@pytest.fixture
+def counting(monkeypatch):
+    """Counts the annotations the tracer opens and its reads of the
+    thread's CPU clock."""
+    import types
+
+    c = types.SimpleNamespace(opened=[], clock_reads=0)
+    real = tracing.TraceAnnotation
+
+    def annotation(name):
+        c.opened.append(name)
+        return real(name)
+
+    def thread_time_ns():
+        c.clock_reads += 1
+        return time.thread_time_ns()
+
+    monkeypatch.setattr(tracing, "TraceAnnotation", annotation)
+    monkeypatch.setattr(tracing, "time", types.SimpleNamespace(
+        time_ns=time.time_ns, thread_time_ns=thread_time_ns))
+    return c
+
+
+def test_unsampled_requests_open_no_annotation_and_read_no_thread_clock(
+        tmp_dbdir, counting):
+    from weaviate_tpu.utils.runtime_config import TRACING_SAMPLE_RATE
+
+    db, api, client = _serve(tmp_dbdir)
+    try:
+        assert not client.batch_objects(_batch(50)).errors
+        client.search(_search())
+        sampled = len(counting.opened)
+        reads = (2 if tracing.THREAD_CLOCK else 0) * sampled
+        assert sampled >= 2 and counting.clock_reads == reads
+        assert "grpc.Search" in counting.opened
+        TRACING_SAMPLE_RATE.set_override(0.0)
+        try:
+            TRACER.clear()
+            assert not client.batch_objects(_batch(50, start=50)).errors
+            assert len(client.search(_search()).results[0].hits) == 10
+            api.shutdown()      # the workers are done, serializers too
+            assert len(counting.opened) == sampled
+            assert counting.clock_reads == reads
+            assert TRACER.recent(limit=TRACER.max_spans) == []
+        finally:
+            TRACING_SAMPLE_RATE.clear_override()
+    finally:
+        client.close()
+        api.shutdown()
+        db.close()
+
+
+def test_span_names_land_on_a_host_line_of_a_profiler_trace(tmp_path,
+                                                            tmp_dbdir):
+    """The shared clock, rehearsed on the CPU backend: with a profile
+    session open (the options ``benchmark/serve.py`` sets), a ``Search``'s
+    spans are events of ONE thread line of the host plane, nested there as
+    in the program's own trace."""
+    import jax
+    from jax.profiler import ProfileData
+
+    from benchmark import xplane
+
+    db, api, client = _serve(tmp_dbdir)
+    try:
+        assert not client.batch_objects(_batch(50)).errors
+        client.search(_search())            # compile outside the session
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        opts.enable_hlo_proto = False
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            client.search(_search())
+            api.shutdown()
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        client.close()
+        api.shutdown()
+        db.close()
+    path = xplane.find_trace(str(tmp_path))
+    assert path is not None
+    want = set(SEARCH_TREE)
+    lines = [
+        {ev.name: (int(ev.start_ns), int(ev.start_ns) + int(ev.duration_ns))
+         for ev in line.events}
+        for plane in ProfileData.from_file(path).planes
+        if plane.name == "/host:CPU" for line in plane.lines]
+    held = [events for events in lines if want <= set(events)]
+    assert len(held) == 1, [sorted(set(e) & want) for e in lines]
+    events = held[0]
+    for name, parent in SEARCH_TREE.items():
+        if parent is None:
+            continue
+        if name == "grpc.serialize":
+            assert events[name][0] >= events[parent][1]
+        else:
+            assert events[parent][0] <= events[name][0]
+            assert events[name][1] <= events[parent][1]
+    # a device call made inside flat.prepare / flat.dispatch lies inside it
+    # on that line: "the innermost host event that covers the gap" is then
+    # the jax call where there is one and the program's span where not
+    jax_calls = [n for n in events if n.startswith("PjitFunction(")]
+    assert jax_calls, sorted(events)
+    for n in jax_calls:
+        assert events["index.search"][0] <= events[n][0]
+        assert events[n][1] <= events["index.search"][1]
+
+
+def test_the_buffer_keeps_a_cells_traced_segment():
+    """~1,200 requests x 9 spans in the search cell's traced 4 s."""
+    assert TRACER.max_spans >= 16384
+    tr = Tracer()
+    n = tr.max_spans + 100
+    for _ in range(n):
+        with tr.span("s"):
+            pass
+    assert len(tr.recent(limit=n)) == tr.max_spans >= 16384

@@ -15,6 +15,7 @@ host↔device round-trip.
 from __future__ import annotations
 
 import json
+import threading
 import time
 from concurrent import futures
 from typing import Optional
@@ -26,6 +27,7 @@ from weaviate_tpu.api.graphql import where_to_filter
 from weaviate_tpu.api.proto import pb
 from weaviate_tpu.cluster.resilience import Deadline, DeadlineExceeded
 from weaviate_tpu.core.db import DB
+from weaviate_tpu.monitoring.tracing import TRACER
 from weaviate_tpu.query import Explorer, HybridParams, QueryParams
 from weaviate_tpu.serving.context import RequestContext, request_scope
 from weaviate_tpu.serving.qos import QosRejected
@@ -86,6 +88,74 @@ def qos_admit(qos, name: str, context, tenant: str = ""):
     return ticket, ctx
 
 
+# what the worker thread of the call in hand knows of its own ingress: when
+# gRPC handed the call to the pool, and (after the handler) the root span
+_ingress = threading.local()
+
+
+class ArrivalStampingPool(futures.ThreadPoolExecutor):
+    """The pool gRPC hands every call to. ``submit`` runs on gRPC's polling
+    thread as the call arrives; the stamp travels to the worker thread that
+    takes the call, where ``traced_unary_handler`` turns it into
+    ``pool_wait_ms``."""
+
+    def submit(self, fn, /, *args, **kwargs):
+        return super().submit(_run_stamped, time.perf_counter_ns(), fn,
+                              *args, **kwargs)
+
+
+def _run_stamped(arrived_ns: int, fn, *args, **kwargs):
+    _ingress.arrived_ns = arrived_ns
+    _ingress.root = None
+    return fn(*args, **kwargs)
+
+
+def traced_unary_handler(name: str, run, req_cls, **root_attrs):
+    """The unary-unary handler of both gRPC planes: ``run(request,
+    context)`` inside the ingress root span ``grpc.<name>`` (handler entry
+    to handler return; the traceparent rides invocation metadata, same W3C
+    format as the REST header), with what happened to the call before the
+    handler as attributes of the root — ``pool_wait_ms`` (arrival at the
+    pool -> handler start: the hand-off to a worker, the wait for the
+    request message and its decode), ``decode_ms`` and ``request_bytes``
+    (``FromString``, which gRPC runs on its one polling thread) — and what
+    happens after it as a late child, ``grpc.serialize``."""
+    span_name = f"grpc.{name}"
+
+    def decode(data: bytes):
+        t0 = time.perf_counter_ns()
+        request = req_cls.FromString(data)
+        return request, (time.perf_counter_ns() - t0) / 1e6, len(data)
+
+    def handler(decoded, context):
+        started_ns = time.perf_counter_ns()
+        request, decode_ms, request_bytes = decoded
+        arrived_ns = getattr(_ingress, "arrived_ns", started_ns)
+        md = dict(context.invocation_metadata() or [])
+        with TRACER.ingress(
+                span_name, traceparent=md.get("traceparent", ""), rpc=name,
+                pool_wait_ms=round((started_ns - arrived_ns) / 1e6, 3),
+                decode_ms=round(decode_ms, 3), request_bytes=request_bytes,
+                **root_attrs) as root:
+            # a reply that is never serialized (abort) leaves this to the
+            # next call's _run_stamped
+            _ingress.root = root
+            return run(request, context)
+
+    def serialize(reply) -> bytes:
+        root, _ingress.root = getattr(_ingress, "root", None), None
+        if root is None:
+            return reply.SerializeToString()
+        # after the root closed, on the same worker thread, as its child
+        with TRACER.span("grpc.serialize", parent=root) as span:
+            data = reply.SerializeToString()
+            span.set(reply_bytes=len(data))
+        return data
+
+    return grpc.unary_unary_rpc_method_handler(
+        handler, request_deserializer=decode, response_serializer=serialize)
+
+
 def insert_grouped(db: DB, items) -> list[tuple[int, str]]:
     """Shared batch-insert tail for both gRPC planes: group decoded objects
     by (collection, tenant), run auto-schema, put_batch; returns
@@ -98,7 +168,8 @@ def insert_grouped(db: DB, items) -> list[tuple[int, str]]:
         try:
             from weaviate_tpu.schema.auto_schema import ensure_schema
 
-            ensure_schema(db, cls, [o.properties for _, o in group])
+            with TRACER.child("schema.ensure"):
+                ensure_schema(db, cls, [o.properties for _, o in group])
             col = db.get_collection(cls)
             col.put_batch([o for _, o in group], tenant=tenant)
         except (KeyError, ValueError, RuntimeError) as e:
@@ -165,17 +236,6 @@ class GrpcAPI:
     def _wrap(self, name, fn):
         action, resource_fn = _RPC_AUTHZ[name]
 
-        def handler(request, context):
-            from weaviate_tpu.monitoring.tracing import TRACER
-
-            md = dict(context.invocation_metadata() or [])
-            # gRPC ingress span: the traceparent rides invocation
-            # metadata (same W3C format as the REST header)
-            with TRACER.ingress(f"grpc.{name}",
-                                traceparent=md.get("traceparent", ""),
-                                rpc=name):
-                return run(request, context)
-
         def run(request, context):
             principal, groups = self._principal(context)
             if name == "BatchObjects":
@@ -218,7 +278,7 @@ class GrpcAPI:
                 context.abort(grpc.StatusCode.UNAVAILABLE, str(e))
             except RuntimeError as e:
                 context.abort(grpc.StatusCode.FAILED_PRECONDITION, str(e))
-        return handler
+        return run
 
     def search(self, req: pb.SearchRequest) -> pb.SearchReply:
         t0 = time.perf_counter()
@@ -253,16 +313,21 @@ class GrpcAPI:
                 target=req.target_vector, flt=flt, tenant=req.tenant,
                 max_distance=max_dist,
             )
-            for row in rows:
-                qr = reply.results.add()
-                page = row[req.offset:]
-                if req.autocut > 0:
-                    cut = autocut_fn([d for _, d in page], int(req.autocut))
-                    page = page[:cut]
-                for obj, dist in page:
-                    self._add_hit(qr, obj, distance=dist,
-                                  include_vector=req.include_vector,
-                                  target=req.target_vector)
+            with TRACER.child("grpc.encode") as span:
+                hits = 0
+                for row in rows:
+                    qr = reply.results.add()
+                    page = row[req.offset:]
+                    if req.autocut > 0:
+                        cut = autocut_fn([d for _, d in page],
+                                         int(req.autocut))
+                        page = page[:cut]
+                    for obj, dist in page:
+                        self._add_hit(qr, obj, distance=dist,
+                                      include_vector=req.include_vector,
+                                      target=req.target_vector)
+                    hits += len(page)
+                span.set(hits=hits)
             reply.took_seconds = time.perf_counter() - t0
             return reply
 
@@ -308,15 +373,16 @@ class GrpcAPI:
             params.bm25_minimum_match = int(req.bm25_minimum_match)
 
         result = self.explorer.get(params)
-        qr = reply.results.add()
-        for hit in result.hits:
-            score = hit.score
-            if "rerank_score" in hit.additional:
-                score = hit.additional["rerank_score"]
-            self._add_hit(qr, hit.object, score=score,
-                          distance=hit.distance,
-                          include_vector=req.include_vector,
-                          target=req.target_vector)
+        with TRACER.child("grpc.encode", hits=len(result.hits)):
+            qr = reply.results.add()
+            for hit in result.hits:
+                score = hit.score
+                if "rerank_score" in hit.additional:
+                    score = hit.additional["rerank_score"]
+                self._add_hit(qr, hit.object, score=score,
+                              distance=hit.distance,
+                              include_vector=req.include_vector,
+                              target=req.target_vector)
         reply.took_seconds = time.perf_counter() - t0
         return reply
 
@@ -341,35 +407,40 @@ class GrpcAPI:
         reply = pb.BatchObjectsReply()
         groups: dict[tuple[str, str], list[tuple[int, StorageObject]]] = {}
         objs: list[Optional[StorageObject]] = []
-        for i, bo in enumerate(req.objects):
-            try:
-                obj = StorageObject(
-                    uuid=bo.uuid,
-                    collection=bo.collection,
-                    properties=json.loads(bo.properties_json)
-                    if bo.properties_json else {},
-                    vector=_np_from_vec(bo.vector)
-                    if bo.vector.values else None,
-                    named_vectors={
-                        k: _np_from_vec(v)
-                        for k, v in bo.named_vectors.items()
-                    },
-                    tenant=bo.tenant,
-                )
-                objs.append(obj)
-                groups.setdefault((bo.collection, bo.tenant), []).append((i, obj))
-            except (json.JSONDecodeError, ValueError) as e:
-                objs.append(None)
+        with TRACER.child("batch.build", objects=len(req.objects)):
+            for i, bo in enumerate(req.objects):
+                try:
+                    obj = StorageObject(
+                        uuid=bo.uuid,
+                        collection=bo.collection,
+                        properties=json.loads(bo.properties_json)
+                        if bo.properties_json else {},
+                        vector=_np_from_vec(bo.vector)
+                        if bo.vector.values else None,
+                        named_vectors={
+                            k: _np_from_vec(v)
+                            for k, v in bo.named_vectors.items()
+                        },
+                        tenant=bo.tenant,
+                    )
+                    objs.append(obj)
+                    groups.setdefault(
+                        (bo.collection, bo.tenant), []).append((i, obj))
+                except (json.JSONDecodeError, ValueError) as e:
+                    objs.append(None)
+                    err = reply.errors.add()
+                    err.index = i
+                    err.message = str(e)
+        decoded = [it for g in groups.values() for it in g]
+        failed = insert_grouped(self.db, decoded)
+        with TRACER.child("grpc.encode", objects=len(objs)):
+            for i, msg in failed:
                 err = reply.errors.add()
                 err.index = i
-                err.message = str(e)
-        decoded = [it for g in groups.values() for it in g]
-        for i, msg in insert_grouped(self.db, decoded):
-            err = reply.errors.add()
-            err.index = i
-            err.message = msg
-            objs[i] = None
-        reply.uuids.extend(o.uuid if o is not None else "" for o in objs)
+                err.message = msg
+                objs[i] = None
+            reply.uuids.extend(
+                o.uuid if o is not None else "" for o in objs)
         reply.took_seconds = time.perf_counter() - t0
         return reply
 
@@ -416,11 +487,7 @@ class GrpcAPI:
             "Aggregate": (self.aggregate, pb.AggregateRequest),
         }
         handlers = {
-            name: grpc.unary_unary_rpc_method_handler(
-                self._wrap(name, fn),
-                request_deserializer=req_cls.FromString,
-                response_serializer=lambda msg: msg.SerializeToString(),
-            )
+            name: traced_unary_handler(name, self._wrap(name, fn), req_cls)
             for name, (fn, req_cls) in rpcs.items()
         }
         return grpc.method_handlers_generic_handler(SERVICE, handlers)
@@ -436,7 +503,7 @@ class GrpcAPI:
         workers = self.max_workers if self.max_workers is not None \
             else max(8, min(64, self.qos.limiter.max_limit))
         self._server = grpc.server(
-            futures.ThreadPoolExecutor(max_workers=workers))
+            ArrivalStampingPool(max_workers=workers))
         # native TPU-first plane + the reference's public weaviate.v1
         # contract, one port (stock clients connect unchanged)
         compat = WeaviateV1Service(self.db, auth=self.auth, rbac=self.rbac,

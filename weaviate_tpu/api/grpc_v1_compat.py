@@ -795,24 +795,15 @@ class WeaviateV1Service:
 
     # -- registration ------------------------------------------------------
     def generic_handler(self):
-        from weaviate_tpu.api.grpc_server import qos_admit
+        from weaviate_tpu.api.grpc_server import (
+            qos_admit,
+            traced_unary_handler,
+        )
         from weaviate_tpu.cluster.resilience import DeadlineExceeded
         from weaviate_tpu.serving.context import request_scope
         from weaviate_tpu.tiering import ColdStartPending
 
         def unary(name, fn, req_cls):
-            def h(request, context):
-                from weaviate_tpu.monitoring.tracing import TRACER
-
-                md = dict(context.invocation_metadata() or [])
-                # ingress span, same W3C traceparent metadata key as the
-                # native plane (the two planes must not drift)
-                with TRACER.ingress(
-                        f"grpc.{name}",
-                        traceparent=md.get("traceparent", ""),
-                        rpc=name, plane="v1_compat"):
-                    return run(request, context)
-
             def run(request, context):
                 # same admission + end-to-end deadline as the native
                 # plane (shared qos_admit); tenant rides most requests
@@ -838,9 +829,10 @@ class WeaviateV1Service:
                 except RuntimeError as e:
                     context.abort(grpc.StatusCode.FAILED_PRECONDITION,
                                   str(e))
-            return grpc.unary_unary_rpc_method_handler(
-                h, request_deserializer=req_cls.FromString,
-                response_serializer=lambda m: m.SerializeToString())
+            # ingress span, attributes and the grpc.serialize child shared
+            # with the native plane (the two planes must not drift)
+            return traced_unary_handler(name, run, req_cls,
+                                        plane="v1_compat")
 
         # BatchStream stays un-admitted: it is flow-controlled per Data
         # message by the gRPC stream itself, and a mid-stream shed would

@@ -198,9 +198,12 @@ class AsyncVectorQueue:
                 # search batch (acceptance pin, docs/ingest.md)
                 with dispatch_group(("ingest",)):
                     for off, size in pow2_buckets(len(ids)):
-                        # graftlint: allow[device-feed-under-lock] reason=_drain_lock is the single-drainer apply guard, not a shard lock; writers and readers never contend on it
-                        idx.add_batch(ids[off:off + size],
-                                      vecs[off:off + size])
+                        # grew: set by DeviceVectorStore.ensure_capacity
+                        with tracing.TRACER.child("index.add_batch",
+                                                 rows=size, grew=False):
+                            # graftlint: allow[device-feed-under-lock] reason=_drain_lock is the single-drainer apply guard, not a shard lock; writers and readers never contend on it
+                            idx.add_batch(ids[off:off + size],
+                                          vecs[off:off + size])
                         buckets_fed += 1
                 applied += len(ids)
             with self._lock:

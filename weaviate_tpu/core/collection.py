@@ -1197,20 +1197,24 @@ class Collection:
             b = 1
         else:
             b = np.atleast_2d(queries).shape[0]
+        from weaviate_tpu.monitoring.tracing import TRACER
+
         out: list[list[tuple[StorageObject, float]]] = []
-        for qi in range(b):
-            cands: list[tuple[float, Shard, int]] = []
-            for shard, res in per_shard:
-                for d, i in zip(res.dists[qi], res.ids[qi]):
-                    if i >= 0:
-                        cands.append((float(d), shard, int(i)))
-            cands.sort(key=lambda t: t[0])
-            row = []
-            for d, shard, docid in cands[:k]:
-                obj = shard.get_by_docid(docid)
-                if obj is not None:
-                    row.append((obj, d))
-            out.append(row)
+        with TRACER.child("objects.fetch") as span:
+            for qi in range(b):
+                cands: list[tuple[float, Shard, int]] = []
+                for shard, res in per_shard:
+                    for d, i in zip(res.dists[qi], res.ids[qi]):
+                        if i >= 0:
+                            cands.append((float(d), shard, int(i)))
+                cands.sort(key=lambda t: t[0])
+                row = []
+                for d, shard, docid in cands[:k]:
+                    obj = shard.get_by_docid(docid)
+                    if obj is not None:
+                        row.append((obj, d))
+                out.append(row)
+            span.set(objects=sum(len(row) for row in out))
         return out
 
     def bm25_search(

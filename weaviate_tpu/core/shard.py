@@ -12,6 +12,7 @@ from __future__ import annotations
 import os
 import struct
 import threading
+import time
 from typing import Any, Optional
 
 import msgpack
@@ -20,6 +21,7 @@ import numpy as np
 from weaviate_tpu.index.base import SearchResult, VectorIndex
 from weaviate_tpu.inverted.index import InvertedIndex
 from weaviate_tpu.inverted.segmented import make_inverted_index
+from weaviate_tpu.monitoring.tracing import TRACER
 from weaviate_tpu.schema.config import (
     CollectionConfig,
     DynamicIndexConfig,
@@ -498,6 +500,10 @@ class Shard:
         drain windows after the lock is released, so one writer's device
         build never convoys every other writer and reader on the shard.
         """
+        with TRACER.child("shard.put_batch", objects=len(objs)) as span:
+            return self._put_batch(objs, span)
+
+    def _put_batch(self, objs: list[StorageObject], span) -> list[int]:
         # memwatch gate (reference memwatch.CheckAlloc on the write path):
         # refuse the batch under memory pressure instead of OOMing mid-write
         from weaviate_tpu.monitoring.memwatch import MONITOR
@@ -513,7 +519,9 @@ class Shard:
         deferred_deletes: Optional[np.ndarray] = None
         ragged: list[tuple[str, np.ndarray, list]] = []
         pushed: list[str] = []
-        with self._lock:
+        t_lock = time.perf_counter()
+        with self._lock, TRACER.child("shard.durable") as durable:
+            lock_wait = time.perf_counter() - t_lock
             self._require_open()
             # validate up-front so a bad object can't leave a partial batch:
             # every vector for a target must match the index dims (or, for a
@@ -569,11 +577,13 @@ class Shard:
             # whose object bytes never landed replays as a no-op, while an
             # unlogged object would silently skip indexing after a crash
             self._seq += 1
+            t_wal = time.perf_counter()
             self._delta.append(msgpack.packb(
                 {"s": self._seq, "o": "a",
                  "d": [o.doc_id for o in final.values()]},
                 use_bin_type=True))
             self._delta.flush_soft()  # never let objects get durable first
+            wal_ms = (time.perf_counter() - t_wal) * 1000
 
             batches: dict[str, tuple[list[int], list[np.ndarray]]] = {}
             # bucket writes accumulate across the batch: one put_many /
@@ -600,6 +610,7 @@ class Shard:
             if old_docids:
                 deferred_deletes = self._delete_docids_durable(old_docids)
 
+            t_push = time.perf_counter()
             for nm, (ids, vecs) in batches.items():
                 id_arr = np.asarray(ids, np.int64)
                 if self._config_for(nm).index_type == "multivector":
@@ -613,6 +624,8 @@ class Shard:
                     # drain below, outside the lock
                     pushed.append(self.async_queue.push(
                         nm, id_arr, np.stack(vecs)))
+            durable.set(wal_ms=round(wal_ms, 3), push_ms=round(
+                (time.perf_counter() - t_push) * 1000, 3))
             self._live_count += len(final)
             self._defer_ops += 1
         try:
@@ -620,8 +633,9 @@ class Shard:
             # covering the whole batch, not one per record — a no-op in
             # non-sync mode
             if self._delta.group:
-                self._delta.sync_window()
-                self.store.sync_all()
+                with TRACER.child("shard.sync"):
+                    self._delta.sync_window()
+                    self.store.sync_all()
             if ragged:
                 # ragged sets bypass the queue but are still ingest work:
                 # same batch-group token as the drain (never coalesces
@@ -639,12 +653,17 @@ class Shard:
             if deferred_deletes is not None:
                 self._apply_index_deletes(deferred_deletes)
         finally:
+            # the shard lock a second time, behind the other writers again
+            t_lock = time.perf_counter()
             with self._lock:
+                lock_wait += time.perf_counter() - t_lock
                 self._defer_ops -= 1
+            span.set(lock_wait_ms=round(lock_wait * 1000, 3))
         if pushed and not self._fully_async:
             # inline mode: drain our own chunks (read-your-writes) — other
             # writers' chunks coalesce into the same drain windows
-            self.async_queue.ensure_drained(pushed)
+            with TRACER.child("shard.drain_wait"):
+                self.async_queue.ensure_drained(pushed)
         self._maybe_upgrade_inverted()
         return doc_ids
 
@@ -783,43 +802,45 @@ class Shard:
             )
         from weaviate_tpu.monitoring.metrics import TIER_SEARCHES
 
-        # residency-tier attribution (tiering/): device = HBM-resident
-        # arrays, host = the warm tier's exact fallback executor
-        TIER_SEARCHES.inc(
-            tier="device" if idx.device_resident else "host")
-        if allow_list is not None \
-                and getattr(allow_list, "plane_id", None) is not None \
-                and (idx.multi_vector or max_distance is not None
-                     or not getattr(idx, "supports_filter_planes", False)):
-            # only the plain graph search consumes planes natively; every
-            # other route gets the plane's host bitmap
-            allow_list = allow_list.mask(max(self._next_doc_id, 1))
-        if idx.multi_vector:
-            # a [Tq, D] matrix is ONE late-interaction query (token set),
-            # not a Tq-query batch; max_distance bounds the negated
-            # MaxSim. The fused rerank stage is built in (search_multi
-            # runs FDE scan + module score as one dispatch).
-            res = idx.search_multi(queries, k, allow_list)
+        with TRACER.child("index.search", index_type=type(idx).__name__,
+                         rows=idx.count(), k=k):
+            # residency-tier attribution (tiering/): device = HBM-resident
+            # arrays, host = the warm tier's exact fallback executor
+            TIER_SEARCHES.inc(
+                tier="device" if idx.device_resident else "host")
+            if allow_list is not None \
+                    and getattr(allow_list, "plane_id", None) is not None \
+                    and (idx.multi_vector or max_distance is not None
+                         or not getattr(idx, "supports_filter_planes", False)):
+                # only the plain graph search consumes planes natively; every
+                # other route gets the plane's host bitmap
+                allow_list = allow_list.mask(max(self._next_doc_id, 1))
+            if idx.multi_vector:
+                # a [Tq, D] matrix is ONE late-interaction query (token set),
+                # not a Tq-query batch; max_distance bounds the negated
+                # MaxSim. The fused rerank stage is built in (search_multi
+                # runs FDE scan + module score as one dispatch).
+                res = idx.search_multi(queries, k, allow_list)
+                if max_distance is not None:
+                    keep = res.dists <= max_distance
+                    res = SearchResult(ids=np.where(keep, res.ids, -1),
+                                       dists=np.where(keep, res.dists, np.inf))
+                return res
+            if rerank is not None:
+                # fused device rerank (modules/device/): only indexes with a
+                # configured module accept the kwarg — the explorer routes
+                # here only after checking the target's config
+                if max_distance is not None:
+                    raise ValueError(
+                        "rerank and max_distance cannot combine: reranked "
+                        "distances are negated module scores, not metric "
+                        "distances a bound could apply to")
+                return idx.search(queries, k, allow_list, rerank=rerank,
+                                  est_selectivity=est_selectivity)
             if max_distance is not None:
-                keep = res.dists <= max_distance
-                res = SearchResult(ids=np.where(keep, res.ids, -1),
-                                   dists=np.where(keep, res.dists, np.inf))
-            return res
-        if rerank is not None:
-            # fused device rerank (modules/device/): only indexes with a
-            # configured module accept the kwarg — the explorer routes
-            # here only after checking the target's config
-            if max_distance is not None:
-                raise ValueError(
-                    "rerank and max_distance cannot combine: reranked "
-                    "distances are negated module scores, not metric "
-                    "distances a bound could apply to")
-            return idx.search(queries, k, allow_list, rerank=rerank,
+                return idx.search_by_distance(queries, max_distance, allow_list, limit=k)
+            return idx.search(queries, k, allow_list,
                               est_selectivity=est_selectivity)
-        if max_distance is not None:
-            return idx.search_by_distance(queries, max_distance, allow_list, limit=k)
-        return idx.search(queries, k, allow_list,
-                          est_selectivity=est_selectivity)
 
     def objects_by_docids(self, doc_ids: np.ndarray) -> list[Optional[StorageObject]]:
         return [self.get_by_docid(int(d)) if d >= 0 else None for d in doc_ids]

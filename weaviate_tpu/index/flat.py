@@ -21,6 +21,7 @@ from weaviate_tpu.index.base import (
     run_tier_stable,
 )
 from weaviate_tpu.index.store import DeviceVectorStore
+from weaviate_tpu.monitoring.tracing import TRACER
 from weaviate_tpu.ops.distance import MASK_DISTANCE, flat_search
 from weaviate_tpu.ops.topk import masked_topk
 from weaviate_tpu.schema.config import FlatIndexConfig
@@ -111,22 +112,33 @@ class FlatIndex(VectorIndex):
             d, ids = host_store_topk(
                 self.store, self.metric, queries, k, allow_list)
             return SearchResult(ids=ids, dists=d)
-        qj = jnp.asarray(queries)
-        if self.metric == "cosine":
-            from weaviate_tpu.ops.distance import normalize
+        with TRACER.child("flat.prepare"):
+            qj = jnp.asarray(queries)
+            if self.metric == "cosine":
+                from weaviate_tpu.ops.distance import normalize
 
-            qj = normalize(qj)
+                qj = normalize(qj)
+        with TRACER.child("flat.dispatch", capacity=self.store.capacity,
+                         batch=queries.shape[0]):
+            d, ids = self._dispatch(qj, k, allow_list, approx_recall)
+        # the wait for the device, behind other requests' scans, and the
+        # copy out
+        with TRACER.child("flat.result"):
+            # graftlint: allow[host-sync-in-hot-path] reason=final top-k materialization
+            return SearchResult(ids=np.asarray(ids), dists=np.asarray(d))
+
+    def _dispatch(self, qj, k: int, allow_list, approx_recall: float):
+        """Start the scan of one device-resident query batch; returns the
+        (distances, ids) device arrays without waiting for them."""
         if self.store.mesh is not None:
             from weaviate_tpu.parallel.sharded_search import mesh_flat_topk
 
-            d, ids = mesh_flat_topk(
+            return mesh_flat_topk(
                 self.store, qj, k, self.metric, allow=allow_list,
                 precision=self.config.precision,
                 chunk_size=self.config.search_chunk_size,
                 approx_recall=approx_recall,
             )
-            # graftlint: allow[host-sync-in-hot-path] reason=final top-k materialization
-            return SearchResult(ids=np.asarray(ids), dists=np.asarray(d))
         # one consistent device-state snapshot (concurrent writers swap it)
         corpus, valid, sqnorms = self.store.snapshot()
         cap = corpus.shape[0]
@@ -164,13 +176,10 @@ class FlatIndex(VectorIndex):
                 live = max(1, live + allow_n - cap)
             if pallas_flat.fits(cap, csz,
                                 corpus.shape[1] * corpus.dtype.itemsize):
-                d, ids = pallas_flat.pallas_flat_topk(
+                return pallas_flat.pallas_flat_topk(
                     qj, corpus, sqnorms, m, k, chunk_size=csz,
                     live_rows=pallas_flat.bucket_live(live))
-                return SearchResult(
-                    # graftlint: allow[host-sync-in-hot-path] reason=final top-k materialization
-                    ids=np.asarray(ids), dists=np.asarray(d))
-        d, ids = flat_search(
+        return flat_search(
             qj,
             corpus,
             k=k,
@@ -182,8 +191,6 @@ class FlatIndex(VectorIndex):
             precision=self.config.precision,
             approx_recall=approx_recall,
         )
-        # graftlint: allow[host-sync-in-hot-path] reason=final top-k materialization
-        return SearchResult(ids=np.asarray(ids), dists=np.asarray(d))
 
     def search_by_distance(
         self,

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from weaviate_tpu.compression.store import ResidencyMoved, TieredResidency
+from weaviate_tpu.monitoring import tracing
 from weaviate_tpu.ops.distance import normalize
 
 _PAGE = 4096
@@ -265,6 +266,8 @@ class DeviceVectorStore(TieredResidency):
         hv = np.zeros((new_cap,), bool)
         hv[: len(self._host_valid)] = self._host_valid
         self._host_valid = hv
+        # the feed that paid for the grow says so (index.add_batch span)
+        tracing.annotate(grew=True)
 
     def per_shard_live(self) -> Optional[np.ndarray]:
         """Live-row count per mesh shard under the row-block layout

@@ -22,6 +22,16 @@ is a real object (so nesting and inheritance stay uniform) but skips id
 generation, attribute work, and retention — near-zero overhead. Hot
 paths that must add literally nothing (the coalescing dispatcher) check
 ``span.sampled``/``current_span()`` before creating anything.
+
+Two clocks. A sampled span entered with ``with`` also opens a
+``jax.profiler.TraceAnnotation`` of the same name on the same thread, so
+while a profile session runs the span appears in the trace's host plane,
+on the profiler's clock, beside the device's operations (``benchmark/
+xplane.py`` names a device's idle gaps from those lines). With no session
+the annotation is a level check and a return. Where the thread's CPU clock
+is cheap (``THREAD_CLOCK``), the span also records the thread's CPU time
+between enter and exit as ``cpu_ms``: wall minus CPU is what the layer
+spent waiting (interpreter lock, device, disk).
 """
 
 from __future__ import annotations
@@ -29,17 +39,51 @@ from __future__ import annotations
 import contextvars
 import json
 import random
-import threading
 import time
-import uuid as uuidlib
 from collections import deque
 from contextlib import contextmanager
 from typing import Any, Iterator, NamedTuple, Optional
+
+# importing jax.profiler initialises no backend (tests/test_import_no_backend)
+from jax.profiler import TraceAnnotation
+
+from weaviate_tpu.monitoring.metrics import TRACE_SPANS
 
 _current_span: contextvars.ContextVar[Optional["Span"]] = \
     contextvars.ContextVar("wv_current_span", default=None)
 
 _UNSET = object()
+
+# the traced segment of a benchmark cell has to fit whole: ~1,200 search
+# requests x 9 spans in 4 s today (PERF.md section 3)
+MAX_SPANS = 32768
+
+
+def _thread_clock_is_cheap() -> bool:
+    """Whether ``time.thread_time_ns`` is worth two reads a span. On a
+    plain Linux host it is ~0.3 us a call at nanosecond steps; on the
+    sealed machines that hold the benchmark's chip it is a 5.9 us call
+    that ticks in 10 ms steps (probe on the chip, PR 26), which cost
+    ``cohere768.search_c20`` 8% of its queries per second and told nothing.
+    The cheapest of five rounds decides, so a preempted round cannot."""
+    cheapest = float("inf")
+    for _ in range(5):
+        t0 = time.perf_counter_ns()
+        for _ in range(10):
+            time.thread_time_ns()
+        cheapest = min(cheapest, (time.perf_counter_ns() - t0) / 10)
+    return cheapest < 2000
+
+
+THREAD_CLOCK = _thread_clock_is_cheap()
+
+
+def _span_id() -> str:
+    return "%016x" % random.getrandbits(64)
+
+
+def _trace_id() -> str:
+    return "%032x" % random.getrandbits(128)
 
 
 class SpanContext(NamedTuple):
@@ -83,7 +127,8 @@ def parse_traceparent(header: str) -> Optional[SpanContext]:
 class Span:
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "start_ns",
                  "end_ns", "attributes", "status", "sampled", "links",
-                 "events", "remote_parent", "_token", "_tracer")
+                 "events", "remote_parent", "_token", "_tracer",
+                 "_annotation", "_cpu_ns")
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
                  parent_id: Optional[str], sampled: bool = True,
@@ -98,15 +143,17 @@ class Span:
         self.trace_id = trace_id
         # unsampled spans exist only to propagate the verdict down the
         # context stack: no ids, no retention, (almost) no work
-        self.span_id = uuidlib.uuid4().hex[:16] if sampled else ""
+        self.span_id = _span_id() if sampled else ""
         self.parent_id = parent_id
         self.start_ns = time.time_ns() if sampled else 0
         self.end_ns: Optional[int] = None
         self.attributes: dict[str, Any] = {}
-        self.links: list[dict] = []
-        self.events: list[dict] = []
+        self.links: Optional[list[dict]] = None
+        self.events: Optional[list[dict]] = None
         self.status = "OK"
         self._token = None
+        self._annotation = None
+        self._cpu_ns = 0
 
     def set(self, **attrs) -> "Span":
         if self.sampled:
@@ -117,6 +164,8 @@ class Span:
         """Timestamped point-in-time annotation (retry attempts, breaker
         skips, dispatcher sheds)."""
         if self.sampled:
+            if self.events is None:
+                self.events = []
             self.events.append({
                 "name": name,
                 "timeUnixNano": time.time_ns(),
@@ -127,6 +176,8 @@ class Span:
     def add_link(self, ctx: Optional[SpanContext], **attrs) -> "Span":
         """Link another trace's span (the N:1 batch<-requests relation)."""
         if self.sampled and ctx is not None:
+            if self.links is None:
+                self.links = []
             self.links.append({
                 "traceId": ctx.trace_id,
                 "spanId": ctx.span_id,
@@ -150,7 +201,11 @@ class Span:
         if self.sampled:
             # open-span registry: lets the assembler tell "parent still
             # executing" apart from "parent evicted from the buffer"
-            self._tracer._open_add(self.span_id)
+            self._tracer._open.add(self.span_id)
+            self._annotation = TraceAnnotation(self.name)
+            self._annotation.__enter__()
+            if THREAD_CLOCK:
+                self._cpu_ns = time.thread_time_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
@@ -158,7 +213,11 @@ class Span:
             self.status = "ERROR"
             self.attributes["error"] = repr(exc)
         if self.sampled:
+            if THREAD_CLOCK:
+                self._cpu_ns = time.thread_time_ns() - self._cpu_ns
             self.end_ns = time.time_ns()
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         if self._token is not None:
             _current_span.reset(self._token)
             self._token = None
@@ -170,6 +229,8 @@ class Span:
         return (end - self.start_ns) / 1e6
 
     def to_dict(self) -> dict:
+        if THREAD_CLOCK and self.end_ns is not None:
+            self.attributes["cpu_ms"] = round(self._cpu_ns / 1e6, 3)
         out = {
             "traceId": self.trace_id,
             "spanId": self.span_id,
@@ -190,33 +251,52 @@ class Span:
         return out
 
 
+class _NoSpan:
+    """What ``Tracer.child`` hands out where no trace is under way: takes
+    attributes, records nothing and leaves the thread's current span alone
+    (a ``Tracer.span`` nested inside still mints its own root, as before)."""
+
+    sampled = False
+
+    def set(self, **attrs) -> "_NoSpan":
+        return self
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        return None
+
+
+_NO_SPAN = _NoSpan()
+
+
 class Tracer:
     """Bounded-retention tracer; disabled/unsampled = near-zero overhead."""
 
-    def __init__(self, max_spans: int = 4096, enabled: bool = True,
+    def __init__(self, max_spans: int = MAX_SPANS, enabled: bool = True,
                  sample_rate: Optional[float] = None):
         self.enabled = enabled
         self.max_spans = max_spans
         # None = follow the tracing_sample_rate runtime knob; a float
         # pins it (unit tests, the bench harness)
         self.sample_rate = sample_rate
-        self._lock = threading.Lock()
         self._rng = random.Random()
-        # deque(maxlen): O(1) append-with-eviction — a full buffer must not
-        # copy 4k entries under the lock on every request
-        self._spans: deque[dict] = deque(maxlen=max_spans)
+        # No lock on the span path. Twenty request threads finishing spans
+        # under one tracer lock convoyed on it (a holder that loses the
+        # interpreter lock stalls every other finisher): 8 us a span
+        # became 40-50 (host count, PR 26). A finished Span is appended as
+        # it is — deque.append with maxlen evicts in O(1) and, like set.add
+        # / discard and list(deque), is one atomic step under the
+        # interpreter lock — and turned into a dict when somebody reads.
+        self._spans: deque[Span] = deque(maxlen=max_spans)
         # span ids currently OPEN (entered, not finished): finished
         # children whose parent is here belong to an in-flight trace,
         # not a truncated one
         self._open: set[str] = set()
 
-    def _open_add(self, span_id: str) -> None:
-        with self._lock:
-            self._open.add(span_id)
-
     def open_span_ids(self) -> set:
-        with self._lock:
-            return set(self._open)
+        return set(self._open)
 
     # -- sampling ----------------------------------------------------------
     def _rate(self) -> float:
@@ -252,7 +332,7 @@ class Tracer:
         else:
             sampled = self._sample()
             s = Span(self, name,
-                     uuidlib.uuid4().hex if sampled else "", None,
+                     _trace_id() if sampled else "", None,
                      sampled=sampled)
         if s.sampled:
             if attrs:
@@ -261,6 +341,18 @@ class Tracer:
                 for ctx in links:
                     s.add_link(ctx)
         return s
+
+    def child(self, name: str, **attrs):
+        """A span only as part of a trace that is under way on this thread:
+        the layer boundaries deep in the served path (``index.search``,
+        ``shard.durable``, ...). With no active span — direct library use, a
+        background thread — they must not each mint a one-span trace of
+        their own (4,000 of them pushed a test's real traces out of the
+        buffer), so the caller gets a span that does nothing."""
+        parent = _current_span.get()
+        if parent is None:
+            return _NO_SPAN
+        return self.span(name, parent=parent, **attrs)
 
     def ingress(self, name: str, traceparent: str = "", **attrs) -> Span:
         """Root-of-request span minted at REST/gRPC ingress: continues an
@@ -274,26 +366,18 @@ class Tracer:
     def _finish(self, span: Span) -> None:
         if not span.sampled:
             return
-        if not self.enabled:
-            with self._lock:
-                self._open.discard(span.span_id)
-            return
-        from weaviate_tpu.monitoring.metrics import TRACE_SPANS
-
-        TRACE_SPANS.inc(name=span.name)
-        d = span.to_dict()
-        with self._lock:
-            self._open.discard(span.span_id)
-            self._spans.append(d)
+        self._open.discard(span.span_id)
+        if self.enabled:
+            TRACE_SPANS.inc(name=span.name)
+            self._spans.append(span)
 
     # -- export ------------------------------------------------------------
     def recent(self, limit: int = 100,
                trace_id: Optional[str] = None) -> list[dict]:
-        with self._lock:
-            spans = list(self._spans)
+        spans = list(self._spans)
         if trace_id:
-            spans = [s for s in spans if s["traceId"] == trace_id]
-        return spans[-limit:]
+            spans = [s for s in spans if s.trace_id == trace_id]
+        return [s.to_dict() for s in spans[-limit:]]
 
     @staticmethod
     def _assemble(group: list[dict], open_ids: set) -> dict:
@@ -345,8 +429,8 @@ class Tracer:
 
     def traces(self, limit: int = 20) -> list[dict]:
         """Assembled span trees, newest first (root span + children)."""
-        with self._lock:
-            spans = list(self._spans)
+        # list(deque) first: one atomic snapshot, then the dicts
+        spans = [s.to_dict() for s in list(self._spans)]
         by_trace: dict[str, list[dict]] = {}
         order: list[str] = []
         for s in spans:
@@ -489,20 +573,8 @@ class Tracer:
         return "".join(json.dumps(self._otlp_record([s])) + "\n"
                        for s in spans)
 
-    def export_jsonl(self, path: str,
-                     trace_id: Optional[str] = None) -> int:
-        with self._lock:
-            spans = list(self._spans)
-        if trace_id:
-            spans = [s for s in spans if s["traceId"] == trace_id]
-        with open(path, "w") as f:
-            for s in spans:
-                f.write(json.dumps(s) + "\n")
-        return len(spans)
-
     def clear(self) -> None:
-        with self._lock:
-            self._spans.clear()
+        self._spans.clear()
 
 
 # -- context helpers (the thread-hop API layers use) ------------------------
