@@ -1,0 +1,448 @@
+"""Concurrent searches on a flat collection share scans: ``FlatIndex``
+behind the coalescing dispatcher, by count and structure (never by wall
+clock). N threads get row for row what serial ``search`` returns in fewer
+batches than requests; whatever decides the compiled program or its arrays
+keeps requests apart; a lone search runs one B = 1 scan and waits for
+nothing; a request queued behind a running batch is led by hand-off, not by
+its poll tick; an error reaches every member of its batch; every row bucket
+is compiled at the first search, not under a later batch."""
+
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from weaviate_tpu.index import dispatch, flat
+from weaviate_tpu.index.dispatch import CoalescingDispatcher, dispatch_group
+from weaviate_tpu.index.flat import ROW_BUCKETS, FlatIndex
+from weaviate_tpu.schema.config import FlatIndexConfig
+
+D, ROWS = 32, 600
+JOIN_S = 30
+
+
+def _index(metric="cosine"):
+    idx = FlatIndex(D, FlatIndexConfig(distance=metric))
+    rng = np.random.default_rng(7)
+    vecs = rng.standard_normal((ROWS, D)).astype(np.float32)
+    idx.add_batch(np.arange(ROWS), vecs)
+    queries = (vecs[:64] + 0.1 * rng.standard_normal((64, D))).astype(
+        np.float32)
+    return idx, queries
+
+
+class _Gate:
+    """Wraps an index's ``run_batch``: holds the FIRST batch until the
+    test opens the gate (so what queues behind it is decided by the test,
+    not the scheduler) and records every batch's rows, k and tags (a
+    request's tag rides in its query's last component)."""
+
+    def __init__(self, idx):
+        self.idx, self.open, self.calls = idx, threading.Event(), []
+        self.real = idx._dispatcher.run_batch
+        idx._dispatcher.run_batch = self
+
+    def __call__(self, q, k, allow, tier_key):
+        assert self.open.wait(JOIN_S)
+        self.calls.append({"rows": q.shape[0], "k": k, "tier_key": tier_key,
+                           "filtered": allow is not None})
+        return self.real(q, k, allow, tier_key=tier_key)
+
+    def wait_leading(self):
+        deadline = time.monotonic() + JOIN_S
+        while not self.idx._dispatcher._draining:
+            assert time.monotonic() < deadline, "nobody took the lead"
+            time.sleep(0.001)
+
+    def wait_pending(self, n):
+        deadline = time.monotonic() + JOIN_S
+        while time.monotonic() < deadline:
+            with self.idx._dispatcher._lock:
+                if len(self.idx._dispatcher._pending) >= n:
+                    return
+            time.sleep(0.001)
+        raise AssertionError(f"{n} requests never queued")
+
+
+def _run_threads(fns):
+    errs, threads = [], []
+
+    def guard(fn):
+        try:
+            fn()
+        except BaseException as e:  # the test re-raises it below
+            errs.append(e)
+
+    for fn in fns:
+        threads.append(threading.Thread(target=guard, args=(fn,)))
+        threads[-1].start()
+    return threads, errs
+
+
+def _join(threads, errs):
+    for t in threads:
+        t.join(JOIN_S)
+        assert not t.is_alive()
+    if errs:
+        raise errs[0]
+
+
+def _assert_same_answers(got, want):
+    """Row for row the same hits. A B = 1 and a B > 1 product differ in
+    their last bits (7.6e-6 here), so a rank may differ inside a tie, or
+    at the k-th place where the tie's other half fell off the end."""
+    assert got.ids.shape == want.ids.shape
+    np.testing.assert_allclose(got.dists, want.dists, rtol=1e-5, atol=1e-4)
+    for g, w, d in zip(got.ids, want.ids, want.dists):
+        for j in np.flatnonzero(g != w):
+            tied = np.flatnonzero(np.abs(d - d[j]) <= 1e-4)
+            assert g[j] in w[tied] or j == len(w) - 1, (g, w, d)
+
+
+@pytest.mark.parametrize("metric", ["cosine", "l2-squared"])
+@pytest.mark.parametrize("masked", [False, True], ids=["plain", "masked"])
+def test_concurrent_searches_match_serial_in_fewer_batches(metric, masked):
+    idx, queries = _index(metric)
+    n = 40
+    ks = [5 if i % 4 else 9 for i in range(n)]          # mixed k
+    mask = None
+    if masked:
+        mask = np.zeros(ROWS, bool)
+        mask[::3] = True
+    # equal content, a different array object per request: one tenant's
+    # precomputed mask arriving with each request
+    serial = [idx.search(queries[i][None], ks[i],
+                         None if mask is None else mask.copy())
+              for i in range(n)]
+    gate = _Gate(idx)
+    got = {}
+
+    def client(i):
+        got[i] = idx.search(queries[i][None], ks[i],
+                            None if mask is None else mask.copy())
+
+    first, errs1 = _run_threads([lambda: client(0)])
+    gate.wait_leading()
+    rest, errs2 = _run_threads(
+        [lambda i=i: client(i) for i in range(1, n)])
+    gate.wait_pending(n - 1)
+    gate.open.set()
+    _join(first + rest, errs1 + errs2)
+    for i in range(n):
+        assert got[i].ids.shape == (1, ks[i])
+        _assert_same_answers(got[i], serial[i])
+        if masked:
+            assert mask[got[i].ids[0]].all()
+    # the leader's lone batch, then the 39 queued: full batches per k
+    assert gate.calls[0]["rows"] == 1
+    cap = ROW_BUCKETS[-1]
+    assert sorted((c["k"], c["rows"]) for c in gate.calls[1:]) == sorted(
+        [(5, cap)] * (30 // cap) + [(5, 30 % cap)]
+        + [(9, cap)] * (9 // cap) + [(9, 9 % cap)])
+    assert all(c["filtered"] == masked for c in gate.calls)
+
+
+def test_incompatible_requests_never_share_a_batch():
+    """k, the allow mask, ``approx_recall``, the ``dispatch_group`` token
+    and the residency epoch each keep a request out of the others' batch;
+    two requests equal in all of them share one."""
+    idx, queries = _index()
+    idx.search(queries[:1], 5)
+    gate = _Gate(idx)
+    mask_a = np.ones(ROWS, bool)
+    mask_b = np.ones(ROWS, bool)
+    mask_b[0] = False
+
+    def in_group(q):
+        with dispatch_group(("hybrid", "rankedFusion")):
+            return idx.search(q, 5)
+
+    variants = {
+        "leader": lambda q: idx.search(q, 5),
+        "base1": lambda q: idx.search(q, 5),
+        "base2": lambda q: idx.search(q, 5),
+        "k": lambda q: idx.search(q, 6),
+        "mask_a": lambda q: idx.search(q, 5, mask_a),
+        "mask_b": lambda q: idx.search(q, 5, mask_b),
+        "approx": lambda q: idx.search(q, 5, approx_recall=0.5),
+        "token": in_group,
+        "epoch": lambda q: idx.search(q, 5),
+    }
+    names = list(variants)
+
+    def send(name):
+        q = queries[:1].copy()
+        q[0, -1] = names.index(name)        # the tag the runner reads
+        return lambda: variants[name](q)
+
+    real, seen = gate.real, []
+
+    def recording(q, k, allow, tier_key):
+        seen.append(sorted(names[int(t)] for t in q[:, -1]))
+        return real(q, k, allow, tier_key=tier_key)
+
+    gate.real = recording
+    threads, errs = _run_threads([send("leader")])
+    gate.wait_leading()
+    queued = [n for n in names if n not in ("leader", "epoch")]
+    more, errs2 = _run_threads([send(n) for n in queued])
+    gate.wait_pending(len(queued))
+    # enqueued under the next residency epoch: what a demote + promote
+    # between two arrivals leaves behind
+    idx._residency_epoch += 1
+    late, errs3 = _run_threads([send("epoch")])
+    gate.wait_pending(len(queued) + 1)
+    gate.open.set()
+    _join(threads + more + late, errs + errs2 + errs3)
+    assert sorted(seen) == sorted(
+        [["base1", "base2"]] + [[n] for n in names
+                                if n not in ("base1", "base2")]), seen
+
+
+def test_a_lone_search_runs_one_b1_scan_and_waits_for_nothing(monkeypatch):
+    idx, queries = _index()
+    idx.search(queries[:1], 5)              # compiles every bucket
+    scans, waits = [], []
+    real_scan, real_wait = flat.flat_search, threading.Event.wait
+    me = threading.get_ident()
+
+    def scan(q, *a, **kw):
+        scans.append(tuple(q.shape))
+        return real_scan(q, *a, **kw)
+
+    def wait(self, timeout=None):
+        if threading.get_ident() == me:
+            waits.append(timeout)
+        return real_wait(self, timeout)
+
+    monkeypatch.setattr(flat, "flat_search", scan)
+    monkeypatch.setattr(threading.Event, "wait", wait)
+    before = idx._dispatcher.handoffs
+    for i in range(5):
+        res = idx.search(queries[i][None], 5)
+        assert res.ids.shape == (1, 5)
+    assert scans == [(1, D)] * 5
+    assert waits == []
+    assert idx._dispatcher.ticks_expired == 0
+    assert idx._dispatcher.handoffs == before
+    # wider than the largest bucket: at its own width, as before
+    wide = np.tile(queries, (2, 1))[:ROW_BUCKETS[-1] + 6]
+    assert idx.search(wide, 5).ids.shape == (ROW_BUCKETS[-1] + 6, 5)
+    assert scans[-1] == (ROW_BUCKETS[-1] + 6, D)
+
+
+def test_rows_are_padded_to_the_buckets_and_dropped_before_hand_back(
+        monkeypatch):
+    idx, queries = _index("l2-squared")
+    idx.search(queries[:1], 5)
+    scans = []
+    real_scan = flat.flat_search
+
+    def scan(q, *a, **kw):
+        scans.append(q.shape[0])
+        return real_scan(q, *a, **kw)
+
+    monkeypatch.setattr(flat, "flat_search", scan)
+    one_by_one = np.concatenate(
+        [idx.search(queries[i][None], 5).ids for i in range(20)])
+    scans.clear()
+    for rows, bucket in ((1, 1), (2, 4), (3, 4), (4, 4), (5, 8), (8, 8)):
+        res = idx.search(queries[:rows], 5)
+        assert res.ids.shape == res.dists.shape == (rows, 5)
+        np.testing.assert_array_equal(res.ids[:20], one_by_one[:rows])
+        assert scans[-1] == bucket
+    assert set(scans) == set(ROW_BUCKETS)
+
+
+def test_a_request_queued_behind_a_batch_is_led_by_hand_off(monkeypatch):
+    """With the poll tick out of reach (an hour), the only way the queued
+    request is ever led is the yielding leader's hand-off."""
+    monkeypatch.setattr(dispatch, "POLL_TICK_S", 3600.0)
+    gate, running = threading.Event(), threading.Event()
+    calls = []
+
+    def run_batch(q, k, allow):
+        calls.append(q.shape[0])
+        running.set()
+        assert gate.wait(JOIN_S)
+        return (np.zeros((q.shape[0], k), np.int64),
+                np.zeros((q.shape[0], k), np.float32))
+
+    disp = CoalescingDispatcher(run_batch)
+    out = {}
+
+    def client(i):
+        out[i] = disp.search(np.full((1, 4), float(i), np.float32), 3)
+
+    first, e1 = _run_threads([lambda: client(0)])
+    assert running.wait(JOIN_S)
+    rest, e2 = _run_threads([lambda: client(1), lambda: client(2)])
+    deadline = time.monotonic() + JOIN_S
+    while len(disp._pending) < 2 and time.monotonic() < deadline:
+        time.sleep(0.001)
+    gate.set()
+    _join(first + rest, e1 + e2)
+    assert sorted(out) == [0, 1, 2]
+    assert calls == [1, 2]
+    assert disp.handoffs == 1
+    assert disp.ticks_expired == 0
+
+
+def test_a_waiter_woken_while_a_leader_is_active_waits_again(monkeypatch):
+    """An heir may wake after a newcomer has taken the lead: it neither
+    leads beside it (``run_batch`` is single-flight) nor loses its answer."""
+    monkeypatch.setattr(dispatch, "POLL_TICK_S", 3600.0)
+    gate, running = threading.Event(), threading.Event()
+    active, calls = [], []
+
+    def run_batch(q, k, allow):
+        active.append(1)
+        assert len(active) == 1             # single-flight
+        calls.append(q.shape[0])
+        running.set()
+        assert gate.wait(JOIN_S)
+        active.pop()
+        return (np.zeros((q.shape[0], k), np.int64),
+                np.zeros((q.shape[0], k), np.float32))
+
+    disp = CoalescingDispatcher(run_batch)
+    out = {}
+
+    def client(i):
+        out[i] = disp.search(np.full((1, 4), float(i), np.float32), 3)
+
+    first, e1 = _run_threads([lambda: client(0)])
+    assert running.wait(JOIN_S)
+    second, e2 = _run_threads([lambda: client(1)])
+    deadline = time.monotonic() + JOIN_S
+    while not disp._pending and time.monotonic() < deadline:
+        time.sleep(0.001)
+    waiter = disp._pending[0]
+    waiter.event.set()                      # woken, no answer, a leader on
+    while waiter.event.is_set() and time.monotonic() < deadline:
+        time.sleep(0.001)
+    assert not waiter.event.is_set() and not waiter.done
+    gate.set()
+    _join(first + second, e1 + e2)
+    assert sorted(out) == [0, 1] and calls == [1, 1]
+    assert disp.ticks_expired == 0
+
+
+def test_an_error_reaches_every_member_and_the_index_stays_usable(
+        monkeypatch):
+    idx, queries = _index()
+    want = idx.search(queries[:1], 5)
+    gate = _Gate(idx)
+    boom = {"left": 1}
+    real_dispatch = idx._dispatch
+
+    def failing(qj, *a, **kw):
+        if qj.shape[0] > 1 and boom["left"]:
+            boom["left"] -= 1
+            raise RuntimeError("scan failed")
+        return real_dispatch(qj, *a, **kw)
+
+    monkeypatch.setattr(idx, "_dispatch", failing)
+    outcomes = {}
+
+    def client(i):
+        try:
+            outcomes[i] = idx.search(queries[i][None], 5)
+        except RuntimeError as e:
+            outcomes[i] = e
+
+    first, e1 = _run_threads([lambda: client(0)])
+    gate.wait_leading()
+    rest, e2 = _run_threads([lambda i=i: client(i) for i in range(1, 6)])
+    gate.wait_pending(5)
+    gate.open.set()
+    _join(first + rest, e1 + e2)
+    assert not isinstance(outcomes[0], Exception)    # its own lone batch
+    assert all(isinstance(outcomes[i], RuntimeError) for i in range(1, 6))
+    np.testing.assert_array_equal(idx.search(queries[:1], 5).ids, want.ids)
+    assert not idx._dispatcher._draining and not idx._dispatcher._pending
+
+
+def test_a_demote_between_enqueue_and_drain_reroutes_the_batch():
+    idx, queries = _index("l2-squared")
+    idx.search(queries[:1], 5)
+    gate = _Gate(idx)
+    got = {}
+
+    def client(i):
+        got[i] = idx.search(queries[i][None], 5)
+
+    first, e1 = _run_threads([lambda: client(0)])
+    gate.wait_leading()
+    rest, e2 = _run_threads([lambda i=i: client(i) for i in range(1, 6)])
+    gate.wait_pending(5)
+    epoch = idx._residency_epoch
+    assert idx.demote_device() > 0
+    assert idx._residency_epoch == epoch + 1
+    gate.open.set()
+    _join(first + rest, e1 + e2)
+    # answered by the warm host tier (exact fp32), all six of them
+    assert not idx.device_resident
+    assert [c["rows"] for c in gate.calls] == [1, 5]
+    assert [c["tier_key"][0] for c in gate.calls] == [epoch, epoch]
+    for i in range(6):
+        _assert_same_answers(got[i], idx.search(queries[i][None], 5))
+    assert idx.promote_device() > 0
+    assert idx._residency_epoch == epoch + 2
+    assert idx.search(queries[:1], 5).ids[0, 0] == 0
+
+
+@pytest.fixture
+def compiles():
+    """Counts XLA backend compiles of this process while it is open."""
+    import jax.monitoring
+
+    count = {"n": 0}
+
+    def listener(event, _secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            count["n"] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        yield count
+    finally:
+        from jax._src import monitoring
+
+        monitoring.unregister_event_duration_listener(listener)
+
+
+@pytest.mark.parametrize("metric", ["cosine", "l2-squared"])
+def test_every_bucket_is_compiled_at_the_first_search(metric, compiles):
+    idx, queries = _index(metric)
+    idx.search(queries[:1], 7)
+    assert compiles["n"] > 0
+    assert (idx.capacity, 7, False, 0.0) in idx._warm_programs
+    after_first = compiles["n"]
+    sizes = (1, 2, 3, 4, 5, 7, 8)
+    for round_ in range(2):
+        for rows in sizes:
+            assert idx.search(queries[:rows], 7).ids.shape == (rows, 7)
+        gate = _Gate(idx)
+        gate.open.set()
+        threads, errs = _run_threads(
+            [lambda i=i: idx.search(queries[i][None], 7) for i in range(24)])
+        _join(threads, errs)
+        idx._dispatcher.run_batch = gate.real
+        assert sum(c["rows"] for c in gate.calls) == 24
+        assert compiles["n"] == after_first, f"round {round_}"
+    # a capacity the store grows into is compiled at ITS first search
+    rng = np.random.default_rng(1)
+    grown = idx.capacity
+    idx.add_batch(np.arange(ROWS, grown + 1),
+                  rng.standard_normal((grown + 1 - ROWS, D)).astype(
+                      np.float32))
+    assert idx.capacity > grown
+    idx.search(queries[:1], 7)
+    assert compiles["n"] > after_first
+    assert {p[0] for p in idx._warm_programs} == {idx.capacity}
+    regrown = compiles["n"]
+    for rows in sizes:
+        idx.search(queries[:rows], 7)
+    assert compiles["n"] == regrown

@@ -10,9 +10,12 @@ goroutines over per-core SIMD, ``shard_read.go:374``).
 Leader-follower, no dedicated thread: any waiter that finds no active
 drainer promotes itself, repeatedly collects every compatible pending
 request (same k, same filter), runs them as ONE batch, and publishes
-results. A leader yields once its own request completes; remaining waiters
-self-promote within one poll tick — no request's latency is bound to
-another's queue, and a crashed leader can't wedge the dispatcher.
+results. A leader yields once its own request completes and HANDS OFF:
+the oldest request still pending is woken to lead at once, so the device
+path never idles while work is queued (a flat scan takes milliseconds —
+a follower left to its poll tick would idle the device for up to ten of
+them). No request's latency is bound to another's queue; the poll tick
+remains only as the safety net for a leader that died without yielding.
 
 Filtered requests coalesce too, when their allow masks are IDENTICAL —
 the common multi-tenant case where every request in a tenant shares one
@@ -81,8 +84,8 @@ def current_dispatch_group():
 
 class _Req:
     __slots__ = ("queries", "k", "allow", "mask_key", "tier_key",
-                 "deadline", "event", "ids", "dists", "error", "span",
-                 "enq_t", "rerank", "group_key")
+                 "deadline", "event", "done", "ids", "dists", "error",
+                 "span", "enq_t", "rerank", "group_key")
 
     def __init__(self, queries: np.ndarray, k: int, allow, deadline=None,
                  tier_key=None, rerank=None):
@@ -121,7 +124,11 @@ class _Req:
             a = np.asarray(allow)
             self.mask_key = (a.shape, a.dtype.str, hash(a.tobytes()))
         self.deadline = deadline  # cluster.resilience.Deadline or None
+        # ``event`` wakes the waiter for either of two reasons: its
+        # result (or error) is in — ``done`` is set FIRST — or a yielding
+        # leader handed it the lead (``done`` still False)
         self.event = threading.Event()
+        self.done = False
         self.ids: Optional[np.ndarray] = None
         self.dists: Optional[np.ndarray] = None
         self.error: Optional[BaseException] = None
@@ -178,19 +185,34 @@ def _masks_equal(a: _Req, b: _Req) -> bool:
     return a.mask_key == b.mask_key and np.array_equal(a.allow, b.allow)
 
 
+# The crashed-leader safety net: a waiter that is neither answered nor
+# handed the lead re-checks for an absent leader this often.
+POLL_TICK_S = 0.02
+
+
 class CoalescingDispatcher:
     """Wraps ``run_batch(queries [B, D], k, allow) -> (ids, dists)``.
 
     ``run_batch`` is guaranteed single-flight (only the current leader calls
-    it), so it may use shared scratch without further locking.
+    it), so it may use shared scratch without further locking. With
+    ``pass_tier_key`` it is also handed the group's ``tier_key=`` — for a
+    runner whose compiled program depends on part of that key (the flat
+    scan's ``approx_recall``).
     """
 
-    def __init__(self, run_batch: Callable, max_batch: int = 64):
+    def __init__(self, run_batch: Callable, max_batch: int = 64,
+                 pass_tier_key: bool = False):
         self.run_batch = run_batch
         self.max_batch = max_batch
+        self.pass_tier_key = pass_tier_key
         self._lock = threading.Lock()
         self._pending: list[_Req] = []
         self._draining = False
+        # how often a yielding leader woke a successor, and how often a
+        # waiter's poll tick ran out instead (the safety net; ~0 in a
+        # healthy process)
+        self.handoffs = 0
+        self.ticks_expired = 0
 
     def search(self, queries: np.ndarray, k: int, allow=None, deadline=None,
                tier_key=None, rerank=None):
@@ -211,12 +233,12 @@ class CoalescingDispatcher:
             self._pending.append(req)
         # Every waiter is a potential leader: whoever finds no active
         # drainer promotes itself and drains until ITS request completes
-        # (plus the group in flight), then yields. Remaining waiters
-        # self-promote within one poll tick, so no request waits on an
-        # exited leader and a crashed leader can't wedge the queue.
-        # Leadership is attempted BEFORE the first wait so an uncontended
-        # query pays zero poll-tick latency — it drains itself immediately.
-        while True:
+        # (plus the group in flight), then yields and wakes the oldest
+        # pending request to lead next (_yield_lead), so no request waits
+        # on an exited leader. Leadership is attempted BEFORE the first
+        # wait, so an uncontended query drains itself immediately and
+        # never waits at all.
+        while not req.done:
             with self._lock:
                 lead = not self._draining and bool(self._pending)
                 if lead:
@@ -225,10 +247,16 @@ class CoalescingDispatcher:
                 try:
                     self._drain(until_done=req)
                 finally:
-                    with self._lock:
-                        self._draining = False
-            if req.event.wait(timeout=0.02):
-                break
+                    self._yield_lead()
+                continue
+            if req.event.wait(timeout=POLL_TICK_S):
+                if not req.done:
+                    # handed the lead, not answered: re-arm and go lead.
+                    # ``done`` is re-read at the loop head AFTER the
+                    # clear, so an answer racing it is never lost
+                    req.event.clear()
+                continue
+            self.ticks_expired += 1
             if req.expired:
                 # shed from the queue BEFORE a leader batches it; a
                 # request already taken in flight just waits its result
@@ -247,6 +275,18 @@ class CoalescingDispatcher:
             raise req.error
         return req.ids, req.dists
 
+    def _yield_lead(self) -> None:
+        """Give up the lead and, with requests still pending, wake the
+        oldest to take it: arrivals of the leader's last batch are led at
+        once, not a poll tick later."""
+        with self._lock:
+            self._draining = False
+            heir = self._pending[0] if self._pending else None
+            if heir is not None:
+                self.handoffs += 1
+        if heir is not None:
+            heir.event.set()
+
     # -- leader ------------------------------------------------------------
     def _take_group(self) -> list[_Req]:
         """Pop the next compatible group under the lock (empty = done).
@@ -262,6 +302,7 @@ class CoalescingDispatcher:
                 r.deadline.require()
             except TimeoutError as e:  # DeadlineExceeded
                 r.error = e
+            r.done = True
             r.event.set()
         return group
 
@@ -280,12 +321,17 @@ class CoalescingDispatcher:
             head_rr = _rerank_key(head)
             while i < len(self._pending) and rows < self.max_batch:
                 r = self._pending[i]
-                if r.k == head.k and r.tier_key == head.tier_key \
+                n = _rows(r.queries)
+                # a group never outgrows max_batch (a runner pads its
+                # rows to a fixed set of sizes up to it); a request wider
+                # than that runs alone
+                if (not group or rows + n <= self.max_batch) \
+                        and r.k == head.k and r.tier_key == head.tier_key \
                         and r.group_key == head.group_key \
                         and _rerank_key(r) == head_rr \
                         and _masks_equal(head, r):
                     group.append(self._pending.pop(i))
-                    rows += _rows(r.queries)
+                    rows += n
                 else:
                     i += 1
             return group
@@ -332,8 +378,8 @@ class CoalescingDispatcher:
 
     def _drain(self, until_done: Optional[_Req] = None) -> None:
         while True:
-            if until_done is not None and until_done.event.is_set():
-                return  # yield leadership; waiters self-promote
+            if until_done is not None and until_done.done:
+                return  # yield leadership; search() hands it off
             group = self._take_group()
             if not group:
                 return
@@ -379,6 +425,10 @@ class CoalescingDispatcher:
                     ids, dists = self.run_batch(
                         q, group[0].k, group[0].allow,
                         rerank=(parts[0][0], rq, rqm))
+                elif self.pass_tier_key:
+                    ids, dists = self.run_batch(
+                        q, group[0].k, group[0].allow,
+                        tier_key=group[0].tier_key)
                 else:
                     ids, dists = self.run_batch(q, group[0].k,
                                                 group[0].allow)
@@ -406,4 +456,5 @@ class CoalescingDispatcher:
 
                     tracing.deactivate(detach_token)
                 for r in group:
+                    r.done = True
                     r.event.set()

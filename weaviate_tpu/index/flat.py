@@ -5,6 +5,11 @@ the slow fallback (scan LSM bucket, per-vector SIMD distance). On TPU it is the
 *primary* fast path: the whole corpus lives in HBM and a query batch is one
 fused masked-matmul + top_k (see SURVEY.md §7 slice 0 and BASELINE.md SIFT1M
 config).
+
+Concurrent searches share scans: ``FlatIndex.search`` enqueues into a
+``CoalescingDispatcher`` (``index/dispatch.py``) whose leader runs every
+compatible pending request as ONE ``flat_search``. The scan is bound by
+reading the corpus, so a batch costs about what one query costs.
 """
 
 from __future__ import annotations
@@ -20,11 +25,14 @@ from weaviate_tpu.index.base import (
     VectorIndex,
     run_tier_stable,
 )
+from weaviate_tpu.index.dispatch import CoalescingDispatcher
 from weaviate_tpu.index.store import DeviceVectorStore
+from weaviate_tpu.monitoring import tracing
 from weaviate_tpu.monitoring.tracing import TRACER
 from weaviate_tpu.ops.distance import MASK_DISTANCE, flat_search
 from weaviate_tpu.ops.topk import masked_topk
 from weaviate_tpu.schema.config import FlatIndexConfig
+from weaviate_tpu.utils.prewarm import isolation_key
 
 
 def make_flat(dims: int, config: Optional[FlatIndexConfig] = None,
@@ -37,6 +45,29 @@ def make_flat(dims: int, config: Optional[FlatIndexConfig] = None,
     if config.quantizer is not None and config.quantizer.enabled:
         return QuantizedFlatIndex(dims, config, raw_path=raw_path)
     return FlatIndex(dims, config)
+
+
+# Row counts a coalesced group is padded to (``flat_search`` is jitted on
+# the query shape); the largest is the dispatcher's ``max_batch``. The scan
+# is bound by reading the corpus, so padded rows cost selection only
+# (~0.01 ms a row at 262,144 x 768) and every size is one more program to
+# compile per (capacity, k). Why no more than 8 (chip runs, PERF.md PR 27):
+# 8 rows a scan is already 3,200 vectors/s of scan capacity against the
+# ~430 a second the host's interpreter lock lets through, while a batch
+# releases all its replies at once: with 64, twenty closed-loop clients
+# fell into ONE cohort after a stall and stayed there (server and clients
+# then take turns: 315/s against 430); with 4 the single-flight leader
+# path is the limit (310-331/s). A lone single-vector request runs the
+# B = 1 program; a request wider than the largest bucket runs at its own
+# width.
+ROW_BUCKETS = (1, 4, 8)
+
+
+def _bucket_rows(rows: int) -> int:
+    for b in ROW_BUCKETS:
+        if rows <= b:
+            return b
+    return rows
 
 
 class FlatIndex(VectorIndex):
@@ -55,6 +86,15 @@ class FlatIndex(VectorIndex):
             normalized=(self.metric == "cosine"),
             mesh=default_mesh(),
         )
+        # bumped on every demote/promote: the dispatcher keys batch
+        # grouping on it, so a request enqueued against one residency
+        # generation never rides a batch of another
+        self._residency_epoch = 0
+        self._dispatcher = CoalescingDispatcher(
+            self._run_batch, max_batch=ROW_BUCKETS[-1], pass_tier_key=True)
+        # (capacity, k, filtered, approx_recall) whose every row bucket is
+        # compiled; written by the dispatcher's leader only (single-flight)
+        self._warm_programs: set[tuple] = set()
 
     # -- VectorIndex ------------------------------------------------------
     def add_batch(self, doc_ids: np.ndarray, vectors: np.ndarray) -> None:
@@ -77,20 +117,10 @@ class FlatIndex(VectorIndex):
         ``est_selectivity`` is accepted for signature parity with the
         planner-aware HNSW path and ignored — a flat scan IS the exact
         plan."""
-        # a tiering demote/promote between the residency check below and
-        # the array access re-routes the query, never fails it
-        return run_tier_stable(
-            lambda: self._search_impl(queries, k, allow_list, approx_recall))
-
-    def _search_impl(
-        self,
-        queries: np.ndarray,
-        k: int,
-        allow_list: Optional[np.ndarray] = None,
-        approx_recall: Optional[float] = None,
-    ) -> SearchResult:
         queries = np.atleast_2d(np.asarray(queries, np.float32))
         if queries.shape[-1] != self.store.dims:
+            # before the enqueue: a malformed request fails alone, never
+            # the batch it would have joined
             raise ValueError(
                 f"query dims {queries.shape[-1]} != index dims {self.store.dims}"
             )
@@ -104,14 +134,81 @@ class FlatIndex(VectorIndex):
                 )
 
                 approx_recall = FLAT_APPROX_RECALL_DEFAULT.get()
+        # a tiering demote/promote between the residency check and the
+        # array access (here or in the dispatcher's leader) surfaces as
+        # ResidencyMoved: re-route, never fail. The retry re-enqueues
+        # under the NEW residency epoch's tier_key.
+        return run_tier_stable(
+            lambda: self._search_tiered(queries, k, allow_list,
+                                        approx_recall))
+
+    def _search_tiered(self, queries: np.ndarray, k: int, allow_list,
+                       approx_recall: float) -> SearchResult:
         if not self.store.device_resident:
             # WARM tier (tiering/): the corpus is demoted to host RAM —
             # serve exactly from there, never re-renting HBM per query
-            from weaviate_tpu.index.hnsw.backend import host_store_topk
-
-            d, ids = host_store_topk(
-                self.store, self.metric, queries, k, allow_list)
+            ids, d = self._host_search(queries, k, allow_list)
             return SearchResult(ids=ids, dists=d)
+        # everything that decides the compiled program or the arrays it
+        # reads, beyond k and the mask (the dispatcher's own keys): the
+        # residency epoch, approx_recall (a static argument of the scan;
+        # range queries pin 0.0) and the prewarm isolation token
+        tier_key = (self._residency_epoch, approx_recall, isolation_key())
+        ids, d = self._dispatcher.search(queries, k, allow_list,
+                                         tier_key=tier_key)
+        return SearchResult(ids=ids, dists=d)
+
+    def _run_batch(self, queries: np.ndarray, k: int, allow_list,
+                   tier_key: tuple):
+        """Single-flight batch runner behind the coalescing dispatcher:
+        one upload, one normalise, one scan and one copy-out for the
+        whole group. Returns (ids, dists) of the group's rows."""
+        approx_recall = tier_key[1]
+        if not self.store.device_resident:
+            # a demotion landed while this group was queued: the leader
+            # re-routes the whole batch to the warm host tier
+            return self._host_search(queries, k, allow_list)
+        rows = queries.shape[0]
+        program = (self.store.capacity, k, allow_list is not None,
+                   approx_recall)
+        if rows <= ROW_BUCKETS[-1] and program not in self._warm_programs:
+            self._warm_buckets(program, allow_list)
+        return self._scan(queries, k, allow_list, approx_recall)
+
+    def _host_search(self, queries: np.ndarray, k: int, allow_list):
+        from weaviate_tpu.index.hnsw.backend import host_store_topk
+
+        d, ids = host_store_topk(
+            self.store, self.metric, queries, k, allow_list)
+        return ids, d
+
+    def _warm_buckets(self, program: tuple, allow_list) -> None:
+        """Compile every row bucket of one (capacity, k, filtered,
+        approx_recall) at its first search — never under whichever later
+        batch happens to be the first of its size. Zero queries through
+        the whole path: the eager normalise is shaped by the rows too."""
+        capacity, k, _, approx_recall = program
+        with TRACER.child("flat.warm", capacity=capacity, k=k,
+                          buckets=list(ROW_BUCKETS)):
+            # child spans of the synthetic scans would read as requests'
+            token = tracing.detach()
+            try:
+                for b in ROW_BUCKETS:
+                    self._scan(np.zeros((b, self.store.dims), np.float32),
+                               k, allow_list, approx_recall)
+            finally:
+                tracing.deactivate(token)
+        # programs of a capacity the store has outgrown are never asked
+        # for again
+        self._warm_programs = {p for p in self._warm_programs
+                               if p[0] == capacity} | {program}
+
+    def _scan(self, queries: np.ndarray, k: int, allow_list,
+              approx_recall: float):
+        rows = queries.shape[0]
+        padded = _bucket_rows(rows)
+        if padded != rows:
+            queries = np.pad(queries, ((0, padded - rows), (0, 0)))
         with TRACER.child("flat.prepare"):
             qj = jnp.asarray(queries)
             if self.metric == "cosine":
@@ -119,13 +216,14 @@ class FlatIndex(VectorIndex):
 
                 qj = normalize(qj)
         with TRACER.child("flat.dispatch", capacity=self.store.capacity,
-                         batch=queries.shape[0]):
+                         batch=padded):
             d, ids = self._dispatch(qj, k, allow_list, approx_recall)
-        # the wait for the device, behind other requests' scans, and the
-        # copy out
+        # the wait for the device and the copy out (both arrays' copies
+        # started before either is waited for); padded rows are dropped
+        # before hand-back
         with TRACER.child("flat.result"):
-            # graftlint: allow[host-sync-in-hot-path] reason=final top-k materialization
-            return SearchResult(ids=np.asarray(ids), dists=np.asarray(d))
+            ids, d = jax.device_get((ids, d))
+            return ids[:rows], d[:rows]
 
     def _dispatch(self, qj, k: int, allow_list, approx_recall: float):
         """Start the scan of one device-resident query batch; returns the
@@ -235,10 +333,16 @@ class FlatIndex(VectorIndex):
         return self.store.host_bytes
 
     def demote_device(self) -> int:
-        return self.store.detach()
+        freed = self.store.detach()
+        if freed:
+            self._residency_epoch += 1
+        return freed
 
     def promote_device(self) -> int:
-        return self.store.attach()
+        gained = self.store.attach()
+        if gained:
+            self._residency_epoch += 1
+        return gained
 
     def stats(self) -> dict:
         s = {
