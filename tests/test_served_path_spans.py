@@ -50,8 +50,12 @@ BATCH_TREE = {
     "grpc.encode": "grpc.BatchObjects",
     "grpc.serialize": "grpc.BatchObjects",
 }
+# what a filter adds: its resolution to an allow mask, on the request's own
+# thread, and the mask's way to the device, once a batch
+FILTERED_TREE = {**SEARCH_TREE, "filter.resolve": "grpc.Search",
+                 "flat.mask": "flat.dispatch"}
 # 10 before the flat path passed the dispatcher: + dispatch.batch
-SEARCH_BUDGET, BATCH_BUDGET = 11, 16
+SEARCH_BUDGET, BATCH_BUDGET, FILTERED_BUDGET = 11, 16, 13
 
 
 def _serve(tmp_dbdir, sync_writes=False, max_workers=None):
@@ -184,6 +188,45 @@ def test_search_gives_one_trace_with_the_tables_children(served, vectors):
              ("flat.prepare", "flat.dispatch", "flat.result")]
     for a, b in zip(order, order[1:]):
         assert a["endTimeUnixNano"] <= b["startTimeUnixNano"]
+
+
+def test_a_filtered_search_adds_two_spans_inside_its_budget(served):
+    client, _ = served
+    batch = _batch(100)
+    for i, o in enumerate(batch.objects):
+        o.properties_json = json.dumps({"tags": [f"t{i % 4}", "all"]})
+    assert not client.batch_objects(batch).errors
+    req = _search()
+    req.where_json = json.dumps({"operator": "ContainsAll", "path": ["tags"],
+                                 "valueText": ["t1", "all"]})
+    # the first filtered search compiles the masked programs (flat.warm)
+    TRACER.clear()
+    client.search(req)
+    first = _one_trace("grpc.Search")
+    assert {n: len(v) for n, v in _check_tree(
+        first, FILTERED_TREE, "grpc.Search").items()} == dict.fromkeys(
+            FILTERED_TREE, 1)
+    assert len(first) <= FILTERED_BUDGET
+    TRACER.clear()
+    reply = client.search(req)
+    assert all("t1" in json.loads(h.properties_json)["tags"]
+               for h in reply.results[0].hits)
+    spans = _one_trace("grpc.Search")
+    by_name = _check_tree(spans, FILTERED_TREE, "grpc.Search")
+    assert {n: len(v) for n, v in by_name.items()} == {
+        n: 1 for n in FILTERED_TREE if n != "flat.warm"}
+    assert len(spans) < FILTERED_BUDGET
+    resolved = by_name["filter.resolve"][0]["attributes"]
+    assert (resolved["source"], resolved["allowed"], resolved["tags"]) == (
+        "inverted", 25, 2)
+    assert by_name["flat.mask"][0]["attributes"]["bytes"] == \
+        by_name["flat.dispatch"][0]["attributes"]["capacity"]
+    assert by_name["dispatch.batch"][0]["attributes"]["filtered"] is True
+    # resolved before the index is asked, uploaded before the scan starts
+    assert by_name["filter.resolve"][0]["endTimeUnixNano"] <= \
+        by_name["index.search"][0]["startTimeUnixNano"]
+    assert by_name["flat.prepare"][0]["endTimeUnixNano"] <= \
+        by_name["flat.mask"][0]["startTimeUnixNano"]
 
 
 def test_batch_objects_gives_one_trace_with_the_tables_children(served):

@@ -31,6 +31,14 @@ TENANT_COLD = "COLD"
 TENANT_FROZEN = "FROZEN"
 
 
+def _leaf_values(flt: Filter) -> int:
+    """How many values a filter's leaves name (``ContainsAll`` on two tags:
+    2), for the ``filter.resolve`` span."""
+    if flt.operands:
+        return sum(_leaf_values(o) for o in flt.operands)
+    return len(flt.value) if isinstance(flt.value, (list, tuple)) else 1
+
+
 class TenantNotActive(RuntimeError):
     """Request addressed a COLD/FROZEN (or mid-transition) tenant — a
     client error (HTTP 422 / gRPC FAILED_PRECONDITION), not a server
@@ -1108,6 +1116,7 @@ class Collection:
             QUERY_DURATION,
         )
         from weaviate_tpu.monitoring.slow_query import REPORTER
+        from weaviate_tpu.monitoring.tracing import TRACER
         from weaviate_tpu.serving import context as serving_ctx
 
         # end-to-end deadline (serving/context.py): an expired request is
@@ -1146,24 +1155,33 @@ class Collection:
                 allow = None
                 est_sel = None
                 if flt is not None:
-                    # resident plane first: a hot predicate serves from
-                    # its bitmap (and coalesces in the dispatcher by
-                    # (plane_id, version)) instead of materializing a
-                    # fresh full-corpus mask per query; the sketch
-                    # estimate rides along for the planner's trace span
-                    plane = shard.filter_planes.lookup(flt)
-                    allow = (plane if plane is not None
-                             else shard.allow_list(flt))
-                    try:
-                        est_sel = shard.inverted.estimate_selectivity(flt)
-                    except Exception:
-                        # estimator gaps never fail a query
-                        import logging
+                    with TRACER.child("filter.resolve") as fspan:
+                        # resident plane first: a hot predicate serves
+                        # from its bitmap (and coalesces in the dispatcher
+                        # by (plane_id, version)) instead of materializing
+                        # a fresh full-corpus mask per query; the sketch
+                        # estimate rides along for the planner's trace span
+                        plane = shard.filter_planes.lookup(flt)
+                        allow = (plane if plane is not None
+                                 else shard.allow_list(flt))
+                        try:
+                            est_sel = shard.inverted.estimate_selectivity(
+                                flt)
+                        except Exception:
+                            # estimator gaps never fail a query
+                            import logging
 
-                        logging.getLogger(
-                            "weaviate_tpu.core.collection").debug(
-                            "selectivity estimate failed", exc_info=True)
-                        est_sel = None
+                            logging.getLogger(
+                                "weaviate_tpu.core.collection").debug(
+                                "selectivity estimate failed", exc_info=True)
+                            est_sel = None
+                        if fspan.sampled:
+                            fspan.set(
+                                source=("inverted" if plane is None
+                                        else "plane"),
+                                allowed=(int(np.count_nonzero(allow))
+                                         if plane is None else plane.count()),
+                                tags=_leaf_values(flt))
                 tr.stage("filter")
                 if deadline is not None:
                     deadline.require()  # filter work may have spent it
@@ -1197,8 +1215,6 @@ class Collection:
             b = 1
         else:
             b = np.atleast_2d(queries).shape[0]
-        from weaviate_tpu.monitoring.tracing import TRACER
-
         out: list[list[tuple[StorageObject, float]]] = []
         with TRACER.child("objects.fetch") as span:
             for qi in range(b):

@@ -242,7 +242,10 @@ class FlatIndex(VectorIndex):
         cap = corpus.shape[0]
         allow = None
         if allow_list is not None:
-            allow = _pad_mask(allow_list, cap)
+            # one bool a row, padded to the capacity and uploaded: once a
+            # batch, since only requests of one mask share a batch
+            with TRACER.child("flat.mask", bytes=cap):
+                allow = _pad_mask(allow_list, cap)
         chunk = self.config.search_chunk_size
         # optional fused Pallas kernel (env-gated; see pallas_flat.py).
         # Taken only where its semantics match the request: bf16 is the
