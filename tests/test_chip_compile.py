@@ -84,6 +84,26 @@ def test_flat_search_compiles_at_serving_defaults(one_chip, metric):
     _fits(compiled)
 
 
+@pytest.mark.parametrize("rows", [4, 8])
+def test_flat_search_compiles_with_a_mask_a_row(one_chip, rows):
+    """What a flat collection runs for a group of filtered requests whose
+    masks differ: a [rows, capacity] allow mask, sliced chunk by chunk
+    (``yfcc192.filtered_c20``: 524,288 x 192, l2-squared, k = 10)."""
+    from weaviate_tpu.ops.distance import flat_search
+
+    cap, dims = 524288, 192
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = flat_search.lower(
+        s((rows, dims), jnp.float32), s((cap, dims), jnp.float32), k=K,
+        metric="l2-squared", valid_mask=s((cap,), jnp.bool_),
+        allow_mask=s((rows, cap), jnp.bool_),
+        corpus_sqnorms=s((cap,), jnp.float32), chunk_size=CHUNK,
+        precision="bf16", approx_recall=0.0).compile()
+    assert compiled.memory_analysis().argument_size_in_bytes >= \
+        cap * dims * 4 + rows * cap
+    _fits(compiled)
+
+
 @pytest.mark.parametrize("corpus_dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 def test_pallas_flat_topk_compiles(one_chip, corpus_dtype):

@@ -180,6 +180,70 @@ def test_filtered_requests_with_identical_masks_coalesce():
     assert len(calls) < 32
 
 
+def test_a_runner_that_takes_a_mask_a_row_is_handed_them_as_a_list():
+    """``per_row_masks``: filtered requests share a batch whatever their
+    masks; the runner gets the members' masks aligned with their row
+    counts, filtered and unfiltered never mix, and no mask is digested."""
+    from weaviate_tpu.index.dispatch import _Req
+    from weaviate_tpu.monitoring.metrics import DISPATCH_FILTERED_STACKED
+
+    calls = []
+    hold = threading.Event()
+
+    def run_batch(q, k, masks, rows):
+        hold.wait(timeout=10)
+        calls.append((q[:, 0].tolist(), masks, rows))
+        return (np.tile(q[:, :1].astype(np.int64), (1, k)),
+                np.zeros((q.shape[0], k), np.float32))
+
+    disp = CoalescingDispatcher(run_batch, max_batch=8, per_row_masks=True)
+    # the digest stays the default: runners without the capability need it
+    assert _Req(np.zeros((1, 4)), 3, np.ones(4, bool)).mask_key is not None
+    masks = {i: np.arange(16) % (i + 2) == 0 for i in range(1, 5)}
+    masks[5] = None                          # an unfiltered request
+    widths = {1: 1, 2: 2, 3: 1, 4: 1, 5: 1}
+    results = {}
+
+    def client(i):
+        results[i] = disp.search(
+            np.full((widths.get(i, 1), 4), float(i), np.float32), 3,
+            masks.get(i, masks[1]))
+
+    stacked = DISPATCH_FILTERED_STACKED.value()
+    first = threading.Thread(target=client, args=(0,))
+    first.start()
+    for _ in range(10_000):
+        if disp._draining:
+            break
+        time.sleep(0.001)
+    rest = [threading.Thread(target=client, args=(i,)) for i in range(1, 6)]
+    for t in rest:
+        t.start()
+    for _ in range(10_000):
+        with disp._lock:
+            if len(disp._pending) == 5:
+                assert all(r.mask_key is None for r in disp._pending)
+                break
+        time.sleep(0.001)
+    hold.set()
+    for t in [first] + rest:
+        t.join(10)
+        assert not t.is_alive()
+    for i, (ids, _) in results.items():
+        assert ids.shape == (widths.get(i, 1), 3) and (ids == i).all()
+    assert len(calls) == 3
+    assert calls[0] == ([0.0], [masks[1]], [1])
+    by_filter = {c[1] is None: c for c in calls[1:]}
+    assert by_filter[True] == ([5.0], None, [1])
+    tags, handed, rows = by_filter[False]
+    # aligned: member j's mask and row count, in the order of its rows
+    members = [t for j, t in enumerate(tags) if j == 0 or t != tags[j - 1]]
+    assert sorted(members) == [1.0, 2.0, 3.0, 4.0]
+    assert rows == [widths[int(t)] for t in members]
+    assert all(h is masks[int(t)] for h, t in zip(handed, members))
+    assert DISPATCH_FILTERED_STACKED.value() == stacked + 1
+
+
 def test_hnsw_concurrent_search_matches_serial_with_bounded_tail():
     rng = np.random.default_rng(0)
     n, d, k = 4000, 32, 10

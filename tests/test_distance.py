@@ -95,6 +95,47 @@ def test_flat_search_masks(rng):
     assert (ids[2:] == -1).all()
 
 
+@pytest.mark.parametrize("metric", ["l2-squared", "cosine"])
+@pytest.mark.parametrize("chunk", [0, 32, 40], ids=["whole", "chunks", "tail"])
+@pytest.mark.parametrize("with_valid", [True, False], ids=["valid", "novalid"])
+def test_flat_search_a_mask_a_row_equals_a_call_a_mask(rng, metric, chunk,
+                                                       with_valid):
+    """A [B, N] allow mask filters row i of the queries by row i of the
+    mask: bit-equal to B scans of the same queries under the [N] rows,
+    among them a row that allows fewer than k and an all-False (padded)
+    one. 128 rows: 32 divides them, 40 leaves a tail of 8."""
+    b, n, k = 5, 128, 6
+    q = rng.standard_normal((b, 16)).astype(np.float32)
+    c = rng.standard_normal((n, 16)).astype(np.float32)
+    if metric == "cosine":
+        q, c = np.asarray(normalize(q)), np.asarray(normalize(c))
+    valid = np.ones(n, bool)
+    valid[100:] = False
+    masks = rng.random((b, n)) < 0.4
+    masks[1] = False
+    masks[1, [3, 60, 125]] = True        # two live rows allowed: under k
+    masks[4] = False                     # a padded row
+    common = dict(
+        k=k, metric=metric, chunk_size=chunk, precision="bf16",
+        valid_mask=jnp.asarray(valid) if with_valid else None,
+        corpus_sqnorms=(jnp.asarray((c * c).sum(1))
+                        if metric == "l2-squared" else None))
+    d2, i2 = flat_search(jnp.asarray(q), jnp.asarray(c),
+                         allow_mask=jnp.asarray(masks), **common)
+    d2, i2 = np.asarray(d2), np.asarray(i2)
+    for row in range(b):
+        d1, i1 = flat_search(jnp.asarray(q), jnp.asarray(c),
+                             allow_mask=jnp.asarray(masks[row]), **common)
+        np.testing.assert_array_equal(i2[row], np.asarray(i1)[row])
+        np.testing.assert_array_equal(d2[row], np.asarray(d1)[row])
+        hits = i2[row][i2[row] >= 0]
+        assert masks[row][hits].all()    # no other row's mask leaks in
+        live = masks[row] & valid if with_valid else masks[row]
+        assert len(hits) == min(k, int(live.sum()))
+    assert (i2[4] == -1).all() and (d2[4] >= 1e30).all()
+    assert len(i2[1][i2[1] >= 0]) == (2 if with_valid else 3)
+
+
 def test_gather_distance(rng):
     q = rng.standard_normal((2, 8)).astype(np.float32)
     c = rng.standard_normal((30, 8)).astype(np.float32)

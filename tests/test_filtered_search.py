@@ -177,20 +177,22 @@ def test_a_promoted_plane_answers_as_the_inverted_index_did(deployment,
 
 class _Gate:
     """Holds the index's FIRST batch until the test opens the gate, so what
-    queues behind it is decided by the test; records each batch's rows."""
+    queues behind it is decided by the test; records each batch's rows and
+    how many masks it was handed."""
 
     def __init__(self, col):
         (shard,) = col._search_shards()
         self.dispatcher = shard._vector_indexes[DEFAULT_VECTOR]._dispatcher
-        self.open, self.rows = threading.Event(), []
+        self.open, self.rows, self.masks = threading.Event(), [], []
         self.real = self.dispatcher.run_batch
         self.dispatcher.run_batch = self
 
-    def __call__(self, q, k, allow, tier_key):
+    def __call__(self, q, k, masks, tier_key, rows):
         assert self.open.wait(JOIN_S)
-        assert allow is not None
+        assert masks is not None and len(masks) == len(rows)
         self.rows.append(q.shape[0])
-        return self.real(q, k, allow, tier_key=tier_key)
+        self.masks.append(len({id(m) for m in masks}))
+        return self.real(q, k, masks, tier_key=tier_key, rows=rows)
 
     def wait(self, what):
         deadline = time.monotonic() + JOIN_S
@@ -201,11 +203,11 @@ class _Gate:
 
 @pytest.mark.parametrize("same_mask", [False, True],
                          ids=["eight_masks", "one_mask"])
-def test_concurrent_requests_share_a_scan_only_under_one_mask(
+def test_concurrent_requests_share_a_scan_whatever_their_masks(
         deployment, served, same_mask):
-    """Eight requests queued behind a held batch: with eight different
-    masks each runs its own scan and gets its own mask's answer; with one
-    mask (equal content, an array a request) they share one."""
+    """Eight requests queued behind a held batch share ONE scan, a mask a
+    row, and each gets its own mask's answer: with eight different filters
+    as with one (equal content, an array a request)."""
     _, col = served
     filters = deployment.filters([1])
     if same_mask:
@@ -242,7 +244,10 @@ def test_concurrent_requests_share_a_scan_only_under_one_mask(
         t.join(JOIN_S)
         assert not t.is_alive()
     assert not errs, errs
-    assert gate.rows == ([1, 8] if same_mask else [1] * 9)
+    assert gate.rows == [1, 8]
+    # one filter sent nine times is promoted to a plane on the way: some
+    # members then carry the plane's one bitmap, the others an array each
+    assert gate.masks == [1, 8] or (same_mask and 1 <= gate.masks[1] <= 8)
     # slot s sent query s under filter chosen[s]: held to exactly that pair
     pair_filters = [filters[chosen[s]] if s < 9 else ()
                     for s in range(len(deployment.queries))]

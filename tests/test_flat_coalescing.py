@@ -2,10 +2,11 @@
 behind the coalescing dispatcher, by count and structure (never by wall
 clock). N threads get row for row what serial ``search`` returns in fewer
 batches than requests; whatever decides the compiled program or its arrays
-keeps requests apart; a lone search runs one B = 1 scan and waits for
+keeps requests apart, and an allow mask does not: filtered requests share a
+scan whatever their masks, a mask a row; a lone search runs one B = 1 scan and waits for
 nothing; a request queued behind a running batch is led by hand-off, not by
 its poll tick; an error reaches every member of its batch; every row bucket
-is compiled at the first search, not under a later batch."""
+and mask form is compiled at the first search, not under a later batch."""
 
 import threading
 import time
@@ -43,11 +44,17 @@ class _Gate:
         self.real = idx._dispatcher.run_batch
         idx._dispatcher.run_batch = self
 
-    def __call__(self, q, k, allow, tier_key):
+    def __call__(self, q, k, masks, tier_key, rows):
         assert self.open.wait(JOIN_S)
+        assert sum(rows) == q.shape[0]
+        assert masks is None or len(masks) == len(rows)
         self.calls.append({"rows": q.shape[0], "k": k, "tier_key": tier_key,
-                           "filtered": allow is not None})
-        return self.real(q, k, allow, tier_key=tier_key)
+                           "filtered": masks is not None,
+                           "masks": len({id(m) for m in masks or ()})})
+        return self.real(q, k, masks, tier_key=tier_key, rows=rows)
+
+    def close(self):
+        self.idx._dispatcher.run_batch = self.real
 
     def wait_leading(self):
         deadline = time.monotonic() + JOIN_S
@@ -63,6 +70,20 @@ class _Gate:
                     return
             time.sleep(0.001)
         raise AssertionError(f"{n} requests never queued")
+
+
+def _behind_a_held_batch(idx, leader, queued):
+    """Run ``leader`` (its lone batch is held), pile ``queued`` up behind
+    it, let go, join; returns what the runner was handed, batch by batch."""
+    gate = _Gate(idx)
+    first, e1 = _run_threads([leader])
+    gate.wait_leading()
+    rest, e2 = _run_threads(queued)
+    gate.wait_pending(len(queued))
+    gate.open.set()
+    _join(first + rest, e1 + e2)
+    gate.close()
+    return gate.calls
 
 
 def _run_threads(fns):
@@ -144,15 +165,18 @@ def test_concurrent_searches_match_serial_in_fewer_batches(metric, masked):
 
 
 def test_incompatible_requests_never_share_a_batch():
-    """k, the allow mask, ``approx_recall``, the ``dispatch_group`` token
-    and the residency epoch each keep a request out of the others' batch;
-    two requests equal in all of them share one."""
+    """k, ``approx_recall``, the ``dispatch_group`` token, the residency
+    epoch and filtered against unfiltered each keep a request out of the
+    others' batch; two requests equal in all of them share one, and two
+    filtered ones share a scan under DIFFERENT masks, their answers
+    differing as the masks do."""
     idx, queries = _index()
     idx.search(queries[:1], 5)
     gate = _Gate(idx)
-    mask_a = np.ones(ROWS, bool)
-    mask_b = np.ones(ROWS, bool)
-    mask_b[0] = False
+    mask_a = np.zeros(ROWS, bool)
+    mask_a[::2] = True
+    mask_b = ~mask_a
+    answers = {}
 
     def in_group(q):
         with dispatch_group(("hybrid", "rankedFusion")):
@@ -163,8 +187,10 @@ def test_incompatible_requests_never_share_a_batch():
         "base1": lambda q: idx.search(q, 5),
         "base2": lambda q: idx.search(q, 5),
         "k": lambda q: idx.search(q, 6),
-        "mask_a": lambda q: idx.search(q, 5, mask_a),
-        "mask_b": lambda q: idx.search(q, 5, mask_b),
+        "mask_a": lambda q: answers.setdefault(
+            "a", idx.search(q, 5, mask_a)),
+        "mask_b": lambda q: answers.setdefault(
+            "b", idx.search(q, 5, mask_b)),
         "approx": lambda q: idx.search(q, 5, approx_recall=0.5),
         "token": in_group,
         "epoch": lambda q: idx.search(q, 5),
@@ -178,9 +204,9 @@ def test_incompatible_requests_never_share_a_batch():
 
     real, seen = gate.real, []
 
-    def recording(q, k, allow, tier_key):
+    def recording(q, k, masks, tier_key, rows):
         seen.append(sorted(names[int(t)] for t in q[:, -1]))
-        return real(q, k, allow, tier_key=tier_key)
+        return real(q, k, masks, tier_key=tier_key, rows=rows)
 
     gate.real = recording
     threads, errs = _run_threads([send("leader")])
@@ -195,9 +221,82 @@ def test_incompatible_requests_never_share_a_batch():
     gate.wait_pending(len(queued) + 1)
     gate.open.set()
     _join(threads + more + late, errs + errs2 + errs3)
+    shared = (["base1", "base2"], ["mask_a", "mask_b"])
     assert sorted(seen) == sorted(
-        [["base1", "base2"]] + [[n] for n in names
-                                if n not in ("base1", "base2")]), seen
+        list(shared) + [[n] for n in names
+                        if n not in shared[0] + shared[1]]), seen
+    assert (answers["a"].ids % 2 == 0).all()
+    assert (answers["b"].ids % 2 == 1).all()
+
+
+@pytest.mark.parametrize("metric", ["cosine", "l2-squared"])
+def test_different_masks_share_scans_each_answer_inside_its_own_mask(
+        metric, monkeypatch):
+    """Requests whose masks all differ (in length too: each is as long as
+    the doc-id space was when its filter resolved) share scans, a mask a
+    query row; a group of 5 pads to 8 rows whose padded masks allow
+    nothing; a request of several rows repeats its one mask over them."""
+    idx, queries = _index(metric)
+    rng = np.random.default_rng(3)
+    k, n = 5, 14
+    masks = [rng.random(ROWS - 40 * (i % 3)) < 0.3 for i in range(n)]
+    masks[2] = np.zeros(ROWS, bool)
+    masks[2][[5, 9, 11]] = True              # allows fewer than k rows
+    wide = 6                                 # this client sends two rows
+
+    def ask(i):
+        q = queries[i:i + 2] if i == wide else queries[i][None]
+        return idx.search(q, k, masks[i])
+
+    serial = [ask(i) for i in range(n)]
+    scans = []
+    real_scan = flat.flat_search
+
+    def scan(q, *a, allow_mask=None, **kw):
+        scans.append((q.shape[0], np.asarray(allow_mask)))
+        return real_scan(q, *a, allow_mask=allow_mask, **kw)
+
+    monkeypatch.setattr(flat, "flat_search", scan)
+
+    def held(leader, queued):
+        got = {}
+        calls = _behind_a_held_batch(
+            idx, lambda: got.update({leader: ask(leader)}),
+            [lambda i=i: got.update({i: ask(i)}) for i in queued])
+        assert sorted(got) == sorted([leader, *queued])
+        for i, res in got.items():
+            _assert_same_answers(res, serial[i])
+            hit = res.ids[res.ids >= 0]
+            assert masks[i][hit].all(), i    # inside ITS mask, no other's
+        return calls
+
+    # 13 requests, 14 rows behind the leader: two scans, not thirteen
+    calls = held(0, range(1, n))
+    # (the two-row request falls into the first or the second of them)
+    assert [(c["rows"], c["masks"]) for c in calls] in (
+        [(1, 1), (8, 8), (6, 5)], [(1, 1), (8, 7), (6, 6)])
+    assert (serial[2].ids[0, 3:] == -1).all() and serial[2].ids[0, 2] >= 0
+    assert [rows for rows, _ in scans[-3:]] == [1, 8, 8]
+    assert [m.shape for _, m in scans[-3:]] == [
+        (idx.capacity,), (8, idx.capacity), (8, idx.capacity)]
+    assert not scans[-1][1][6:].any()        # padded rows allow nothing
+    # a group of 5 pads to 8
+    calls = held(0, range(1, 6))
+    assert [(c["rows"], c["masks"]) for c in calls] == [(1, 1), (5, 5)]
+    rows, stacked = scans[-1]
+    assert rows == 8 and stacked.shape == (8, idx.capacity)
+    assert stacked[:5].any(axis=1).all() and not stacked[5:].any()
+    # two rows, one mask: the same mask row twice, then the next member's
+    calls = held(0, [wide, 7])
+    assert [(c["rows"], c["masks"]) for c in calls] == [(1, 1), (3, 2)]
+    rows, stacked = scans[-1]
+    assert rows == 4
+    first = 0 if stacked[0, :len(masks[wide])].tolist() == \
+        masks[wide].tolist() else 1
+    np.testing.assert_array_equal(stacked[first], stacked[first + 1])
+    np.testing.assert_array_equal(
+        stacked[first, :len(masks[wide])], masks[wide])
+    assert not stacked[first, len(masks[wide]):].any()
 
 
 def test_a_lone_search_runs_one_b1_scan_and_waits_for_nothing(monkeypatch):
@@ -413,25 +512,42 @@ def compiles():
         monitoring.unregister_event_duration_listener(listener)
 
 
+@pytest.mark.parametrize("filtered", [False, True],
+                         ids=["plain", "filtered"])
 @pytest.mark.parametrize("metric", ["cosine", "l2-squared"])
-def test_every_bucket_is_compiled_at_the_first_search(metric, compiles):
+def test_every_bucket_is_compiled_at_the_first_search(metric, filtered,
+                                                      compiles):
+    """After the first search of a program no batch of 1-8 rows compiles:
+    filtered, neither under one mask (the [capacity] form) nor under
+    masks that differ (the [rows, capacity] form, 2-8 rows)."""
     idx, queries = _index(metric)
-    idx.search(queries[:1], 7)
+    rng = np.random.default_rng(5)
+    # a mask a client, of differing lengths; None where nothing filters
+    masks = [rng.random(ROWS - 7 * i) < 0.5 if filtered else None
+             for i in range(24)]
+    idx.search(queries[:1], 7, masks[0])
     assert compiles["n"] > 0
-    assert (idx.capacity, 7, False, 0.0) in idx._warm_programs
+    assert (idx.capacity, 7, filtered, 0.0) in idx._warm_programs
     after_first = compiles["n"]
     sizes = (1, 2, 3, 4, 5, 7, 8)
     for round_ in range(2):
         for rows in sizes:
-            assert idx.search(queries[:rows], 7).ids.shape == (rows, 7)
-        gate = _Gate(idx)
-        gate.open.set()
-        threads, errs = _run_threads(
-            [lambda i=i: idx.search(queries[i][None], 7) for i in range(24)])
-        _join(threads, errs)
-        idx._dispatcher.run_batch = gate.real
-        assert sum(c["rows"] for c in gate.calls) == 24
-        assert compiles["n"] == after_first, f"round {round_}"
+            assert idx.search(queries[:rows], 7,
+                              masks[rows]).ids.shape == (rows, 7)
+        # behind a held batch: groups of every size up to the cap, each
+        # member under its own mask, then all under ONE mask object
+        for own_mask in (True, False):
+            for waiting in (2, 3, 5, 8, 23):
+                calls = _behind_a_held_batch(
+                    idx, lambda: idx.search(queries[0][None], 7, masks[0]),
+                    [lambda i=i: idx.search(
+                        queries[i][None], 7, masks[i if own_mask else 0])
+                     for i in range(1, waiting + 1)])
+                assert sum(c["rows"] for c in calls) == waiting + 1
+                if filtered:
+                    assert calls[1]["masks"] == (
+                        min(waiting, ROW_BUCKETS[-1]) if own_mask else 1)
+                assert compiles["n"] == after_first, (round_, waiting)
     # a capacity the store grows into is compiled at ITS first search
     rng = np.random.default_rng(1)
     grown = idx.capacity
@@ -439,10 +555,10 @@ def test_every_bucket_is_compiled_at_the_first_search(metric, compiles):
                   rng.standard_normal((grown + 1 - ROWS, D)).astype(
                       np.float32))
     assert idx.capacity > grown
-    idx.search(queries[:1], 7)
+    idx.search(queries[:1], 7, masks[0])
     assert compiles["n"] > after_first
     assert {p[0] for p in idx._warm_programs} == {idx.capacity}
     regrown = compiles["n"]
     for rows in sizes:
-        idx.search(queries[:rows], 7)
+        idx.search(queries[:rows], 7, masks[rows])
     assert compiles["n"] == regrown

@@ -128,6 +128,46 @@ def test_mesh_filtered_search(tmp_dbdir):
         db.close()
 
 
+def test_a_mesh_flat_index_keeps_one_mask_a_batch():
+    """The mesh scan takes one mask a batch, so a ``FlatIndex`` over a mesh
+    store does not declare ``per_row_masks``: concurrent requests with
+    different masks never share a batch, and each answers inside its own."""
+    import threading
+
+    from weaviate_tpu.index.flat import FlatIndex
+
+    idx = FlatIndex(16, FlatIndexConfig(distance="l2-squared"))
+    assert idx.store.mesh is not None
+    assert not idx._dispatcher.per_row_masks
+    rng = np.random.default_rng(4)
+    vecs = rng.standard_normal((200, 16)).astype(np.float32)
+    idx.add_batch(np.arange(200), vecs)
+    masks = [np.arange(200) % 3 == r for r in range(3)]
+    handed, real = [], idx._dispatcher.run_batch
+
+    def run_batch(q, k, allow, **kw):
+        handed.append((q.shape[0], allow))
+        return real(q, k, allow, **kw)
+
+    idx._dispatcher.run_batch = run_batch
+    got = {}
+
+    def client(i):
+        got[i] = idx.search(vecs[i][None], 5, masks[i % 3])
+
+    threads = [threading.Thread(target=client, args=(i,)) for i in range(12)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(60)
+        assert not t.is_alive()
+    assert sum(rows for rows, _ in handed) == 12
+    assert all(any(m is allow for m in masks) for _, allow in handed)
+    for i, res in got.items():
+        assert res.ids[0, 0] == i            # its own row: its mask has it
+        assert masks[i % 3][res.ids[0]].all()
+
+
 def test_sharded_maxsim_matches_single_device():
     """Late-interaction rescore sharded over the candidate axis of the
     8-device mesh must match the single-device einsum exactly (the

@@ -17,14 +17,24 @@ a follower left to its poll tick would idle the device for up to ten of
 them). No request's latency is bound to another's queue; the poll tick
 remains only as the safety net for a leader that died without yielding.
 
-Filtered requests coalesce too, when their allow masks are IDENTICAL —
-the common multi-tenant case where every request in a tenant shares one
-precomputed mask (the underlying kernel applies one mask per batch, so
-only mask-equal requests may share it). Identity is a content digest
-computed once per request at enqueue, verified with an exact compare
-before grouping so a hash collision can never leak one tenant's mask
-onto another's query. Requests with distinct masks still run as
-singleton batches in arrival order.
+Filtered requests coalesce too. How far depends on what the RUNNER can
+apply, which it declares at construction (``per_row_masks``):
+
+- A runner that applies one mask a batch (the graph walk: ``HNSWIndex``,
+  the multi-target runners) shares a batch among requests whose allow
+  masks are IDENTICAL — the common multi-tenant case where every request
+  in a tenant shares one precomputed mask. Identity is a content digest
+  computed once per request at enqueue, verified with an exact compare
+  before grouping so a hash collision can never leak one tenant's mask
+  onto another's query. Requests with distinct masks run as singleton
+  batches in arrival order.
+- A runner that applies one mask a ROW (the flat scan) shares a batch
+  among filtered requests whatever their masks: it is handed the
+  members' masks as a list aligned with the requests and stacks them.
+  Nothing is digested or compared: each row has its own mask, so there
+  is nothing to collide.
+
+Filtered and unfiltered requests never share a batch under either rule.
 
 Tracing (docs/tracing.md): the batch/request relation is N:1 — several
 requests from DIFFERENT traces share one device batch. Each drained
@@ -52,6 +62,7 @@ from weaviate_tpu.monitoring.metrics import (
     DISPATCH_EXPIRED,
     DISPATCH_FILTERED_DIGEST,
     DISPATCH_FILTERED_PLANE,
+    DISPATCH_FILTERED_STACKED,
     DISPATCH_QUEUE_WAIT,
 )
 
@@ -88,7 +99,7 @@ class _Req:
                  "span", "enq_t", "rerank", "group_key")
 
     def __init__(self, queries: np.ndarray, k: int, allow, deadline=None,
-                 tier_key=None, rerank=None):
+                 tier_key=None, rerank=None, mask_identity: bool = True):
         self.queries = queries
         self.k = k
         self.allow = allow
@@ -115,8 +126,11 @@ class _Req:
         # version only bumps on rebuilds, so requests racing live
         # ingest still coalesce (torn-read stance of the live mask).
         # Ad-hoc masks keep the content-digest path, disambiguated by
-        # array_equal in _masks_equal before sharing a batch.
-        if allow is None:
+        # array_equal in _masks_equal before sharing a batch. A
+        # dispatcher whose runner takes one mask a row never compares
+        # masks and asks for no identity (the digest reads the whole
+        # mask under the interpreter lock).
+        if allow is None or not mask_identity:
             self.mask_key = None
         elif getattr(allow, "plane_id", None) is not None:
             self.mask_key = ("plane", allow.plane_id, allow.version)
@@ -185,6 +199,13 @@ def _masks_equal(a: _Req, b: _Req) -> bool:
     return a.mask_key == b.mask_key and np.array_equal(a.allow, b.allow)
 
 
+def one_mask(masks: list):
+    """The mask every member of a group carries (the same OBJECT: one
+    tenant's cached mask, a plane's bitmap), or None where they differ."""
+    first = masks[0]
+    return first if all(m is first for m in masks) else None
+
+
 # The crashed-leader safety net: a waiter that is neither answered nor
 # handed the lead re-checks for an absent leader this often.
 POLL_TICK_S = 0.02
@@ -198,13 +219,21 @@ class CoalescingDispatcher:
     ``pass_tier_key`` it is also handed the group's ``tier_key=`` — for a
     runner whose compiled program depends on part of that key (the flat
     scan's ``approx_recall``).
+
+    ``per_row_masks`` is the runner's statement that it applies one allow
+    mask a query ROW: filtered requests then share a batch whatever their
+    masks, and the runner is called ``run_batch(queries, k, masks,
+    rows=...)`` with ``masks`` the members' masks in request order (None
+    for an unfiltered group) and ``rows`` their row counts. Without it
+    ``allow`` is the group's ONE mask and only mask-equal requests share.
     """
 
     def __init__(self, run_batch: Callable, max_batch: int = 64,
-                 pass_tier_key: bool = False):
+                 pass_tier_key: bool = False, per_row_masks: bool = False):
         self.run_batch = run_batch
         self.max_batch = max_batch
         self.pass_tier_key = pass_tier_key
+        self.per_row_masks = per_row_masks
         self._lock = threading.Lock()
         self._pending: list[_Req] = []
         self._draining = False
@@ -223,7 +252,7 @@ class CoalescingDispatcher:
 
             deadline = current_deadline()
         req = _Req(queries, k, allow, deadline, tier_key=tier_key,
-                   rerank=rerank)
+                   rerank=rerank, mask_identity=not self.per_row_masks)
         from weaviate_tpu.monitoring import tracing
 
         origin = tracing.current_span()
@@ -306,6 +335,11 @@ class CoalescingDispatcher:
             r.event.set()
         return group
 
+    def _masks_share(self, head: _Req, r: _Req) -> bool:
+        if self.per_row_masks:
+            return (head.allow is None) == (r.allow is None)
+        return _masks_equal(head, r)
+
     def _take_group_locked(self, expired: list[_Req]) -> list[_Req]:
         with self._lock:
             alive = []
@@ -329,14 +363,15 @@ class CoalescingDispatcher:
                         and r.k == head.k and r.tier_key == head.tier_key \
                         and r.group_key == head.group_key \
                         and _rerank_key(r) == head_rr \
-                        and _masks_equal(head, r):
+                        and self._masks_share(head, r):
                     group.append(self._pending.pop(i))
                     rows += n
                 else:
                     i += 1
             return group
 
-    def _batch_span(self, group: list[_Req], rows: int, queue_s: float):
+    def _batch_span(self, group: list[_Req], rows: int, queue_s: float,
+                    masks: int):
         """One span per drained batch, created ONLY when some member of
         the group is sampled: parented into the leader's active trace
         when it has one, else the first sampled requester's, and linked
@@ -366,7 +401,7 @@ class CoalescingDispatcher:
             batch_size=len(group), rows=rows,
             rows_pow2=1 << max(0, int(rows - 1).bit_length()),
             k=group[0].k, tier_key=str(group[0].tier_key),
-            filtered=group[0].allow is not None,
+            filtered=group[0].allow is not None, masks=masks,
             queue_ms=round(queue_s * 1000, 3),
             **attrs,
         )
@@ -387,8 +422,17 @@ class CoalescingDispatcher:
             # the group's WORST wait: the batch drained now, so every
             # member's wait ends here
             queue_s = max(t0 - r.enq_t for r in group)
-            rows = sum(_rows(r.queries) for r in group)
-            span = self._batch_span(group, rows, queue_s)
+            member_rows = [_rows(r.queries) for r in group]
+            rows = sum(member_rows)
+            allow = group[0].allow
+            # distinct mask rows the batch carries: 0 unfiltered, 1 one
+            # mask for the whole group, else one a member
+            masks = 0 if allow is None else 1
+            if self.per_row_masks and allow is not None:
+                allow = [r.allow for r in group]
+                if one_mask(allow) is None:
+                    masks = len(group)
+            span = self._batch_span(group, rows, queue_s, masks)
             detach_token = None
             if span is not None:
                 span.__enter__()
@@ -406,13 +450,21 @@ class CoalescingDispatcher:
             try:
                 q = _concat_queries(group)
                 DISPATCH_DEVICE_ROWS.inc(_rows(q))
-                if group[0].allow is not None:
+                if masks > 1:
+                    # unequal masks sharing one scan, a mask a row
+                    DISPATCH_FILTERED_STACKED.inc()
+                elif masks:
                     # plane-vs-digest split: how often filtered batches
                     # ride a resident plane instead of digesting masks
                     if getattr(group[0].allow, "plane_id", None) is not None:
                         DISPATCH_FILTERED_PLANE.inc()
                     else:
                         DISPATCH_FILTERED_DIGEST.inc()
+                kwargs = {}
+                if self.pass_tier_key:
+                    kwargs["tier_key"] = group[0].tier_key
+                if self.per_row_masks:
+                    kwargs["rows"] = member_rows
                 if group[0].rerank is not None:
                     # per-request query token sets concatenate along the
                     # batch rows exactly like the queries themselves
@@ -422,19 +474,10 @@ class CoalescingDispatcher:
                           np.concatenate([p[1] for p in parts], axis=0))
                     rqm = (parts[0][2] if len(parts) == 1 else
                            np.concatenate([p[2] for p in parts], axis=0))
-                    ids, dists = self.run_batch(
-                        q, group[0].k, group[0].allow,
-                        rerank=(parts[0][0], rq, rqm))
-                elif self.pass_tier_key:
-                    ids, dists = self.run_batch(
-                        q, group[0].k, group[0].allow,
-                        tier_key=group[0].tier_key)
-                else:
-                    ids, dists = self.run_batch(q, group[0].k,
-                                                group[0].allow)
+                    kwargs["rerank"] = (parts[0][0], rq, rqm)
+                ids, dists = self.run_batch(q, group[0].k, allow, **kwargs)
                 at = 0
-                for r in group:
-                    n = _rows(r.queries)
+                for r, n in zip(group, member_rows):
                     r.ids = ids[at:at + n]
                     r.dists = dists[at:at + n]
                     at += n

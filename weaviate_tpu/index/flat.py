@@ -9,7 +9,9 @@ config).
 Concurrent searches share scans: ``FlatIndex.search`` enqueues into a
 ``CoalescingDispatcher`` (``index/dispatch.py``) whose leader runs every
 compatible pending request as ONE ``flat_search``. The scan is bound by
-reading the corpus, so a batch costs about what one query costs.
+reading the corpus, so a batch costs about what one query costs. Filtered
+requests share a scan whatever their filters: the members' allow masks go
+up stacked, one row a query row, and the scan applies row i to row i.
 """
 
 from __future__ import annotations
@@ -25,7 +27,7 @@ from weaviate_tpu.index.base import (
     VectorIndex,
     run_tier_stable,
 )
-from weaviate_tpu.index.dispatch import CoalescingDispatcher
+from weaviate_tpu.index.dispatch import CoalescingDispatcher, one_mask
 from weaviate_tpu.index.store import DeviceVectorStore
 from weaviate_tpu.monitoring import tracing
 from weaviate_tpu.monitoring.tracing import TRACER
@@ -90,9 +92,15 @@ class FlatIndex(VectorIndex):
         # grouping on it, so a request enqueued against one residency
         # generation never rides a batch of another
         self._residency_epoch = 0
+        # ``flat_search`` applies one allow mask a query row, so filtered
+        # requests share a scan whatever their masks. The mesh program
+        # (``mesh_flat_topk``) takes one mask a batch: over a mesh store
+        # the capability is not declared and only mask-equal requests share
+        # (every benchmark cell is one chip).
         self._dispatcher = CoalescingDispatcher(
-            self._run_batch, max_batch=ROW_BUCKETS[-1], pass_tier_key=True)
-        # (capacity, k, filtered, approx_recall) whose every row bucket is
+            self._run_batch, max_batch=ROW_BUCKETS[-1], pass_tier_key=True,
+            per_row_masks=self.store.mesh is None)
+        # (capacity, k, filtered, approx_recall) whose every form is
         # compiled; written by the dispatcher's leader only (single-flight)
         self._warm_programs: set[tuple] = set()
 
@@ -147,7 +155,8 @@ class FlatIndex(VectorIndex):
         if not self.store.device_resident:
             # WARM tier (tiering/): the corpus is demoted to host RAM —
             # serve exactly from there, never re-renting HBM per query
-            ids, d = self._host_search(queries, k, allow_list)
+            ids, d = self._host_search(
+                queries, k, None if allow_list is None else [allow_list])
             return SearchResult(ids=ids, dists=d)
         # everything that decides the compiled program or the arrays it
         # reads, beyond k and the mask (the dispatcher's own keys): the
@@ -158,44 +167,69 @@ class FlatIndex(VectorIndex):
                                          tier_key=tier_key)
         return SearchResult(ids=ids, dists=d)
 
-    def _run_batch(self, queries: np.ndarray, k: int, allow_list,
-                   tier_key: tuple):
+    def _run_batch(self, queries: np.ndarray, k: int, masks,
+                   tier_key: tuple, rows: Optional[list[int]] = None):
         """Single-flight batch runner behind the coalescing dispatcher:
         one upload, one normalise, one scan and one copy-out for the
-        whole group. Returns (ids, dists) of the group's rows."""
+        whole group. ``masks`` is None or the members' allow masks in
+        request order, ``rows`` their row counts. Returns (ids, dists) of
+        the group's rows."""
+        if rows is None:
+            # a dispatcher without ``per_row_masks`` (a mesh store) hands
+            # over the group's one mask
+            rows = [queries.shape[0]]
+            masks = None if masks is None else [masks]
         approx_recall = tier_key[1]
         if not self.store.device_resident:
             # a demotion landed while this group was queued: the leader
             # re-routes the whole batch to the warm host tier
-            return self._host_search(queries, k, allow_list)
-        rows = queries.shape[0]
-        program = (self.store.capacity, k, allow_list is not None,
-                   approx_recall)
-        if rows <= ROW_BUCKETS[-1] and program not in self._warm_programs:
-            self._warm_buckets(program, allow_list)
-        return self._scan(queries, k, allow_list, approx_recall)
+            return self._host_search(queries, k, masks, rows)
+        program = (self.store.capacity, k, masks is not None, approx_recall)
+        if queries.shape[0] <= ROW_BUCKETS[-1] \
+                and program not in self._warm_programs:
+            self._warm_buckets(program)
+        return self._scan(queries, k, masks, rows, approx_recall)
 
-    def _host_search(self, queries: np.ndarray, k: int, allow_list):
+    def _host_search(self, queries: np.ndarray, k: int, masks,
+                     rows: Optional[list[int]] = None):
         from weaviate_tpu.index.hnsw.backend import host_store_topk
 
-        d, ids = host_store_topk(
-            self.store, self.metric, queries, k, allow_list)
-        return ids, d
+        if masks is None or one_mask(masks) is not None:
+            d, ids = host_store_topk(self.store, self.metric, queries, k,
+                                     None if masks is None else masks[0])
+            return ids, d
+        # the warm tier's executor takes one mask a call: a member a call
+        parts, at = [], 0
+        for mask, n in zip(masks, rows):
+            parts.append(host_store_topk(
+                self.store, self.metric, queries[at:at + n], k, mask))
+            at += n
+        return (np.concatenate([ids for _, ids in parts]),
+                np.concatenate([d for d, _ in parts]))
 
-    def _warm_buckets(self, program: tuple, allow_list) -> None:
-        """Compile every row bucket of one (capacity, k, filtered,
-        approx_recall) at its first search — never under whichever later
-        batch happens to be the first of its size. Zero queries through
-        the whole path: the eager normalise is shaped by the rows too."""
-        capacity, k, _, approx_recall = program
+    def _warm_buckets(self, program: tuple) -> None:
+        """Compile every form a later batch of one (capacity, k, filtered,
+        approx_recall) can ask for at its first search — never under
+        whichever later batch happens to be the first of its kind: every
+        row bucket and, filtered, the stacked-mask form of the buckets a
+        group of unequal masks can fill (it has at least two rows). Zero
+        queries through the whole path: the eager normalise is shaped by
+        the rows too."""
+        capacity, k, filtered, approx_recall = program
+        forms = [(b, [b]) for b in ROW_BUCKETS]
+        if filtered and self._dispatcher.per_row_masks:
+            forms += [(b, [1, b - 1]) for b in ROW_BUCKETS if b > 1]
         with TRACER.child("flat.warm", capacity=capacity, k=k,
                           buckets=list(ROW_BUCKETS)):
             # child spans of the synthetic scans would read as requests'
             token = tracing.detach()
             try:
-                for b in ROW_BUCKETS:
+                for b, rows in forms:
+                    # one mask object a member: two members, two masks
+                    masks = ([np.zeros(1, bool) for _ in rows]
+                             if filtered else None)
                     self._scan(np.zeros((b, self.store.dims), np.float32),
-                               k, allow_list, approx_recall)
+                               k, masks, rows, approx_recall)
             finally:
                 tracing.deactivate(token)
         # programs of a capacity the store has outgrown are never asked
@@ -203,12 +237,12 @@ class FlatIndex(VectorIndex):
         self._warm_programs = {p for p in self._warm_programs
                                if p[0] == capacity} | {program}
 
-    def _scan(self, queries: np.ndarray, k: int, allow_list,
+    def _scan(self, queries: np.ndarray, k: int, masks, rows: list[int],
               approx_recall: float):
-        rows = queries.shape[0]
-        padded = _bucket_rows(rows)
-        if padded != rows:
-            queries = np.pad(queries, ((0, padded - rows), (0, 0)))
+        n = queries.shape[0]
+        padded = _bucket_rows(n)
+        if padded != n:
+            queries = np.pad(queries, ((0, padded - n), (0, 0)))
         with TRACER.child("flat.prepare"):
             qj = jnp.asarray(queries)
             if self.metric == "cosine":
@@ -217,22 +251,26 @@ class FlatIndex(VectorIndex):
                 qj = normalize(qj)
         with TRACER.child("flat.dispatch", capacity=self.store.capacity,
                          batch=padded):
-            d, ids = self._dispatch(qj, k, allow_list, approx_recall)
+            d, ids = self._dispatch(qj, k, masks, rows, approx_recall)
         # the wait for the device and the copy out (both arrays' copies
         # started before either is waited for); padded rows are dropped
         # before hand-back
         with TRACER.child("flat.result"):
             ids, d = jax.device_get((ids, d))
-            return ids[:rows], d[:rows]
+            return ids[:n], d[:n]
 
-    def _dispatch(self, qj, k: int, allow_list, approx_recall: float):
+    def _dispatch(self, qj, k: int, masks, rows: list[int],
+                  approx_recall: float):
         """Start the scan of one device-resident query batch; returns the
         (distances, ids) device arrays without waiting for them."""
+        shared = None if masks is None else one_mask(masks)
         if self.store.mesh is not None:
             from weaviate_tpu.parallel.sharded_search import mesh_flat_topk
 
+            # one mask a batch: this index's dispatcher groups by mask
+            # equality (see __init__), so ``shared`` is the group's mask
             return mesh_flat_topk(
-                self.store, qj, k, self.metric, allow=allow_list,
+                self.store, qj, k, self.metric, allow=shared,
                 precision=self.config.precision,
                 chunk_size=self.config.search_chunk_size,
                 approx_recall=approx_recall,
@@ -241,20 +279,28 @@ class FlatIndex(VectorIndex):
         corpus, valid, sqnorms = self.store.snapshot()
         cap = corpus.shape[0]
         allow = None
-        if allow_list is not None:
-            # one bool a row, padded to the capacity and uploaded: once a
-            # batch, since only requests of one mask share a batch
+        if shared is not None:
+            # a lone request, or members carrying one and the same mask:
+            # one bool a corpus row, padded to the capacity and uploaded
             with TRACER.child("flat.mask", bytes=cap):
-                allow = _pad_mask(allow_list, cap)
+                allow = _pad_mask(shared, cap)
+        elif masks is not None:
+            # members with different masks: one mask a query ROW, uploaded
+            # once a batch; ``flat_search`` applies row i to row i
+            with TRACER.child("flat.mask", bytes=qj.shape[0] * cap):
+                allow = jnp.asarray(
+                    _stack_masks(masks, rows, qj.shape[0], cap))
         chunk = self.config.search_chunk_size
         # optional fused Pallas kernel (env-gated; see pallas_flat.py).
-        # Taken only where its semantics match the request: bf16 is the
-        # configured precision, approximate selection is permitted
-        # (approx_recall=0.0 pins EXACT — range queries ride that), and k
-        # is small enough for the kernel's unrolled extract-min loop.
+        # Taken only where its semantics match the request: at most one
+        # mask a batch, bf16 is the configured precision, approximate
+        # selection is permitted (approx_recall=0.0 pins EXACT — range
+        # queries ride that), and k is small enough for the kernel's
+        # unrolled extract-min loop.
         from weaviate_tpu.ops import pallas_flat
 
         if (self.metric == "l2-squared" and sqnorms is not None
+                and (masks is None or shared is not None)
                 and pallas_flat.enabled()
                 and self.config.precision == "bf16"
                 and approx_recall > 0.0 and k <= 64):
@@ -271,9 +317,8 @@ class FlatIndex(VectorIndex):
             # degrades toward exact (fold=1) selection, never past the
             # advertised loss bound
             live = self.store.live_count
-            if allow_list is not None:
-                allow_n = int(np.count_nonzero(
-                    np.asarray(allow_list, bool)))
+            if shared is not None:
+                allow_n = int(np.count_nonzero(np.asarray(shared, bool)))
                 live = max(1, live + allow_n - cap)
             if pallas_flat.fits(cap, csz,
                                 corpus.shape[1] * corpus.dtype.itemsize):
@@ -364,6 +409,23 @@ class FlatIndex(VectorIndex):
             s["mesh_shard_rows"] = [int(x) for x in per_shard]
             set_mesh_shard_gauges(per_shard)
         return s
+
+
+def _stack_masks(masks: list, rows: list[int], padded: int,
+                 capacity: int) -> np.ndarray:
+    """[padded, capacity] bools: each member's mask in its query row(s).
+    Masks may differ in length while ingest runs (each is as long as the
+    doc-id space was when its filter resolved); a row past a mask's end,
+    and every padded row, allows nothing. A fresh array a batch: the
+    upload may alias host memory (CPU backend) and is asynchronous on the
+    chip, so a reused scratch could change under a scan in flight."""
+    out = np.zeros((padded, capacity), bool)
+    at = 0
+    for mask, n in zip(masks, rows):
+        mask = np.asarray(mask, bool)[:capacity]
+        out[at:at + n, :mask.shape[0]] = mask
+        at += n
+    return out
 
 
 def _pad_mask(mask: np.ndarray, capacity: int) -> jnp.ndarray:

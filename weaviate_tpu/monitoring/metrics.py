@@ -293,6 +293,12 @@ DISPATCH_FILTERED_DIGEST = REGISTRY.counter(
     "filtered device batches carrying an ad-hoc allow mask, coalesced "
     "by content digest + exact compare (the fallback when no resident "
     "plane serves the filter)")
+DISPATCH_FILTERED_STACKED = REGISTRY.counter(
+    "weaviate_tpu_dispatch_filtered_stacked_total",
+    "filtered device batches whose members carried DIFFERENT allow masks "
+    "and shared one scan, a mask a query row (a runner that declares "
+    "per_row_masks: the flat scan); such a batch counts here and in "
+    "neither of the two above")
 PLANNER_PLANS = REGISTRY.counter(
     "weaviate_tpu_planner_plans_total",
     "filtered-search plans chosen by the cost-based query planner, by "

@@ -218,7 +218,9 @@ def flat_search(
     queries      [B, D] float
     corpus       [N, D] float (padded to capacity; see valid_mask)
     valid_mask   [N] bool — False for pad slots / tombstoned ids
-    allow_mask   [N] bool — optional filter allowlist (reference AllowList)
+    allow_mask   [N] bool — optional filter allowlist (reference AllowList),
+                 or [B, N]: row i of the mask filters row i of the queries
+                 (one scan for B requests whose filters differ)
     chunk_size   evaluate corpus in chunks of this many rows to bound the
                  [B, chunk] score materialization (0 = single shot). Must
                  divide into N by padding; non-multiple tail is handled.
@@ -236,14 +238,20 @@ def flat_search(
     if valid_mask is not None:
         mask = valid_mask
     if allow_mask is not None:
+        if mask is not None and allow_mask.ndim == 2:
+            mask = mask[None, :]
         mask = allow_mask if mask is None else (mask & allow_mask)
+    # corpus rows lie along the mask's LAST axis, whichever form it has
+    mask_axis = 0 if mask is None else mask.ndim - 1
 
     def score_block(c_block, norms_block, mask_block, base):
         d = pairwise_distance(
             queries, c_block, metric, corpus_sqnorms=norms_block, precision=precision
         )
         if mask_block is not None:
-            d = jnp.where(mask_block[None, :], d, MASK_DISTANCE)
+            if mask_block.ndim == 1:
+                mask_block = mask_block[None, :]
+            d = jnp.where(mask_block, d, MASK_DISTANCE)
         kk = min(k, c_block.shape[0])
         vals, idx = select_topk(d, kk, approx_recall)
         ids = idx.astype(jnp.int32) + base
@@ -271,7 +279,8 @@ def flat_search(
                 else None
             )
             mask_block = (
-                jax.lax.dynamic_slice_in_dim(mask, start, chunk_size, 0)
+                jax.lax.dynamic_slice_in_dim(mask, start, chunk_size,
+                                             mask_axis)
                 if mask is not None
                 else None
             )
@@ -287,7 +296,7 @@ def flat_search(
         if n_full < n:
             tail_c = corpus[n_full:]
             tail_norms = corpus_sqnorms[n_full:] if corpus_sqnorms is not None else None
-            tail_mask = mask[n_full:] if mask is not None else None
+            tail_mask = mask[..., n_full:] if mask is not None else None
             v, idx = score_block(tail_c, tail_norms, tail_mask, n_full)
             vals, ids = merge_topk(vals, ids, v, idx, k)
 
