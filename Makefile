@@ -2,7 +2,7 @@
 # pytest line; these targets cover the static-analysis side.
 
 .PHONY: lint lint-sarif lint-dot lint-errorflow-dot lint-fix-baseline \
-	test trace-demo chaos bench-device
+	test trace-demo chaos
 
 # Full graftlint: every per-file rule plus BOTH interprocedural
 # passes — concurrency (lock-order cycles, blocking-under-lock,
@@ -64,15 +64,3 @@ chaos:
 # tier-1 (tests/test_observability.py::test_trace_demo_smoke).
 trace-demo:
 	JAX_PLATFORMS=cpu python -m tools.trace_demo
-
-# One journaled sweep over every bench config that carries a pending
-# perf-flag verdict (utils/perf_flags.py): each run re-records its
-# flag's enabled/evidence from live measurements, so a chip session
-# settles ALL device verdicts in one command instead of ad-hoc
-# per-config invocations. Configs: device_beam_quantized (hnswquant),
-# mesh_device_beam (meshbeam), compile_cache (coldstart),
-# device_rerank (rerank), device_hybrid (hybrid), device_filter_planes
-# (filtered), device_multi_target (multitarget).
-bench-device:
-	python bench.py --configs \
-		hnswquant,meshbeam,coldstart,rerank,hybrid,filtered,multitarget

@@ -62,23 +62,29 @@ def _fits(compiled) -> int:
     return total
 
 
-def _flat_args(sharding, corpus_dtype=jnp.float32):
+def _flat_args(sharding):
     s = functools.partial(jax.ShapeDtypeStruct, sharding=sharding)
-    return (s((B, D), jnp.float32), s((N, D), corpus_dtype),
+    return (s((B, D), jnp.float32), s((N, D), jnp.float32),
             s((N,), jnp.bool_), s((N,), jnp.float32))
 
 
+@pytest.mark.parametrize("approx_recall", [0.0, 0.95],
+                         ids=["exact", "approx"])
 @pytest.mark.parametrize("metric", ["l2-squared", "cosine"])
-def test_flat_search_compiles_at_serving_defaults(one_chip, metric):
+def test_flat_search_compiles_at_serving_defaults(one_chip, metric,
+                                                  approx_recall):
     """The program a default FlatIndexConfig collection serves with: bf16
-    matmul, exact selection, 131072-row chunks over a 1M x 768 fp32 corpus."""
+    matmul, exact selection, 131072-row chunks over a 1M x 768 fp32 corpus;
+    and the one a collection with ``flat_approx_recall`` set serves with
+    (``lax.approx_min_k`` a chunk), the only approximate flat program."""
     from weaviate_tpu.ops.distance import flat_search
 
     q, corpus, valid, sqnorms = _flat_args(one_chip)
     compiled = flat_search.lower(
         q, corpus, k=K, metric=metric, valid_mask=valid,
         corpus_sqnorms=sqnorms if metric == "l2-squared" else None,
-        chunk_size=CHUNK, precision="bf16", approx_recall=0.0).compile()
+        chunk_size=CHUNK, precision="bf16",
+        approx_recall=approx_recall).compile()
     # the corpus is an argument, not a temporary: >= 3.2 GB resident
     assert compiled.memory_analysis().argument_size_in_bytes >= N * D * 4
     _fits(compiled)
@@ -101,23 +107,6 @@ def test_flat_search_compiles_with_a_mask_a_row(one_chip, rows):
         precision="bf16", approx_recall=0.0).compile()
     assert compiled.memory_analysis().argument_size_in_bytes >= \
         cap * dims * 4 + rows * cap
-    _fits(compiled)
-
-
-@pytest.mark.parametrize("corpus_dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["f32", "bf16"])
-def test_pallas_flat_topk_compiles(one_chip, corpus_dtype):
-    """fp32 is what the serving store hands the kernel (index/store.py keeps
-    float32): a 2048-row fp32 block asked for 17.35 MB of the 16 MB scoped
-    VMEM until the block choice learned the corpus itemsize."""
-    from weaviate_tpu.ops.pallas_flat import bucket_live, pallas_flat_topk
-
-    q, corpus, valid, sqnorms = _flat_args(one_chip, corpus_dtype)
-    mask = jax.ShapeDtypeStruct((N,), jnp.float32, sharding=one_chip)
-    compiled = pallas_flat_topk.lower(
-        q, corpus, sqnorms, mask, k=K, chunk_size=CHUNK,
-        live_rows=bucket_live(N)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
     _fits(compiled)
 
 

@@ -5,15 +5,19 @@ host walk's recall on the same graph, handle tombstones (traversable,
 not returned), and track graph mutations through the adjacency mirror.
 """
 
+import json
+
+import jax
 import numpy as np
 import pytest
 
 from weaviate_tpu.index.hnsw.hnsw import HNSWIndex
 from weaviate_tpu.schema.config import HNSWIndexConfig
 
-# every test builds a 3k-node graph and compiles the beam program
-# (~10-20s each on the virtual-CPU platform): full-CI tier, not tier-1
-pytestmark = pytest.mark.slow
+# a test that builds a 3k-node graph and compiles the beam program
+# (~10-20s each on the virtual-CPU platform) is full-CI tier, not tier-1;
+# the walk's selection rule builds nothing and runs in tier-1
+slow = pytest.mark.slow
 
 
 def _build(n=3000, d=32, seed=0, **kw):
@@ -38,12 +42,42 @@ def _recall(idx, corpus, rng, k=10, nq=32):
                for i in range(nq)) / (nq * k)
 
 
+@pytest.mark.parametrize("env, config_on, stray_file, built", [
+    ("on", False, False, True),
+    ("off", True, False, False),
+    ("bogus", True, False, False),
+    (None, True, False, True),
+    (None, False, False, False),
+    (None, False, True, False),
+], ids=["env-on", "env-off", "env-bogus", "config-on", "config-off",
+        "stray-verdict-file"])
+def test_walk_selected_by_env_then_config(env, config_on, stray_file, built,
+                                          tmp_path, monkeypatch):
+    """WEAVIATE_TPU_DEVICE_BEAM (on/1/true enable, any other non-empty
+    value disables), else config.device_beam, else off; nothing else
+    selects the walk, a verdict file someone left behind included."""
+    monkeypatch.delenv("WEAVIATE_TPU_DEVICE_BEAM", raising=False)
+    if env is not None:
+        monkeypatch.setenv("WEAVIATE_TPU_DEVICE_BEAM", env)
+    if stray_file:
+        p = tmp_path / "verdicts.json"  # the deleted flags file's format
+        p.write_text(json.dumps({"device_beam": {
+            "enabled": True, "platform": jax.default_backend()}}))
+        monkeypatch.setenv("WEAVIATE_TPU_PERF_FLAGS", str(p))
+    idx = HNSWIndex(8, HNSWIndexConfig(distance="l2-squared",
+                                       precision="fp32",
+                                       device_beam=config_on))
+    assert (idx._device_beam is not None) is built
+
+
+@slow
 def test_device_beam_active_and_recall():
     idx, corpus, rng = _build()
     assert idx._device_beam is not None, "device beam not enabled"
     assert _recall(idx, corpus, rng) >= 0.9
 
 
+@slow
 def test_device_beam_matches_host_walk():
     idx, corpus, rng = _build()
     q = corpus[:16] + 0.05 * rng.standard_normal((16, 32)).astype(
@@ -59,6 +93,7 @@ def test_device_beam_matches_host_walk():
     assert agree >= 0.9, agree
 
 
+@slow
 def test_construction_beam_builds_searchable_graph():
     """ef_construction walks run on device (VERDICT r3 #5): the graph built
     by the device construction beam must reach the same recall as the host
@@ -85,6 +120,7 @@ def test_construction_beam_builds_searchable_graph():
     assert dev_recall >= host_recall - 0.05, (dev_recall, host_recall)
 
 
+@slow
 def test_construction_beam_cosine():
     rng = np.random.default_rng(11)
     n, d = 2000, 24
@@ -105,6 +141,7 @@ def test_construction_beam_cosine():
     assert recall >= 0.9, recall
 
 
+@slow
 def test_tombstones_traversable_not_returned():
     idx, corpus, rng = _build(n=1500)
     dead = np.arange(0, 1500, 3, dtype=np.int64)
@@ -116,6 +153,7 @@ def test_tombstones_traversable_not_returned():
     assert len(live) and not set(live.tolist()) & set(dead.tolist())
 
 
+@slow
 def test_mirror_tracks_incremental_inserts():
     idx, corpus, rng = _build(n=1000)
     assert _recall(idx, corpus, rng) >= 0.85  # syncs the mirror once
@@ -129,6 +167,7 @@ def test_mirror_tracks_incremental_inserts():
     assert hits >= 7, res.ids[:, 0]
 
 
+@slow
 def test_filtered_queries_stay_on_host_path():
     idx, corpus, rng = _build(n=1200)
     allow = np.zeros(2048, bool)
@@ -140,6 +179,7 @@ def test_filtered_queries_stay_on_host_path():
     assert (live < 600).all()
 
 
+@slow
 def test_cosine_metric_normalizes_queries():
     rng = np.random.default_rng(3)
     n, d = 1200, 24
@@ -157,6 +197,7 @@ def test_cosine_metric_normalizes_queries():
     assert -1e-3 <= float(res.dists[0, 0]) < 0.05
 
 
+@slow
 def test_masked_device_beam_filtered_search():
     """High-selectivity filters now ride the device beam too (VERDICT r3
     #3: the `allow_list is None` restriction is gone): the walk stays
@@ -196,6 +237,7 @@ def test_masked_device_beam_filtered_search():
     assert dev_recall >= host_recall - 0.05, (dev_recall, host_recall)
 
 
+@slow
 def test_masked_device_beam_respects_deletes():
     """Tombstoned ids must not surface through the kept track even when
     the allowlist still has them set."""

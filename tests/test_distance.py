@@ -17,6 +17,7 @@ from weaviate_tpu.ops import (
     merge_topk,
     masked_topk,
 )
+from weaviate_tpu.ops.distance import MASK_DISTANCE
 
 
 def np_dist(q, c, metric):
@@ -75,7 +76,14 @@ def test_flat_search_chunked_matches_single_shot(rng):
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
 
 
-def test_flat_search_masks(rng):
+# approximate selection (``lax.approx_min_k``) lowers to an exact sort on
+# the CPU, so the same assertions hold under both
+APPROX = pytest.mark.parametrize("approx_recall", [0.0, 0.95],
+                                 ids=["exact", "approx"])
+
+
+@APPROX
+def test_flat_search_masks(rng, approx_recall):
     q = rng.standard_normal((1, 8)).astype(np.float32)
     c = rng.standard_normal((20, 8)).astype(np.float32)
     valid = np.ones(20, bool)
@@ -89,10 +97,29 @@ def test_flat_search_masks(rng):
         metric="l2-squared",
         valid_mask=jnp.asarray(valid),
         allow_mask=jnp.asarray(allow),
+        approx_recall=approx_recall,
     )
     ids = np.asarray(ids)[0]
     assert set(ids[ids >= 0]) == {1, 3}
     assert (ids[2:] == -1).all()
+
+
+@APPROX
+def test_flat_search_fully_masked_returns_sentinels(rng, approx_recall):
+    """No live row is allowed: every slot is the sentinel (id -1,
+    MASK_DISTANCE), whole and chunked with a tail."""
+    q = rng.standard_normal((3, 8)).astype(np.float32)
+    c = rng.standard_normal((103, 8)).astype(np.float32)
+    valid = np.ones(103, bool)
+    valid[50:] = False
+    allow = ~valid  # only dead rows allowed
+    for chunk in (0, 32):
+        d, ids = flat_search(
+            jnp.asarray(q), jnp.asarray(c), k=5, metric="l2-squared",
+            valid_mask=jnp.asarray(valid), allow_mask=jnp.asarray(allow),
+            chunk_size=chunk, approx_recall=approx_recall)
+        assert (np.asarray(ids) == -1).all()
+        assert (np.asarray(d) == np.float32(MASK_DISTANCE)).all()
 
 
 @pytest.mark.parametrize("metric", ["l2-squared", "cosine"])

@@ -737,63 +737,3 @@ def test_pprof_endpoints(tmp_path):
         stop.set()
     api.shutdown()
     db.close()
-
-
-def test_perf_flags_measured_defaults(tmp_path, monkeypatch):
-    """Bench A/B verdicts flip serving defaults through perf_flags.json:
-    env overrides win, measured verdicts apply, absence stays
-    conservative (off)."""
-    from weaviate_tpu.ops import pallas_flat
-    from weaviate_tpu.utils import perf_flags
-
-    p = str(tmp_path / "perf_flags.json")
-    monkeypatch.setenv("WEAVIATE_TPU_PERF_FLAGS", p)
-    monkeypatch.delenv("WEAVIATE_TPU_PALLAS_FLAT", raising=False)
-
-    assert pallas_flat.enabled() is False  # no file -> conservative
-
-    import jax as _jax
-
-    plat = _jax.default_backend()
-    perf_flags.record("pallas_flat", True,
-                      {"pallas_qps": 60000.0, "xla_qps": 45000.0,
-                       "pallas_recall": 0.996, "xla_recall": 0.994},
-                      platform=plat)
-    assert pallas_flat.enabled() is True  # measured win applies
-
-    ev = perf_flags.load()["pallas_flat"]
-    assert ev["pallas_qps"] == 60000.0  # evidence rides with the verdict
-
-    monkeypatch.setenv("WEAVIATE_TPU_PALLAS_FLAT", "off")
-    assert pallas_flat.enabled() is False  # env always wins
-    monkeypatch.setenv("WEAVIATE_TPU_PALLAS_FLAT", "false")
-    assert pallas_flat.enabled() is False  # any non-on value disables
-    monkeypatch.setenv("WEAVIATE_TPU_PALLAS_FLAT", "bogus")
-    assert pallas_flat.enabled() is False  # unknown values stay OFF
-
-    monkeypatch.delenv("WEAVIATE_TPU_PALLAS_FLAT", raising=False)
-    perf_flags.record("pallas_flat", False, {"error": "lowering failed"},
-                      platform=plat)
-    assert pallas_flat.enabled() is False  # measured loss turns it off
-
-    # a verdict from a DIFFERENT platform never applies
-    perf_flags.record("pallas_flat", True, {"pallas_qps": 1.0},
-                      platform="not-a-platform")
-    if plat != "not-a-platform":
-        assert pallas_flat.enabled() is False
-
-    # device_beam follows the same file through HNSWIndex construction
-    import numpy as np
-
-    from weaviate_tpu.index.hnsw.hnsw import HNSWIndex
-    from weaviate_tpu.schema.config import HNSWIndexConfig
-
-    perf_flags.record("device_beam", True, {"beam_qps": 9000.0,
-                                            "host_qps": 700.0},
-                      platform=plat)
-    monkeypatch.delenv("WEAVIATE_TPU_DEVICE_BEAM", raising=False)
-    idx = HNSWIndex(8, HNSWIndexConfig(distance="l2-squared",
-                                       precision="fp32"))
-    idx.add_batch(np.arange(64), np.random.default_rng(0)
-                  .standard_normal((64, 8)).astype(np.float32))
-    assert idx._device_beam is not None  # measured win enabled the beam

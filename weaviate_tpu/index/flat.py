@@ -291,40 +291,6 @@ class FlatIndex(VectorIndex):
                 allow = jnp.asarray(
                     _stack_masks(masks, rows, qj.shape[0], cap))
         chunk = self.config.search_chunk_size
-        # optional fused Pallas kernel (env-gated; see pallas_flat.py).
-        # Taken only where its semantics match the request: at most one
-        # mask a batch, bf16 is the configured precision, approximate
-        # selection is permitted (approx_recall=0.0 pins EXACT — range
-        # queries ride that), and k is small enough for the kernel's
-        # unrolled extract-min loop.
-        from weaviate_tpu.ops import pallas_flat
-
-        if (self.metric == "l2-squared" and sqnorms is not None
-                and (masks is None or shared is not None)
-                and pallas_flat.enabled()
-                and self.config.precision == "bf16"
-                and approx_recall > 0.0 and k <= 64):
-            m = valid if allow is None else (valid & allow)
-            csz = min(chunk or cap, cap)
-            # live candidate count (host-tracked; allowlist cardinality
-            # counted on the host-side mask) sizes the kernel's fold so
-            # its collision-loss bound holds against the REAL population,
-            # not the padded capacity; power-of-4 bucketing keeps the
-            # static arg from recompiling per write. With a filter the
-            # true population is |valid & allow|, unknown host-side —
-            # use the inclusion-exclusion LOWER bound max(live+|allow|-
-            # cap, 1): fold sizing from an underestimate only ever
-            # degrades toward exact (fold=1) selection, never past the
-            # advertised loss bound
-            live = self.store.live_count
-            if shared is not None:
-                allow_n = int(np.count_nonzero(np.asarray(shared, bool)))
-                live = max(1, live + allow_n - cap)
-            if pallas_flat.fits(cap, csz,
-                                corpus.shape[1] * corpus.dtype.itemsize):
-                return pallas_flat.pallas_flat_topk(
-                    qj, corpus, sqnorms, m, k, chunk_size=csz,
-                    live_rows=pallas_flat.bucket_live(live))
         return flat_search(
             qj,
             corpus,

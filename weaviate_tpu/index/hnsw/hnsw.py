@@ -119,21 +119,14 @@ class HNSWIndex(VectorIndex):
         # Created AFTER snapshot load/replay: those swap self.graph, and
         # the mirror must bind the final graph object.
         self._device_beam = None
-        # env > per-index config > platform-matched measured verdict
-        # (the backend store above already initialized jax, so
-        # default_backend() cannot trip a fresh device init here).
-        # Quantized backends follow their own measured flag: a raw-corpus
-        # A/B win says nothing about the code-space walk.
-        import jax as _jax
-
-        from weaviate_tpu.utils import perf_flags
-
-        _beam_on = perf_flags.resolve(
-            "device_beam_quantized" if self.backend.quantized
-            else "device_beam",
-            os.environ.get("WEAVIATE_TPU_DEVICE_BEAM", ""),
-            config_on=getattr(self.config, "device_beam", False),
-            platform=_jax.default_backend())
+        # env > per-index config > off: on/1/true enable, any other
+        # non-empty value disables (an operator who set something is not
+        # overridden by the config)
+        _beam_env = os.environ.get("WEAVIATE_TPU_DEVICE_BEAM", "")
+        if _beam_env:
+            _beam_on = _beam_env.lower() in ("on", "1", "true")
+        else:
+            _beam_on = bool(getattr(self.config, "device_beam", False))
         # Mesh mode: with the backend's planes row-sharded across a
         # device mesh, the fused walk runs as ONE SPMD dispatch spanning
         # every chip — per-shard subgraph walks + on-device cross-shard

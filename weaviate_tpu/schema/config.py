@@ -267,8 +267,7 @@ class VectorIndexConfig:
     # (0, 1) = TPU two-stage approx_min_k with this recall target (~4-5x
     # faster at 1M rows; on CPU it lowers to an exact sort, so results
     # there are identical). The reference's flat scan is always exact —
-    # this knob is the TPU-native trade the hardware rewards; measured
-    # recall is reported by bench.py.
+    # this knob is the TPU-native trade the hardware rewards.
     flat_approx_recall: float = -1.0
     # Quantized indexes keep raw originals host-side for the exact rescore
     # tier (reference keeps them LSM-resident, flat/index.go:49). Beyond

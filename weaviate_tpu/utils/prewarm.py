@@ -67,8 +67,6 @@ MANIFEST: dict[str, str] = {
         "MUVERA serving path, docs/modules.md)",
     "ops.distance.flat_search":
         "exact flat top-k scan (flat index + filtered-triage tier)",
-    "ops.pallas_flat.pallas_flat_topk":
-        "Pallas flat top-k kernel (perf-flag gated flat path)",
     "ops.quantized.bq_search":
         "binary-quantized flat scan over packed code planes",
     "ops.quantized.sq_search":
