@@ -412,3 +412,110 @@ def test_backup_includes_frozen_tenants(tmp_path, monkeypatch):
     assert hits[0][0].properties["t"] == "doc 3"
     assert col2.count(tenant="cold-co") == 8
     db2.close()
+
+
+# -- the stored object's codec: vectors decoded when read (PR 32) ------------
+
+def _eager_vectors(data: bytes):
+    """(vector, named_vectors) decoded straight off the envelope, as
+    ``from_bytes`` did before it left them as stored."""
+    import msgpack
+
+    env = msgpack.unpackb(data, raw=False)
+    vec = env.get("vec")
+    if vec is not None:
+        vec = np.frombuffer(vec, np.float32).copy()
+        if env.get("vec_shape"):
+            vec = vec.reshape(env["vec_shape"])
+    named = {k: np.frombuffer(v, np.float32).reshape(
+        env["nvec_shapes"][k]).copy() for k, v in env.get("nvecs", {}).items()}
+    return vec, named
+
+
+def _codec_object(kind: str) -> StorageObject:
+    rng = np.random.default_rng(11)
+    kw = {
+        "flat": dict(vector=rng.standard_normal(24).astype(np.float32)),
+        "tokens": dict(vector=rng.standard_normal((5, 8)).astype(np.float32)),
+        "named": dict(named_vectors={
+            "title": rng.standard_normal(6).astype(np.float32),
+            "toks": rng.standard_normal((3, 4)).astype(np.float32)}),
+        "both": dict(vector=rng.standard_normal(4).astype(np.float32),
+                     named_vectors={"a": rng.standard_normal(4).astype(
+                         np.float32)}),
+        "float64_in": dict(vector=rng.standard_normal(7)),
+        "none": dict(),
+    }[kind]
+    return StorageObject(
+        uuid="88000000-0000-0000-0000-000000000001", collection="Doc",
+        properties={"t": "doc", "n": 3, "tags": ["a", "b"]}, doc_id=17,
+        tenant="t1", creation_time_ms=1_700_000_000_000,
+        update_time_ms=1_700_000_000_500, **kw)
+
+
+@pytest.mark.parametrize(
+    "kind", ["flat", "tokens", "named", "both", "float64_in", "none"])
+def test_lazy_vectors_equal_eager_and_round_trip_bit_equal(kind):
+    src = _codec_object(kind)
+    data = src.to_bytes()
+    # bytes -> object -> bytes is the identity, read or not read
+    unread = StorageObject.from_bytes(data)
+    assert unread.to_bytes() == data
+    obj = StorageObject.from_bytes(data)
+    want_vec, want_named = _eager_vectors(data)
+    got = obj.vector
+    if want_vec is None:
+        assert got is None
+    else:
+        assert got.dtype == np.float32 and got.shape == want_vec.shape
+        assert got.tobytes() == want_vec.tobytes()
+        assert got.flags.writeable
+        assert obj.vector is got  # decoded once, then the same array
+    named = obj.named_vectors
+    assert list(named) == list(want_named)
+    for k, want in want_named.items():
+        assert named[k].dtype == np.float32 and named[k].shape == want.shape
+        assert named[k].tobytes() == want.tobytes()
+    assert obj.named_vectors is named
+    assert obj.to_bytes() == data  # and after the reads
+    for f in ("uuid", "collection", "properties", "doc_id", "tenant",
+              "creation_time_ms", "update_time_ms"):
+        assert getattr(obj, f) == getattr(src, f)
+    # writers of the attributes see plain attributes
+    obj.vector = np.ones(3, np.float32)
+    obj.named_vectors["extra"] = np.zeros(2, np.float32)
+    back = StorageObject.from_bytes(obj.to_bytes())
+    assert back.vector.tolist() == [1.0, 1.0, 1.0]
+    assert "extra" in back.named_vectors
+
+
+def test_object_never_read_for_its_vector_allocates_none(monkeypatch):
+    data = _codec_object("both").to_bytes()
+    made = []
+    real = np.frombuffer
+    monkeypatch.setattr(np, "frombuffer",
+                        lambda *a, **kw: made.append(1) or real(*a, **kw))
+    obj = StorageObject.from_bytes(data)
+    assert (obj.uuid, obj.doc_id, obj.properties["n"]) == \
+        ("88000000-0000-0000-0000-000000000001", 17, 3)
+    obj.to_bytes()
+    assert not made
+    assert not any(isinstance(v, np.ndarray) for v in vars(obj).values())
+    assert obj.vector is not None and obj.named_vectors["a"] is not None
+    assert len(made) == 2
+
+
+def test_object_with_its_times_does_not_read_the_clock(monkeypatch):
+    import time as time_mod
+
+    data = _codec_object("flat").to_bytes()
+
+    def no_clock():
+        raise AssertionError("from_bytes read the clock")
+
+    monkeypatch.setattr(time_mod, "time", no_clock)
+    obj = StorageObject.from_bytes(data)
+    assert obj.creation_time_ms == 1_700_000_000_000
+    monkeypatch.undo()
+    fresh = StorageObject(uuid="", collection="Doc")
+    assert fresh.uuid and fresh.creation_time_ms == fresh.update_time_ms > 0

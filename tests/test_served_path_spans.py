@@ -179,8 +179,12 @@ def test_search_gives_one_trace_with_the_tables_children(served, vectors):
     assert by_name["flat.dispatch"][0]["attributes"]["batch"] == \
         {1: 1, 3: 4}[vectors]
     assert by_name["flat.dispatch"][0]["attributes"]["capacity"] >= 100
-    assert by_name["objects.fetch"][0]["attributes"]["objects"] == \
-        10 * vectors
+    fetch = by_name["objects.fetch"][0]["attributes"]
+    assert fetch["objects"] == 10 * vectors
+    # 100 rows never flushed: the memtable answers every key of the
+    # request's one multi-get, and no segment record is read
+    assert (fetch["lock_takes"], fetch["mem_hits"], fetch["records_read"]) \
+        == (1, 10 * vectors, 0)
     assert by_name["grpc.encode"][0]["attributes"]["hits"] == 10 * vectors
     assert by_name["grpc.serialize"][0]["attributes"]["reply_bytes"] > 0
     # the three scan spans follow each other on the leader's thread
@@ -188,6 +192,40 @@ def test_search_gives_one_trace_with_the_tables_children(served, vectors):
              ("flat.prepare", "flat.dispatch", "flat.result")]
     for a, b in zip(order, order[1:]):
         assert a["endTimeUnixNano"] <= b["startTimeUnixNano"]
+
+
+@pytest.mark.parametrize("vectors", [1, 3])
+def test_hits_over_flushed_segments_cost_one_record_and_one_lock_take(
+        tmp_dbdir, vectors):
+    """The reply's hits come from ONE multi-get a shard: one take of the
+    ``objects`` bucket's lock and one record read a hit, with the rows in
+    three segments and the memtable; span counts as in the memtable case."""
+    db, api, client = _serve(tmp_dbdir)
+    try:
+        (shard,) = db.get_collection("Article")._shards.values()
+        for start in range(0, 400, 100):
+            assert not client.batch_objects(_batch(100, start=start)).errors
+            if start < 300:
+                shard.objects.flush_memtable()
+        assert len(shard.objects._segments) == 3
+        client.search(_search(vectors))      # compiles (flat.warm)
+        TRACER.clear()
+        reply = client.search(_search(vectors))
+        assert [len(r.hits) for r in reply.results] == [10] * vectors
+        spans = _one_trace("grpc.Search")
+        by_name = _check_tree(spans, SEARCH_TREE, "grpc.Search")
+        assert {n: len(v) for n, v in by_name.items()} == {
+            n: 1 for n in SEARCH_TREE if n != "flat.warm"}
+        assert len(spans) <= SEARCH_BUDGET
+        fetch = by_name["objects.fetch"][0]["attributes"]
+        assert fetch["objects"] == 10 * vectors
+        assert fetch["lock_takes"] == 1
+        assert fetch["records_read"] + fetch["mem_hits"] == 10 * vectors
+        assert 0 < fetch["records_read"] <= 10 * vectors
+    finally:
+        client.close()
+        api.shutdown()
+        db.close()
 
 
 def test_a_filtered_search_adds_two_spans_inside_its_budget(served):

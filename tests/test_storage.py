@@ -5,8 +5,19 @@ Mirrors reference tests ``lsmkv/bucket_recover_test.go``,
 ``segment_group_compaction.go`` (pairwise/tiered).
 """
 
+import hashlib
 import os
+import random
+import struct
+import sys
+import threading
 
+import msgpack
+import pytest
+
+from weaviate_tpu import native
+from weaviate_tpu.storage import segment as segmod
+from weaviate_tpu.storage.segment import MISSING, DiskSegment, native_merge
 from weaviate_tpu.storage.wal import WAL
 from weaviate_tpu.storage.store import Bucket, Store
 
@@ -196,3 +207,375 @@ def test_write_heavy_soak_bounded_write_amplification(tmp_path):
     # data correct after all that churn
     assert b.get(b"k00123") is not None
     b.close()
+
+
+# -- multi-get and the exact index (PR 32) ----------------------------------
+
+_K = struct.Struct(">q")
+
+
+def _layered_bucket(tmp_path):
+    """Three segments and a memtable over one key space, with newer values
+    and tombstones shadowing older ones; -> (bucket, {key: expected})."""
+    b = Bucket(str(tmp_path / "b"), memtable_max_entries=10_000)
+    want: dict[bytes, bytes | None] = {}
+    rng = random.Random(5)
+    for layer in range(4):  # 0..2 flushed, 3 stays in the memtable
+        for i in rng.sample(range(300), 120):
+            key = _K.pack(i)
+            if layer and rng.random() < 0.2:
+                b.delete(key)
+                want[key] = None
+            else:
+                val = f"L{layer}-{i}-".encode() + b"v" * (i % 300)
+                b.put(key, val)
+                want[key] = val
+        if layer < 3:
+            b.flush_memtable()
+    assert len(b._segments) == 3 and b._mem
+    return b, want
+
+
+_GET_MANY_CASES = {
+    "memtable_hits": lambda b, want: list(b._mem),
+    "oldest_segment": lambda b, want: list(b._segments[0].keys()),
+    "middle_segment": lambda b, want: list(b._segments[1].keys()),
+    "newest_segment": lambda b, want: list(b._segments[2].keys()),
+    "misses": lambda b, want: [_K.pack(i) for i in range(300, 330)]
+    + [b"", b"short", b"x" * 9],
+    "tombstones_over_older_values": lambda b, want: [
+        k for k, v in want.items() if v is None],
+    "duplicate_keys": lambda b, want: [_K.pack(7), _K.pack(7), _K.pack(299),
+                                       _K.pack(7), _K.pack(400)] * 3,
+    "any_order": lambda b, want: random.Random(9).sample(
+        [_K.pack(i) for i in range(-5, 320)], 325),
+    "empty": lambda b, want: [],
+}
+
+
+@pytest.mark.parametrize("case", sorted(_GET_MANY_CASES))
+def test_get_many_equals_loop_of_get(tmp_path, case):
+    b, want = _layered_bucket(tmp_path)
+    keys = _GET_MANY_CASES[case](b, want)
+    stats: dict = {}
+    got = b.get_many(keys, stats)
+    assert got == [b.get(k) for k in keys]
+    assert got == [want.get(k) for k in keys]
+    # one take of the lock whatever the keys; every key is answered by the
+    # memtable, by one record of one segment, or by nothing
+    assert stats["lock_takes"] == 1
+    assert stats["mem_hits"] == sum(k in b._mem for k in keys)
+    assert stats["records_read"] <= len(keys) - stats["mem_hits"]
+    # the same after a restart (segments opened from their files) and after
+    # the merges have folded the stack
+    b.close()
+    b2 = Bucket(str(tmp_path / "b"), memtable_max_entries=10_000)
+    assert b2.get_many(keys) == got
+    b2.compact()
+    assert b2.get_many(keys) == got
+    b2.close()
+
+
+def test_get_many_requires_replace(tmp_path):
+    b = Bucket(str(tmp_path / "s"), strategy="set")
+    with pytest.raises(ValueError):
+        b.get_many([b"k"])
+    b.close()
+
+
+def _parent_write(path: str, items) -> None:
+    """The segment writer as it stood before the exact index (PR 31's
+    ``DiskSegment.write``, less the reopen): what files in the field hold."""
+    sparse, keys, count, last = [], [], 0, None
+    with open(path, "wb") as f:
+        f.write(segmod.MAGIC)
+        off = len(segmod.MAGIC)
+        for key, val in items:
+            payload = msgpack.packb(val, use_bin_type=True)
+            if count % segmod.SPARSE == 0:
+                sparse.append((key, off))
+            last = (key, off)
+            keys.append(key)
+            f.write(struct.pack("<II", len(key), len(payload)))
+            f.write(key)
+            f.write(payload)
+            off += 8 + len(key) + len(payload)
+            count += 1
+        if last is not None and (count - 1) % segmod.SPARSE != 0:
+            sparse.append(last)
+        index_off = off
+        f.write(msgpack.packb([[k, o] for k, o in sparse], use_bin_type=True))
+        bloom_off = f.tell()
+        f.write(segmod.BloomFilter.build(keys, count).to_bytes())
+        f.write(struct.pack("<QQQ", index_off, bloom_off, count))
+        f.write(segmod.MAGIC)
+
+
+def _fixed_items(n=500):
+    return [(_K.pack(3 * i), None if i % 11 == 0
+             else bytes([i % 251]) * (1 + i * 7 % 400)) for i in range(n)]
+
+
+def _probe_keys(n=500):
+    return [_K.pack(i) for i in range(-2, 3 * n + 2)] + [b"", b"abc"]
+
+
+def test_segment_file_format_unchanged(tmp_path):
+    """Today's writer produces the parent's bytes (digest pinned from the
+    parent's tree), and a parent-written file opens and answers the same:
+    the exact index is derived state, built from the record headers."""
+    items = _fixed_items()
+    old, new = str(tmp_path / "old.db"), str(tmp_path / "new.db")
+    _parent_write(old, items)
+    written = DiskSegment.write(new, items)
+    raw = open(new, "rb").read()
+    assert raw == open(old, "rb").read()
+    assert hashlib.sha256(raw).hexdigest() == _PARENT_SEGMENT_SHA256
+    opened = DiskSegment(old)
+    for seg in (written, opened):
+        assert seg._keys is not None and seg.bloom is None
+        assert len(seg._keys) == len(seg._offs) == len(items)
+    expect = dict(items)
+    keys = _probe_keys()
+    vals, read = opened.get_many(keys)
+    assert vals == [expect.get(k, MISSING) for k in keys]
+    assert read == len(items)  # one record a hit, none for a miss
+    assert written.get_many(keys) == (vals, read)
+    assert list(opened.items()) == items
+    assert list(opened.items(start=_K.pack(700))) == \
+        [kv for kv in items if kv[0] >= _K.pack(700)]
+    written.close()
+    opened.close()
+
+
+# sha256 of DiskSegment.write(_fixed_items()) at commit 15267b3 (PR 31)
+_PARENT_SEGMENT_SHA256 = \
+    "75917fa1473893c9edcecc42d4e1bff0ad4dd39f4074c07cdc9a13b46bfbc2a2"
+
+
+@pytest.mark.skipif(not native.available("segment_merge"),
+                    reason="native toolchain unavailable")
+def test_native_merge_output_gets_the_exact_index(tmp_path):
+    a = [(k, v) for k, v in _fixed_items() if k[-1] % 2]
+    bb = [(k, b"newer" if v is not None else b"back") for k, v
+          in _fixed_items()[::3]]
+    pa, pb, out = (str(tmp_path / n) for n in ("a.db", "b.db", "out.db"))
+    _parent_write(pa, a)
+    _parent_write(pb, bb)
+    assert native_merge([pa, pb], out, "replace", False) is not None
+    seg = DiskSegment(out)
+    assert seg._keys is not None and seg.bloom is None
+    expect = {**dict(a), **dict(bb)}
+    keys = _probe_keys()
+    vals, read = seg.get_many(keys)
+    assert vals == [expect.get(k, MISSING) for k in keys]
+    assert read == len(expect)
+    seg.close()
+
+
+def test_mixed_width_segment_takes_the_sparse_path(tmp_path):
+    items = sorted({f"t{i}".encode(): {b"m": bytes([i % 256])}
+                    for i in range(0, 2000, 3)}.items())
+    seg = DiskSegment.write(str(tmp_path / "m.db"), items)
+    reopened = DiskSegment(seg.path)
+    expect = dict(items)
+    keys = [f"t{i}".encode() for i in range(0, 400)] + [b"", b"zz"]
+    for s in (seg, reopened):
+        assert s._keys is None and s.bloom is not None
+        vals, read = s.get_many(keys)
+        assert vals == [expect.get(k, MISSING) for k in keys]
+        assert [s.get(k) for k in keys] == vals
+        assert read > len([v for v in vals if v is not MISSING])  # it steps
+        s.close()
+
+
+def test_index_is_built_at_write_and_open_never_by_reads(tmp_path):
+    """Rule (b) of the exact index: complete before the first read, and no
+    read changes it (a cache that filled with the traffic would read fast
+    for repeated queries only)."""
+    items = _fixed_items(200)
+    seg = DiskSegment.write(str(tmp_path / "w.db"), items)
+    for s in (seg, DiskSegment(seg.path)):
+        keys0, offs0, attrs = s._keys, s._offs, set(vars(s))
+        assert len(keys0) == 200
+        before = (keys0.tobytes(), offs0.tobytes())
+        for _ in range(3):
+            s.get_many(_probe_keys(200))
+        assert s._keys is keys0 and s._offs is offs0
+        assert (s._keys.tobytes(), s._offs.tobytes()) == before
+        assert set(vars(s)) == attrs    # and nothing was put beside it
+        s.close()
+
+
+def test_truncated_record_area_is_refused(tmp_path):
+    """A file whose footer count disagrees with its records is corrupt, and
+    the bucket quarantines it like any unreadable segment."""
+    d = tmp_path / "b"
+    b = Bucket(str(d))
+    for i in range(50):
+        b.put(_K.pack(i), b"v")
+    b.flush_memtable()
+    path = b._segments[0].path
+    b.close()
+    raw = bytearray(open(path, "rb").read())
+    foot = len(raw) - 24 - 8
+    index_off, bloom_off, count = struct.unpack_from("<QQQ", raw, foot)
+    struct.pack_into("<QQQ", raw, foot, index_off, bloom_off, count + 1)
+    open(path, "wb").write(raw)
+    with pytest.raises(ValueError):
+        DiskSegment(path)
+    b2 = Bucket(str(d))
+    assert not b2._segments and os.path.exists(path + ".corrupt")
+    b2.close()
+
+
+@pytest.mark.timeout(120)
+def test_flush_and_compaction_race_multi_get_readers(tmp_path):
+    """Readers multi-get while a writer overwrites, flushes and merges: every
+    key's answer is one of the values written for it, never torn, never
+    lost (count-based: no wall clock)."""
+    b = Bucket(str(tmp_path / "race"), memtable_max_entries=10_000)
+    nkeys, rounds = 120, 12
+    keys = [_K.pack(i) for i in range(nkeys)]
+    for k in keys:
+        b.put(k, b"r0:" + k)
+    errors: list = []
+    done = threading.Event()
+    reads = [0] * 4
+
+    def reader(slot):
+        rng = random.Random(slot)
+        while not done.is_set():
+            ks = rng.sample(keys, 10) + [_K.pack(10_000)]
+            try:
+                for k, v in zip(ks, b.get_many(ks)):
+                    if k == _K.pack(10_000):
+                        ok = v is None
+                    else:
+                        ok = v is not None and v[v.index(b":") + 1:] == k \
+                            and v.startswith(b"r")
+                    if not ok:
+                        errors.append((k, v))
+                reads[slot] += 1
+            except Exception as e:  # noqa: BLE001 — surface in main thread
+                errors.append(repr(e))
+                return
+
+    threads = [threading.Thread(target=reader, args=(s,)) for s in range(4)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)  # many more hand-overs inside the lock
+    for th in threads:
+        th.start()
+    try:
+        for r in range(1, rounds + 1):
+            for k in keys[r % 3::3]:
+                b.put(k, f"r{r}:".encode() + k)
+            b.flush_memtable()
+            if r % 2 == 0:
+                b.compact_once()
+            # each round waits for every reader to have read through it
+            mark = list(reads)
+            while not errors and any(
+                    reads[s] <= mark[s] for s in range(4)):
+                threading.Event().wait(0.001)
+    finally:
+        done.set()
+        for th in threads:
+            th.join(timeout=60)
+        sys.setswitchinterval(interval)
+    assert not any(th.is_alive() for th in threads)
+    assert not errors, errors[:5]
+    b.close()
+
+
+@pytest.mark.timeout(120)
+def test_multi_get_fetches_see_acknowledged_and_undeleted_objects(tmp_path):
+    """8 threads x 10-hit ``objects_by_docids`` against a writer that puts,
+    updates, deletes, flushes and merges. By count, no wall clock: an
+    object comes back only if its write was acknowledged and its delete
+    had not returned when the fetch started; an acknowledged doc id whose
+    delete had not begun when the fetch ended always comes back."""
+    from weaviate_tpu.core.db import DB
+    from weaviate_tpu.schema.config import CollectionConfig
+    from weaviate_tpu.storage.objects import StorageObject
+
+    db = DB(str(tmp_path / "db"))
+    col = db.create_collection(CollectionConfig(name="Doc"))
+    (shard,) = col._search_shards()
+    uid = "99000000-0000-0000-0000-{:012d}".format
+    table = threading.Lock()
+    acked: dict[int, str] = {}      # doc id -> uuid, once put_batch returned
+    deleting: set[int] = set()      # doc ids whose delete has begun
+    deleted: set[int] = set()       # ... and has returned
+    errors: list = []
+    done = threading.Event()
+    readers, rounds = 8, 10
+    fetches = [0] * readers
+
+    def put(numbers):
+        objs = [StorageObject(uuid=uid(n), collection="Doc",
+                              properties={"n": n}) for n in numbers]
+        col.put_batch(objs)
+        with table:
+            acked.update({o.doc_id: o.uuid for o in objs})
+
+    def reader(slot):
+        rng = random.Random(slot)
+        while not done.is_set():
+            with table:
+                ids = rng.choices(list(acked), k=10)
+                gone_before = set(deleted)
+            try:
+                objs = shard.objects_by_docids(ids)
+            except Exception as e:  # noqa: BLE001 — surface in main thread
+                errors.append(repr(e))
+                return
+            with table:
+                begun_after = set(deleting)
+            for d, o in zip(ids, objs):
+                if o is None:
+                    if d not in begun_after:
+                        errors.append(f"acknowledged {d} not returned")
+                elif (o.doc_id, o.uuid) != (d, acked[d]) or d in gone_before:
+                    errors.append(f"{d}: got {o.doc_id} {o.uuid}")
+            fetches[slot] += 1
+
+    put(range(60))
+    threads = [threading.Thread(target=reader, args=(s,))
+               for s in range(readers)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)  # many more hand-overs inside the lock
+    for th in threads:
+        th.start()
+    try:
+        for r in range(rounds):
+            put(range(60 + 20 * r, 80 + 20 * r))
+            by_uuid = {u: d for d, u in acked.items() if d not in deleting}
+            drop = [uid(6 * r + j) for j in range(3)]
+            again = [6 * r + 3 + j for j in range(3)]
+            with table:
+                deleting.update(by_uuid[u] for u in drop)
+            assert col.delete(drop) == 3
+            with table:
+                deleted.update(by_uuid[u] for u in drop)
+                # an update writes a new doc id and tombstones the old
+                deleting.update(by_uuid[uid(n)] for n in again)
+            put(again)
+            with table:
+                deleted.update(by_uuid[uid(n)] for n in again)
+            shard.objects.flush_memtable()
+            if r % 3 == 2:
+                shard.objects.compact_once()
+            mark = list(fetches)
+            while not errors and any(
+                    fetches[s] <= mark[s] for s in range(readers)):
+                threading.Event().wait(0.001)
+    finally:
+        done.set()
+        for th in threads:
+            th.join(timeout=60)
+        sys.setswitchinterval(interval)
+    assert not any(th.is_alive() for th in threads)
+    assert not errors, errors[:5]
+    assert len(shard.objects._segments) >= 2 and min(fetches) >= rounds
+    db.close()

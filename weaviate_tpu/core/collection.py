@@ -1217,20 +1217,33 @@ class Collection:
             b = np.atleast_2d(queries).shape[0]
         out: list[list[tuple[StorageObject, float]]] = []
         with TRACER.child("objects.fetch") as span:
+            # the kept candidates of ALL rows, then one multi-get a shard
+            kept: list[list[tuple[float, int, int]]] = []
+            wanted: dict[int, list[int]] = {}
             for qi in range(b):
-                cands: list[tuple[float, Shard, int]] = []
-                for shard, res in per_shard:
+                cands: list[tuple[float, int, int]] = []
+                for si, (_, res) in enumerate(per_shard):
                     for d, i in zip(res.dists[qi], res.ids[qi]):
                         if i >= 0:
-                            cands.append((float(d), shard, int(i)))
+                            cands.append((float(d), si, int(i)))
                 cands.sort(key=lambda t: t[0])
+                kept.append(cands[:k])
+                for _, si, docid in kept[-1]:
+                    wanted.setdefault(si, []).append(docid)
+            stats = {"records_read": 0, "mem_hits": 0, "lock_takes": 0}
+            fetched = {
+                si: iter(per_shard[si][0].objects_by_docids(docids, stats))
+                for si, docids in wanted.items()}
+            for row_cands in kept:
                 row = []
-                for d, shard, docid in cands[:k]:
-                    obj = shard.get_by_docid(docid)
-                    if obj is not None:
+                for d, si, _ in row_cands:
+                    # a shard's objects come back in the order its doc ids
+                    # were collected: row by row, rank by rank
+                    obj = next(fetched[si])
+                    if obj is not None:  # deleted between scan and fetch
                         row.append((obj, d))
                 out.append(row)
-            span.set(objects=sum(len(row) for row in out))
+            span.set(objects=sum(len(row) for row in out), **stats)
         return out
 
     def bm25_search(

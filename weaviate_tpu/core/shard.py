@@ -566,8 +566,7 @@ class Shard:
                 if obj is not winner:
                     obj.doc_id = winner.doc_id
                 doc_ids.append(winner.doc_id)
-            for uuid, obj in final.items():
-                prev = self.ids.get(uuid.encode())
+            for prev in self.ids.get_many([u.encode() for u in final]):
                 if prev is not None:
                     # update == new docid, old one tombstoned (reference
                     # updates reuse uuid but bump docid)
@@ -842,8 +841,20 @@ class Shard:
             return idx.search(queries, k, allow_list,
                               est_selectivity=est_selectivity)
 
-    def objects_by_docids(self, doc_ids: np.ndarray) -> list[Optional[StorageObject]]:
-        return [self.get_by_docid(int(d)) if d >= 0 else None for d in doc_ids]
+    def objects_by_docids(
+            self, doc_ids, stats: Optional[dict] = None,
+    ) -> list[Optional[StorageObject]]:
+        """Objects of ``doc_ids`` in the order given (``None``: a negative
+        id, or an object that is gone) by ONE multi-get of the ``objects``
+        bucket; ``stats`` as in :meth:`Bucket.get_many`."""
+        ids = [int(d) for d in doc_ids]
+        raws = iter(self.objects.get_many(
+            [_DOCID.pack(d) for d in ids if d >= 0], stats))
+        out: list[Optional[StorageObject]] = []
+        for d in ids:
+            raw = next(raws) if d >= 0 else None
+            out.append(None if raw is None else StorageObject.from_bytes(raw))
+        return out
 
     # -- fused multi-target serving (docs/multitarget.md) ------------------
     def multi_target_device_eligible(self, targets: tuple[str, ...]) -> bool:

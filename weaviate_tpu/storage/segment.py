@@ -11,10 +11,26 @@ disk and loads only a sparse index (every SPARSE-th key) plus a bloom filter:
     bloom:  [u64 nbits][u32 nhashes][bit bytes]
     footer: [u64 index_off][u64 bloom_off][u64 count][magic]
 
-``get`` = bloom probe -> bisect sparse index -> scan <= SPARSE records via
-mmap. Iteration streams records in key order (compaction never materializes a
-segment in RAM). Tombstones are msgpack ``nil`` payloads, kept until
-compaction drops them.
+Point reads take one of two paths, chosen by the key widths the segment
+holds (no option):
+
+- **Exact index** — every key has one width (the ``objects`` bucket: 8-byte
+  big-endian doc ids; ``ids``: uuid bytes). The segment keeps every key and
+  its record offset in RAM: a numpy ``S<width>`` array plus ``uint64``
+  offsets, ``width + 8`` bytes a record (16 B for ``objects``: 4 MB for
+  250,000 rows). ``get_many`` is one ``np.searchsorted`` for all of a
+  request's keys and one record read a hit; the bloom filter is not loaded.
+  The index is built when the segment is written (``write`` has every key
+  and offset in hand) or opened (one pass over the record headers: restart,
+  ``native_merge``'s output) — never filled by reads.
+- **Sparse index** — keys of mixed width (the inverted buckets): bloom probe
+  -> bisect the sparse index -> scan <= SPARSE records via mmap.
+
+The file format is the same for both: the exact index is derived state, and
+a file written before it existed opens and answers the same. Iteration
+streams records in key order (compaction never materializes a segment in
+RAM). Tombstones are msgpack ``nil`` payloads, kept until compaction drops
+them.
 """
 
 from __future__ import annotations
@@ -27,6 +43,7 @@ import struct
 from typing import Any, Iterator
 
 import msgpack
+import numpy as np
 
 MAGIC = b"WVTSEG01"
 SPARSE = 32  # one index entry per this many records
@@ -89,9 +106,12 @@ class BloomFilter:
 
 
 class DiskSegment:
-    """Immutable on-disk sorted segment; RAM cost is the sparse index only."""
+    """Immutable on-disk sorted segment; RAM cost is the exact index
+    (uniform key width) or the sparse index + bloom (mixed widths)."""
 
-    def __init__(self, path: str):
+    def __init__(self, path: str, exact=None):
+        """``exact``: (keys, offsets) from :meth:`write`, which has them in
+        hand; a segment opened from a file reads them off the headers."""
         self.path = path
         self._f = open(path, "rb")
         size = os.fstat(self._f.fileno()).st_size
@@ -104,17 +124,88 @@ class DiskSegment:
         idx = msgpack.unpackb(bytes(self._mm[index_off:bloom_off]), raw=True)
         self._idx_keys: list[bytes] = [e[0] for e in idx]
         self._idx_offs: list[int] = [e[1] for e in idx]
-        self.bloom = BloomFilter.from_bytes(bytes(self._mm[bloom_off:foot_at]))
+        self._keys, self._offs = \
+            self._scan_exact() if exact is None else exact
+        # a segment with an exact index answers absence itself
+        self.bloom = None if self._keys is not None else \
+            BloomFilter.from_bytes(bytes(self._mm[bloom_off:foot_at]))
+
+    def _scan_exact(self):
+        """(keys, offsets) off one pass over the record headers, or
+        (None, None) at the first key of another width."""
+        mm, off, end = self._mm, len(MAGIC), self._data_end
+        if off >= end:
+            return None, None
+        width = _REC.unpack_from(mm, off)[0]
+        keys = bytearray(width * self.count)
+        offs = np.empty(self.count, np.uint64)
+        i = 0
+        while off < end and i < self.count:
+            klen, vlen = _REC.unpack_from(mm, off)
+            if klen != width:
+                return None, None
+            offs[i] = off
+            keys[i * width:(i + 1) * width] = mm[off + 8:off + 8 + width]
+            off += _REC.size + klen + vlen
+            i += 1
+        if i != self.count or off != end:
+            raise ValueError(
+                f"corrupt segment {self.path!r} (record count)")
+        return _exact_index(bytes(keys), width, offs)
 
     # -- reads ------------------------------------------------------------
+    def _value(self, off: int, vlen: int):
+        """Decode the msgpack payload at ``off``. A ``bin`` payload (the
+        replace buckets' opaque bytes) is sliced past its header: one copy
+        out of the mmap, not two."""
+        mm = self._mm
+        hdr = _BIN_HEADER.get(mm[off])
+        if hdr is not None:
+            return mm[off + hdr:off + vlen]
+        return msgpack.unpackb(mm[off:off + vlen], raw=True)
+
     def get(self, key: bytes):
         """Value for key, None for a tombstone, MISSING when absent."""
+        return self.get_many([key])[0][0]
+
+    def get_many(self, keys: list[bytes]):
+        """([value | None (tombstone) | MISSING per key], records read).
+        Keys in any order, duplicates allowed."""
+        if self._keys is None:
+            read = 0
+            out = []
+            for key in keys:
+                v, n = self._get_sparse(key)
+                out.append(v)
+                read += n
+            return out, read
+        out = [MISSING] * len(keys)
+        width = self._keys.dtype.itemsize
+        # a key of another width cannot be here (and must not reach the
+        # S-dtype compare, which pads with NULs)
+        at = [i for i, k in enumerate(keys) if len(k) == width]
+        if not at:
+            return out, 0
+        q = np.frombuffer(b"".join([keys[i] for i in at]), self._keys.dtype)
+        pos = np.minimum(self._keys.searchsorted(q), self.count - 1)
+        found = (self._keys[pos] == q).tolist()
+        mm = self._mm
+        read = 0
+        for i, hit, off in zip(at, found, self._offs[pos].tolist()):
+            if hit:
+                klen, vlen = _REC.unpack_from(mm, off)
+                out[i] = self._value(off + _REC.size + klen, vlen)
+                read += 1
+        return out, read
+
+    def _get_sparse(self, key: bytes):
+        """(value, records stepped) by bloom probe -> bisect -> scan."""
         if not self._idx_keys or key not in self.bloom:
-            return MISSING
+            return MISSING, 0
         # rightmost sparse entry with idx_key <= key
         i = bisect.bisect_right(self._idx_keys, key) - 1
         if i < 0:
-            return MISSING
+            return MISSING, 0
         off = self._idx_offs[i]
         stop = (
             self._idx_offs[i + 1]
@@ -122,17 +213,19 @@ class DiskSegment:
             else self._data_end
         )
         mm = self._mm
+        read = 0
         while off <= stop and off < self._data_end:
             klen, vlen = _REC.unpack_from(mm, off)
             off += _REC.size
-            k = bytes(mm[off:off + klen])
+            k = mm[off:off + klen]
             off += klen
+            read += 1
             if k == key:
-                return msgpack.unpackb(bytes(mm[off:off + vlen]), raw=True)
+                return self._value(off, vlen), read
             if k > key:
-                return MISSING
+                return MISSING, read
             off += vlen
-        return MISSING
+        return MISSING, read
 
     def __contains__(self, key: bytes) -> bool:
         return self.get(key) is not MISSING
@@ -153,12 +246,12 @@ class DiskSegment:
         while off < end:
             klen, vlen = _REC.unpack_from(mm, off)
             off += _REC.size
-            k = bytes(mm[off:off + klen])
+            k = mm[off:off + klen]
             off += klen
             if start is not None and k < start:
                 off += vlen  # inside the sparse gap, before the cursor
                 continue
-            v = msgpack.unpackb(bytes(mm[off:off + vlen]), raw=True)
+            v = self._value(off, vlen)
             off += vlen
             yield k, v
 
@@ -187,6 +280,8 @@ class DiskSegment:
         tmp = path + ".tmp"
         sparse: list[tuple[bytes, int]] = []
         keys: list[bytes] = []
+        offs: list[int] = []
+        widths: set[int] = set()
         count = 0
         last: tuple[bytes, int] | None = None
         with open(tmp, "wb") as f:
@@ -198,6 +293,8 @@ class DiskSegment:
                     sparse.append((key, off))
                 last = (key, off)
                 keys.append(key)
+                offs.append(off)
+                widths.add(len(key))
                 f.write(_REC.pack(len(key), len(payload)))
                 f.write(key)
                 f.write(payload)
@@ -214,7 +311,22 @@ class DiskSegment:
             f.flush()
             os.fsync(f.fileno())
         os.replace(tmp, path)
-        return DiskSegment(path)
+        if len(widths) != 1:
+            return DiskSegment(path, exact=(None, None))
+        return DiskSegment(path, exact=_exact_index(
+            b"".join(keys), widths.pop(), np.array(offs, np.uint64)))
+
+
+# msgpack ``bin`` type byte -> header length (bin8 / bin16 / bin32)
+_BIN_HEADER = {0xC4: 2, 0xC5: 3, 0xC6: 5}
+
+
+def _exact_index(keys: bytes, width: int, offs: np.ndarray):
+    """(sorted ``S<width>`` key array over ``keys``, record offsets); no
+    index for zero-width keys (numpy has no ``S0`` ordering)."""
+    if not width:
+        return None, None
+    return np.frombuffer(keys, f"S{width}"), offs
 
 
 def native_merge(in_paths: list[str], out_path: str, strategy: str,

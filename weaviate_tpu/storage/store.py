@@ -12,10 +12,12 @@ Strategies implemented:
 - ``map``    — value is a key->bytes mapping merged newest-wins per map-key
                (postings with payloads)
 
-Segments are disk-resident (``storage/segment.py``): sparse index + bloom
-filter in RAM, record reads via mmap, iteration/compaction as streaming
-k-way merges — a bucket's open cost is O(segments * count/SPARSE), not
-O(corpus) (reference ``segment_bloom_filters.go``, ``segmentindex/``).
+Segments are disk-resident (``storage/segment.py``): record reads via mmap,
+iteration/compaction as streaming k-way merges. A segment whose keys all
+have one width (``objects``, ``ids``) keeps an exact key -> offset index in
+RAM (width + 8 bytes a record, built at write / open); one of mixed widths
+the sparse index + bloom filter (reference ``segment_bloom_filters.go``,
+``segmentindex/``).
 """
 
 from __future__ import annotations
@@ -137,22 +139,60 @@ class Bucket:
     def get(self, key: bytes) -> Optional[bytes]:
         if self.strategy in ("roaringset", "roaringsetrange"):
             return self.roaring_get(key)
+        if self.strategy == "replace":
+            return self.get_many([key])[0]
         try:
-            return self._get_locked(key)
+            return self._get_merged(key)
         except ValueError as e:
             self._guard_closed(e)
 
-    def _get_locked(self, key: bytes) -> Optional[bytes]:
-        with self._lock:
-            if self.strategy == "replace":
-                if key in self._mem:
-                    return self._mem[key]
+    def get_many(self, keys: list[bytes],
+                 stats: Optional[dict] = None) -> list[Optional[bytes]]:
+        """Newest value of each key (``None``: absent or deleted), in the
+        order asked, under ONE take of the bucket lock: the memtable first,
+        then the segments newest to oldest for the keys still open; a
+        tombstone ends a key's search. Keys in any order, duplicates
+        allowed. ``stats`` (a request's counters, see ``docs/tracing.md``
+        ``objects.fetch``) gains ``lock_takes``, ``mem_hits`` and
+        ``records_read``."""
+        if self.strategy != "replace":
+            raise ValueError("get_many() requires replace strategy")
+        out: list[Optional[bytes]] = [None] * len(keys)
+        records = 0
+        try:
+            with self._lock:
+                mem = self._mem
+                open_at = []
+                for i, key in enumerate(keys):
+                    v = mem.get(key, _MISSING)
+                    if v is _MISSING:
+                        open_at.append(i)
+                    else:
+                        out[i] = v
+                mem_hits = len(keys) - len(open_at)
                 for seg in reversed(self._segments):
-                    v = seg.get(key)
-                    if v is not _MISSING:
-                        return v
-                return None
-            # set/map/inverted: merged dict view
+                    if not open_at:
+                        break
+                    vals, n = seg.get_many([keys[i] for i in open_at])
+                    records += n
+                    still = []
+                    for i, v in zip(open_at, vals):
+                        if v is _MISSING:
+                            still.append(i)
+                        else:
+                            out[i] = v
+                    open_at = still
+        except ValueError as e:
+            self._guard_closed(e)
+        if stats is not None:
+            stats["lock_takes"] = stats.get("lock_takes", 0) + 1
+            stats["mem_hits"] = stats.get("mem_hits", 0) + mem_hits
+            stats["records_read"] = stats.get("records_read", 0) + records
+        return out
+
+    def _get_merged(self, key: bytes) -> dict:
+        """set/map/inverted: merged dict view, oldest segment first."""
+        with self._lock:
             merged: dict = {}
             for seg in self._segments:
                 v = seg.get(key)
