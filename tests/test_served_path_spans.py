@@ -54,8 +54,17 @@ BATCH_TREE = {
 # thread, and the mask's way to the device, once a batch
 FILTERED_TREE = {**SEARCH_TREE, "filter.resolve": "grpc.Search",
                  "flat.mask": "flat.dispatch"}
+# what a hybrid Search adds: the sparse leg on a pool thread (its engine and
+# its object reads beneath it), the dense leg's span around the flat path
+# (index.search re-enters the request's scope, so it stays the root's child;
+# the leg's own objects.fetch hangs under the leg), and the fusion
+HYBRID_TREE = {**SEARCH_TREE, "hybrid.sparse": "grpc.Search",
+               "bm25.search": "hybrid.sparse", "bm25.fetch": "hybrid.sparse",
+               "hybrid.dense": "grpc.Search", "objects.fetch": "hybrid.dense",
+               "hybrid.fuse": "grpc.Search"}
 # 10 before the flat path passed the dispatcher: + dispatch.batch
 SEARCH_BUDGET, BATCH_BUDGET, FILTERED_BUDGET = 11, 16, 13
+HYBRID_BUDGET = SEARCH_BUDGET + 5
 
 
 def _serve(tmp_dbdir, sync_writes=False, max_workers=None):
@@ -265,6 +274,60 @@ def test_a_filtered_search_adds_two_spans_inside_its_budget(served):
         by_name["index.search"][0]["startTimeUnixNano"]
     assert by_name["flat.prepare"][0]["endTimeUnixNano"] <= \
         by_name["flat.mask"][0]["startTimeUnixNano"]
+
+
+@pytest.mark.parametrize("fusion", ["relativeScoreFusion", "rankedFusion"])
+def test_a_hybrid_search_adds_its_legs_and_fusion_under_the_root(
+        served, fusion):
+    """The spans and attributes the hybrid cell's per-layer metrics read
+    (``benchmark/metrics/hybrid_*.json``, ``bm25_*.json``)."""
+    client, _ = served
+    batch = _batch(100)
+    for i, o in enumerate(batch.objects):
+        o.properties_json = json.dumps(
+            {"title": f"article {i} about topic{i % 7} and the rest"})
+    assert not client.batch_objects(batch).errors
+    req = _search()
+    req.use_hybrid, req.bm25_query = True, "the article on topic3"
+    if fusion != "relativeScoreFusion":     # else: the server's default
+        req.fusion = fusion
+    client.search(req)      # compiles the k = 20 scans and the fusion
+    TRACER.clear()
+    (result,) = client.search(req).results
+    assert len(result.hits) == 10
+    spans = _one_trace("grpc.Search")
+    by_name = _check_tree(spans, HYBRID_TREE, "grpc.Search")
+    assert {n: len(v) for n, v in by_name.items()} == {
+        n: 1 for n in HYBRID_TREE if n != "flat.warm"}
+    assert len(spans) <= HYBRID_BUDGET
+    assert by_name["grpc.Search"][0]["attributes"]["legs_shed"] == 0
+    sparse = by_name["hybrid.sparse"][0]["attributes"]
+    # each leg is ceil(hybrid_overfetch_factor x limit) deep
+    assert (sparse["k"], sparse["hits"]) == (20, 20)
+    assert sparse["pool_wait_ms"] >= 0
+    engine = by_name["bm25.search"][0]["attributes"]
+    # "the" and "on" are stopwords; "article" is in all 100 rows and
+    # "topic3" in 14 of them (the native engine may be the Python tier)
+    assert engine["engine"] in ("wand", "python")
+    assert (engine["terms"], engine["postings"], engine["hits"]) == (
+        2, 114, 20)
+    fetch = by_name["bm25.fetch"][0]["attributes"]
+    # the leg's 20 hits by one multi-get, as the dense leg's objects.fetch
+    assert (fetch["objects"], fetch["lock_takes"], fetch["mem_hits"]) == (
+        20, 1, 20)
+    assert by_name["hybrid.dense"][0]["attributes"]["k"] == 20
+    assert by_name["index.search"][0]["attributes"]["k"] == 20
+    assert by_name["objects.fetch"][0]["attributes"]["objects"] == 20
+    assert by_name["dispatch.batch"][0]["attributes"]["group"] == str(
+        ("hybrid", fusion))
+    fuse = by_name["hybrid.fuse"][0]["attributes"]
+    assert (fuse["fusion"], fuse["legs"], fuse["tier"]) == (
+        fusion, 2, "device")
+    assert 20 <= fuse["union"] <= 40 and fuse["sync_ms"] >= 0
+    # the legs overlap; the fusion starts when both have ended
+    for leg in ("hybrid.sparse", "hybrid.dense"):
+        assert by_name[leg][0]["endTimeUnixNano"] <= \
+            by_name["hybrid.fuse"][0]["startTimeUnixNano"]
 
 
 def test_batch_objects_gives_one_trace_with_the_tables_children(served):

@@ -1263,12 +1263,14 @@ class Collection:
         sets it for filtered legs, where WAND's skipping advantage
         collapses. A shard whose tier can't serve it (segment-resident
         postings, mesh min-match) falls back to WAND and latches."""
+        from weaviate_tpu.monitoring import tracing
         from weaviate_tpu.monitoring.metrics import (
             HYBRID_FALLBACK,
             QUERIES_TOTAL,
             QUERY_DURATION,
         )
         from weaviate_tpu.monitoring.slow_query import REPORTER
+        from weaviate_tpu.monitoring.tracing import TRACER
         from weaviate_tpu.serving.context import current_deadline
 
         if deadline is None:
@@ -1286,56 +1288,72 @@ class Collection:
                 if flt is not None:
                     allow = shard.allow_list(flt, space)
                 hit = None
-                if device_scoring:
-                    reason = None
-                    try:
-                        hit = shard.inverted.bm25_device_search(
+                # the leg's span (hybrid.sparse) keeps the fallback event;
+                # bm25.search, one a shard, times the engine alone
+                leg_span = tracing.current_span()
+                with TRACER.child("bm25.search", shard=shard.name) as span:
+                    stats = {"engine": "device"}
+                    if device_scoring:
+                        reason = None
+                        try:
+                            hit = shard.inverted.bm25_device_search(
+                                query, k, properties=properties,
+                                allow_list=allow, doc_space=space,
+                                operator=operator,
+                                minimum_match=minimum_match,
+                            )
+                            if hit is None:
+                                reason = "unsupported"
+                        except TimeoutError:
+                            raise  # a spent deadline is a shed, not a tier
+                        except Exception as e:
+                            # device tier down (OOM, lowering failure): the
+                            # leg still serves from WAND — latched, never a
+                            # request failure
+                            import logging
+
+                            hit, reason = None, "device_error"
+                            logging.getLogger(
+                                "weaviate_tpu.core.collection").warning(
+                                "device sparse scoring fell back to WAND "
+                                "(%s/%s): %s", self.config.name,
+                                shard.name, e)
+                        if reason is not None:
+                            HYBRID_FALLBACK.inc(stage="sparse",
+                                                reason=reason)
+                            if leg_span is not None:
+                                leg_span.add_event(
+                                    "hybrid.sparse.fallback",
+                                    reason=reason, shard=shard.name)
+                    if hit is None:
+                        hit = shard.inverted.bm25_search(
                             query, k, properties=properties,
                             allow_list=allow, doc_space=space,
                             operator=operator,
-                            minimum_match=minimum_match,
+                            minimum_match=minimum_match, stats=stats,
                         )
-                        if hit is None:
-                            reason = "unsupported"
-                    except TimeoutError:
-                        raise  # a spent deadline is a shed, not a tier
-                    except Exception as e:
-                        # device tier down (OOM, lowering failure): the
-                        # leg still serves from WAND — latched, never a
-                        # request failure
-                        import logging
-
-                        hit, reason = None, "device_error"
-                        logging.getLogger(
-                            "weaviate_tpu.core.collection").warning(
-                            "device sparse scoring fell back to WAND "
-                            "(%s/%s): %s", self.config.name, shard.name,
-                            e)
-                    if reason is not None:
-                        from weaviate_tpu.monitoring import tracing
-
-                        HYBRID_FALLBACK.inc(stage="sparse",
-                                            reason=reason)
-                        span = tracing.current_span()
-                        if span is not None:
-                            span.add_event("hybrid.sparse.fallback",
-                                           reason=reason,
-                                           shard=shard.name)
-                if hit is None:
-                    hit = shard.inverted.bm25_search(
-                        query, k, properties=properties, allow_list=allow,
-                        doc_space=space, operator=operator,
-                        minimum_match=minimum_match,
-                    )
-                ids, scores = hit
+                    ids, scores = hit
+                    span.set(hits=len(ids), **stats)
                 for i, s in zip(ids, scores):
                     results.append((float(s), shard, int(i)))
             results.sort(key=lambda t: -t[0])
             out = []
-            for s, shard, docid in results[:k]:
-                obj = shard.get_by_docid(docid)
-                if obj is not None:
-                    out.append((obj, s))
+            with TRACER.child("bm25.fetch") as span:
+                # ONE multi-get a shard, as vector_search_batch's
+                # objects.fetch: twenty single gets were twenty takes of
+                # the bucket lock, a convoy among the request threads
+                wanted: dict[Shard, list[int]] = {}
+                for _, shard, docid in results[:k]:
+                    wanted.setdefault(shard, []).append(docid)
+                stats = {"records_read": 0, "mem_hits": 0, "lock_takes": 0}
+                fetched = {
+                    shard: iter(shard.objects_by_docids(docids, stats))
+                    for shard, docids in wanted.items()}
+                for s, shard, _ in results[:k]:
+                    obj = next(fetched[shard])   # rank order a shard
+                    if obj is not None:  # deleted between search and fetch
+                        out.append((obj, s))
+                span.set(objects=len(out), **stats)
         QUERIES_TOTAL.inc(type="bm25", collection=self.config.name)
         QUERY_DURATION.observe(time.perf_counter() - t0, type="bm25")
         return out
@@ -1409,19 +1427,25 @@ class Collection:
         else:  # auto: filtered legs, where WAND's advantage collapses
             device_sparse = flt is not None
 
+        submitted = time.perf_counter()
+
         def sparse_leg():
             # pool thread: re-enter the request scope (deadline) and the
             # ingress trace so the leg's span overlaps the dense leg's
             with serving_ctx.request_scope(req_ctx), \
                     TRACER.span("hybrid.sparse", parent=parent, k=fetch,
-                                device_scoring=device_sparse):
+                                device_scoring=device_sparse) as span:
                 t0 = time.perf_counter()
+                # submit -> a pool thread takes the leg up: the pool is
+                # shared with the shard fan-out of vector_search_batch
+                span.set(pool_wait_ms=round((t0 - submitted) * 1e3, 3))
                 out = self.bm25_search(
                     query, fetch, properties=properties, flt=flt,
                     tenant=tenant, operator=operator,
                     minimum_match=minimum_match,
                     device_scoring=device_sparse,
                 )
+                span.set(hits=len(out))
                 HYBRID_LEG_SECONDS.observe(time.perf_counter() - t0,
                                            leg="sparse")
                 return out
@@ -1433,6 +1457,7 @@ class Collection:
         weights: list[float] = []
         by_uuid: dict[str, StorageObject] = {}
         dense = None
+        legs_shed = 0
         if want_dense:
             try:
                 with TRACER.span("hybrid.dense", parent=parent,
@@ -1454,6 +1479,7 @@ class Collection:
                 if sparse_future is None or not sparse_future.done():
                     raise
                 HYBRID_LEG_SHED.inc(leg="dense")
+                legs_shed += 1
                 if parent is not None:
                     parent.add_event("hybrid.leg_shed", leg="dense")
 
@@ -1471,6 +1497,7 @@ class Collection:
                 # (with no surviving leg the request itself is over
                 # deadline and sheds below)
                 HYBRID_LEG_SHED.inc(leg="sparse")
+                legs_shed += 1
                 if parent is not None:
                     parent.add_event("hybrid.leg_shed", leg="sparse")
                 if dense is None:
@@ -1488,9 +1515,13 @@ class Collection:
             for o, _ in dense:
                 by_uuid.setdefault(o.uuid, o)
 
+        if parent is not None:
+            parent.set(legs_shed=legs_shed)     # 0 in a sound answer
         with TRACER.span("hybrid.fuse", parent=parent, fusion=fusion,
-                         legs=len(sets)):
-            fused = fuse_result_sets(sets, weights, k, fusion)
+                         legs=len(sets)) as span:
+            stats: dict = {}
+            fused = fuse_result_sets(sets, weights, k, fusion, stats)
+            span.set(**stats)
         HYBRID_REQUESTS.inc(fusion=fusion)
         return [(by_uuid[u], s) for u, s in fused if u in by_uuid]
 

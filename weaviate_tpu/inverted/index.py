@@ -551,6 +551,7 @@ class InvertedIndex:
         doc_space: int = 0,
         operator: str = "Or",
         minimum_match: int = 0,
+        stats: Optional[dict] = None,
     ) -> tuple[np.ndarray, np.ndarray]:
         """BM25F over the given (optionally boosted ``prop^2``) properties.
 
@@ -559,6 +560,11 @@ class InvertedIndex:
         must match EVERY query token; Or with minimum_match = at least
         that many distinct tokens (a token matching in several
         properties counts once).
+
+        ``stats``, where given, receives what the ``bm25.search`` span
+        reports: ``engine`` (``wand`` / ``python``), ``terms`` (query terms
+        after stopwords that have a posting list) and ``postings`` (the sum
+        of those lists' lengths: the work the query asks for).
 
         Returns (doc_ids [<=k], scores [<=k]) sorted by descending score.
         """
@@ -575,6 +581,11 @@ class InvertedIndex:
                                                   all_tokens)
             query_terms = [(p, t, w, a) for p, t, w, a, _ in weighted]
             groups = [g for _, _, _, _, g in weighted]
+            if stats is not None:
+                stats.update(
+                    engine="wand", terms=len(weighted),
+                    postings=sum(len(self.postings[p][t])
+                                 for p, t, _, _, _ in weighted))
             return self.native.search(query_terms, k, allow=allow_list,
                                       groups=groups, min_match=min_match)
 
@@ -592,6 +603,7 @@ class InvertedIndex:
         )
         scores = np.zeros(space, np.float32)
         touched = np.zeros(space, bool)
+        n_terms = n_postings = 0
 
         for prop, boost in props:
             prop_postings = self.postings.get(prop)
@@ -615,6 +627,8 @@ class InvertedIndex:
                 from weaviate_tpu.inverted.native_bm25 import bm25_idf
 
                 idf = bm25_idf(n_docs, len(plist))
+                n_terms += 1
+                n_postings += len(plist)
                 ids, tfs_u = plist.arrays()
                 tfs = tfs_u.astype(np.float32)
                 dls = (
@@ -627,6 +641,9 @@ class InvertedIndex:
                 scores[ids] += boost * term_scores
                 touched[ids] = True
 
+        if stats is not None:
+            stats.update(engine="python", terms=n_terms,
+                         postings=n_postings)
         if min_match > 1:
             touched &= self._min_match_mask(all_tokens, props, space,
                                             min_match)

@@ -600,7 +600,9 @@ class SegmentedInvertedIndex(InvertedIndex):
                     allow_list: Optional[np.ndarray] = None,
                     doc_space: int = 0,
                     operator: str = "Or",
-                    minimum_match: int = 0) -> tuple[np.ndarray, np.ndarray]:
+                    minimum_match: int = 0,
+                    stats: Optional[dict] = None,
+                    ) -> tuple[np.ndarray, np.ndarray]:
         """BM25F over bucket-resident postings. Hot path: BlockMax-WAND on
         the bounded native term cache (loaded per-term from segments, LRU
         by byte budget, invalidated on write). Fallback (cache disabled or
@@ -651,6 +653,7 @@ class SegmentedInvertedIndex(InvertedIndex):
             with self._wand_lock:
                 query_terms = []
                 groups = []
+                n_postings = 0
                 for prop, boost in props:
                     cnt = self.lens_counts.get(prop, 0)
                     avg_len = max(
@@ -659,17 +662,22 @@ class SegmentedInvertedIndex(InvertedIndex):
                         df = self._wand_ensure_locked(prop, term, pinned)
                         if not df:
                             continue
+                        n_postings += df
                         idf = math.log(
                             1.0 + (n_docs - df + 0.5) / (df + 0.5))
                         query_terms.append(
                             (prop, term, boost * idf, avg_len))
                         groups.append(all_tokens[term])
+                if stats is not None:
+                    stats.update(engine="wand", terms=len(query_terms),
+                                 postings=n_postings)
                 return self._wand.search(query_terms, k, allow=allow,
                                          groups=groups,
                                          min_match=min_match)
 
         scores = np.zeros(space, np.float32)
         touched = np.zeros(space, bool)
+        n_terms = n_postings = 0
 
         for prop, boost in props:
             cnt = self.lens_counts.get(prop, 0)
@@ -687,6 +695,8 @@ class SegmentedInvertedIndex(InvertedIndex):
                 if not len(ids):
                     continue
                 df = len(ids)
+                n_terms += 1
+                n_postings += df
                 idf = math.log(1.0 + (n_docs - df + 0.5) / (df + 0.5))
                 tfs = tfs_u.astype(np.float32)
                 denom = tfs + self.k1 * (
@@ -695,6 +705,9 @@ class SegmentedInvertedIndex(InvertedIndex):
                     idf * tfs * (self.k1 + 1) / np.maximum(denom, 1e-9))
                 touched[ids] = True
 
+        if stats is not None:
+            stats.update(engine="python", terms=n_terms,
+                         postings=n_postings)
         if min_match > 1:
             touched &= self._min_match_mask(all_tokens, props, space,
                                             min_match)

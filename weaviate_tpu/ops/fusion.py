@@ -22,6 +22,7 @@ at boot (utils/prewarm.py MANIFEST).
 from __future__ import annotations
 
 import functools
+import time
 
 import jax
 import jax.numpy as jnp
@@ -107,14 +108,16 @@ def relative_score_fusion_topk(slots, scores, weights, k: int, union: int):
 
 
 def fuse_topk(slot_sets, score_sets, weights, k: int, algorithm: str,
-              union_size: int):
+              union_size: int, stats=None):
     """Host-callable entry: pad each leg to one pow2 (legs x length)
     bucket, run the requested fusion as ONE jitted dispatch, and hand
     back (slot ids [<=k] int32 np, fused scores [<=k] f32 np) with the
     absent tail trimmed.
 
     slot_sets / score_sets: one int/float sequence per leg (rank order);
-    union_size: distinct keys across all legs (slot ids are < this).
+    union_size: distinct keys across all legs (slot ids are < this);
+    ``stats``, where given, receives ``sync_ms``: the wait for the two
+    results.
     """
     global _dispatch_count
     n_sets = max(1, len(slot_sets))
@@ -136,7 +139,10 @@ def fuse_topk(slot_sets, score_sets, weights, k: int, algorithm: str,
         raise ValueError(f"unknown fusion algorithm {algorithm!r}")
     _dispatch_count += 1
     # result materialization: the one host sync of the fusion stage
+    t0 = time.perf_counter()
     out_ids = np.asarray(ids)
     out_vals = np.asarray(vals)
+    if stats is not None:
+        stats["sync_ms"] = round((time.perf_counter() - t0) * 1e3, 3)
     live = out_ids >= 0
     return out_ids[live], out_vals[live]

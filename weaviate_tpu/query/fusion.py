@@ -153,24 +153,34 @@ def fuse_result_sets(
     weights: list[float],
     k: int,
     algorithm: str,
+    stats: Optional[dict] = None,
 ) -> list[tuple[Hashable, float]]:
     """Fuse the legs on device (one jitted dispatch), falling back to
     the exact host twin — loudly — when the device tier is disabled or
     errors. Same contract as the host functions: [(key, fused score)]
-    best-first, at most ``k`` entries."""
+    best-first, at most ``k`` entries. ``stats``, where given, receives
+    what the ``hybrid.fuse`` span reports: ``tier`` (``device`` /
+    ``host``), ``union`` (distinct keys over the legs) and, on the device
+    tier, ``sync_ms`` (the wait for the program's two results)."""
     validate_fusion(algorithm)
+    if stats is None:
+        stats = {}
     if not any(result_sets):
         return []
+    stats["tier"] = "host"
     if not device_fusion_enabled():
         _latch_fallback("disabled", None)
+        stats["union"] = len({key for rs in result_sets for key, _ in rs})
         return FUSION_ALGORITHMS[algorithm](result_sets, weights, k)
     keys, slot_sets, score_sets = assemble_slots(result_sets)
+    stats["union"] = len(keys)
     try:
         from weaviate_tpu.ops.fusion import fuse_topk
 
         ids, vals = fuse_topk(slot_sets, score_sets, weights, k,
-                              algorithm, len(keys))
+                              algorithm, len(keys), stats)
     except Exception as e:  # device tier down: serve host, latch loudly
         _latch_fallback("device_error", e)
         return FUSION_ALGORITHMS[algorithm](result_sets, weights, k)
+    stats["tier"] = "device"
     return [(keys[int(i)], float(v)) for i, v in zip(ids, vals)]
