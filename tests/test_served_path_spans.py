@@ -354,8 +354,14 @@ def test_batch_objects_gives_one_trace_with_the_tables_children(served):
     assert put["attributes"]["objects"] == 100
     assert put["attributes"]["lock_wait_ms"] >= 0
     (durable,) = by_name["shard.durable"]
-    assert durable["attributes"]["wal_ms"] >= 0
-    assert durable["attributes"]["push_ms"] >= 0
+    attrs = durable["attributes"]
+    assert attrs["wal_ms"] >= 0 and attrs["push_ms"] >= 0
+    assert attrs["store_ms"] > 0 and attrs["inverted_ms"] > 0
+    assert attrs["wal_ms"] + attrs["store_ms"] + attrs["inverted_ms"] \
+        + attrs["push_ms"] <= durable["durationMs"] + 0.01
+    # a batch is written as a batch: the delta log, the id map and the
+    # objects each get ONE write() for the hundred (was 1 + 2 x 100)
+    assert (attrs["objects"], attrs["wal_writes"]) == (100, 3)
     (drain,) = by_name["ingest.drain"]
     assert drain["attributes"]["rows"] == 100
     assert drain["attributes"]["buckets"] == 3

@@ -283,6 +283,130 @@ def test_get_many_requires_replace(tmp_path):
     b.close()
 
 
+_WAL_MODES = {"soft": {}, "sync": {"sync": True},
+              "group": {"sync": True, "group": True}}
+
+
+def _put_many_pairs():
+    """Pairs in no key order, with a key given three times, an empty value
+    and one past the file object's buffer."""
+    rng = random.Random(2)
+    pairs = [(_K.pack(rng.randrange(10_000)), rng.randbytes(rng.randrange(400)))
+             for _ in range(60)]
+    pairs[10] = (pairs[3][0], b"second")
+    pairs[40] = (pairs[3][0], b"third and last")
+    pairs[20] = (pairs[20][0], b"")
+    pairs[30] = (pairs[30][0], rng.randbytes(70_000))
+    return pairs
+
+
+def _wal_bytes(b: Bucket) -> bytes:
+    b._wal.flush_soft()
+    with open(b._wal.path, "rb") as f:
+        return f.read()
+
+
+@pytest.mark.parametrize("mode", sorted(_WAL_MODES))
+def test_put_many_writes_the_bytes_of_n_puts(tmp_path, monkeypatch, mode):
+    pairs = _put_many_pairs()
+    keys, values = [k for k, _ in pairs], [v for _, v in pairs]
+    fsyncs = []
+    real_fsync = os.fsync
+    monkeypatch.setattr(os, "fsync",
+                        lambda fd: (fsyncs.append(fd), real_fsync(fd))[1])
+    one = Bucket(str(tmp_path / "one"), **_WAL_MODES[mode])
+    many = Bucket(str(tmp_path / "many"), **_WAL_MODES[mode])
+    many.put(b"before", b"x")  # the batch is appended, not a file of its own
+    one.put(b"before", b"x")
+    fsyncs.clear()
+    for k, v in pairs:
+        one.put(k, v)
+    puts_fsyncs = len(fsyncs)
+    fsyncs.clear()
+    writes = many.wal_writes()
+    many.put_many(keys, values)
+    # one write() for the batch; where every append is fsynced, one fsync
+    # after it and before put_many returns (a put each: one a record)
+    assert many.wal_writes() - writes == 1
+    assert one.wal_writes() == 1 + len(pairs)
+    assert (len(fsyncs), puts_fsyncs) == \
+        {"soft": (0, 0), "sync": (1, len(pairs)), "group": (0, 0)}[mode]
+    assert _wal_bytes(many) == _wal_bytes(one)
+    assert many._mem == one._mem
+    assert many.get(pairs[3][0]) == b"third and last"  # the last of three
+    # the group-commit barrier sees every record of the batch
+    state = lambda b: (b._wal._appended, b._wal._synced)  # noqa: E731
+    assert state(many) == state(one)
+    many.sync_window()
+    one.sync_window()
+    assert state(many) == state(one)
+    assert state(many) == ((61, 61) if mode == "group" else (0, 0))
+    # replay: a bucket opened on either file holds the same
+    for b in (one, many):
+        b._wal.close()
+    again = Bucket(str(tmp_path / "many"), **_WAL_MODES[mode])
+    assert again._mem == one._mem
+    again.close()
+
+
+@pytest.mark.parametrize("where", ["last_byte", "last_payload",
+                                   "last_header", "previous_record"])
+def test_put_many_torn_tail_replays_the_whole_records_before_it(tmp_path,
+                                                                where):
+    """A crash inside the batch's one write() leaves a prefix of it: replay
+    recovers exactly the records that are whole, as it does after a torn
+    put, and truncates the file there."""
+    pairs = [(_K.pack(i), b"v%d-" % i + b"x" * (i * 3)) for i in range(12)]
+    b = Bucket(str(tmp_path / "b"))
+    b.put_many([k for k, _ in pairs[:4]], [v for _, v in pairs[:4]])
+    b.put_many([k for k, _ in pairs[4:]], [v for _, v in pairs[4:]])
+    data = _wal_bytes(b)
+    b._wal.close()
+    ends, off = [], 0  # where each framed record ends
+    while off < len(data):
+        off += 8 + struct.unpack_from("<I", data, off)[0]
+        ends.append(off)
+    assert len(ends) == 12 and ends[-1] == len(data)
+    last = ends[-1] - ends[-2]
+    cut = {"last_byte": 1, "last_payload": last // 2, "last_header": last - 3,
+           "previous_record": last + 5}[where]
+    whole = 10 if where == "previous_record" else 11
+    os.makedirs(tmp_path / "torn")
+    with open(tmp_path / "torn" / "wal.log", "wb") as f:
+        f.write(data[:-cut])
+    torn = Bucket(str(tmp_path / "torn"))
+    assert torn._mem == dict(pairs[:whole])
+    assert torn.get(pairs[whole][0]) is None
+    assert os.path.getsize(tmp_path / "torn" / "wal.log") == ends[whole - 1]
+    torn.put(pairs[11][0], b"after")  # and the log goes on from there
+    torn._wal.close()
+    reopened = Bucket(str(tmp_path / "torn"))
+    assert reopened._mem == {**dict(pairs[:whole]), pairs[11][0]: b"after"}
+    reopened.close()
+
+
+def test_put_many_empty_and_strategy(tmp_path):
+    b = Bucket(str(tmp_path / "b"))
+    b.put_many([], [])
+    assert b.wal_writes() == 0 and not b._mem
+    b.close()
+    s = Bucket(str(tmp_path / "s"), strategy="set")
+    with pytest.raises(ValueError):
+        s.put_many([b"k"], [b"v"])
+    s.close()
+
+
+def test_put_many_flushes_the_memtable_once_after_the_batch(tmp_path):
+    b = Bucket(str(tmp_path / "b"), memtable_max_entries=10)
+    b.put_many([_K.pack(i) for i in range(25)], [b"v"] * 25)
+    # not a durability point: one segment for the batch, the WAL restarted,
+    # the counter of write() calls carried over the rotation
+    assert len(b._segments) == 1 and not b._mem
+    assert b.wal_writes() == 1
+    assert b.get_many([_K.pack(0), _K.pack(24)]) == [b"v", b"v"]
+    b.close()
+
+
 def _parent_write(path: str, items) -> None:
     """The segment writer as it stood before the exact index (PR 31's
     ``DiskSegment.write``, less the reopen): what files in the field hold."""

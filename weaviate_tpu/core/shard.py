@@ -33,6 +33,8 @@ from weaviate_tpu.storage.objects import StorageObject
 from weaviate_tpu.storage.store import Store
 
 _DOCID = struct.Struct(">q")
+# objects a call of InvertedIndex.add_objects when re-indexing from the store
+_REINDEX_CHUNK = 1000
 
 DEFAULT_VECTOR = ""  # unnamed/default target vector
 
@@ -251,15 +253,15 @@ class Shard:
             if seq <= inv_seq:
                 continue
             if rec["o"] == "a":
-                for d in rec["d"]:
-                    raw = self.objects.get(_DOCID.pack(d))
-                    if raw is None:
-                        continue
-                    obj = StorageObject.from_bytes(raw)
+                raws = self.objects.get_many(
+                    [_DOCID.pack(d) for d in rec["d"]])
+                added = [(d, StorageObject.from_bytes(raw))
+                         for d, raw in zip(rec["d"], raws) if raw is not None]
+                self.inverted.add_objects([obj for _, obj in added])
+                for d, obj in added:
                     if not (d < len(self._live) and self._live[d]):
                         self._live_count += 1
                     self._mark_live(d)
-                    self.inverted.add_object(obj)
                     if obj.vector is not None:
                         b = batches.setdefault(DEFAULT_VECTOR, ([], []))
                         b[0].append(d)
@@ -310,17 +312,22 @@ class Shard:
         batches: dict[str, tuple[list[int], list[np.ndarray]]] = {}
         live = 0
         self._live = np.zeros(max(self._next_doc_id, 64), bool)
+        chunk: list[StorageObject] = []
         for key, raw in self.objects.items():
             obj = StorageObject.from_bytes(raw)
             live += 1
             self._mark_live(obj.doc_id)
-            self.inverted.add_object(obj)
+            chunk.append(obj)
+            if len(chunk) >= _REINDEX_CHUNK:
+                self.inverted.add_objects(chunk)
+                chunk = []
             if obj.vector is not None:
                 batches.setdefault(DEFAULT_VECTOR, ([], []))[0].append(obj.doc_id)
                 batches[DEFAULT_VECTOR][1].append(obj.vector)
             for nm, v in obj.named_vectors.items():
                 batches.setdefault(nm, ([], []))[0].append(obj.doc_id)
                 batches[nm][1].append(v)
+        self.inverted.add_objects(chunk)
         for nm, (ids, vecs) in batches.items():
             idx = self._index_for(nm, int(np.asarray(vecs[0]).shape[-1]))
             _feed_index(idx, np.asarray(ids, np.int64), vecs)
@@ -504,18 +511,55 @@ class Shard:
             return self._put_batch(objs, span)
 
     def _put_batch(self, objs: list[StorageObject], span) -> list[int]:
+        # -- before the lock: what needs nothing the lock guards ----------
+        # validate up-front so a bad object can't leave a partial batch:
+        # every vector for a target must match the index dims (or, for a
+        # brand-new target, the dims of the first vector in this batch)
+        dims = self._dims  # published copy-on-write; a pinned dim stays
+        batch_dims = dict(dims)
+        for obj in objs:
+            vec_items = []
+            if obj.vector is not None:
+                vec_items.append((DEFAULT_VECTOR, obj.vector))
+            vec_items.extend(obj.named_vectors.items())
+            for nm, vec in vec_items:
+                d = int(np.shape(vec)[-1])
+                want = batch_dims.setdefault(nm, d)
+                if d != want:
+                    raise ValueError(
+                        f"object {obj.uuid}: vector {nm or 'default'!r} dims "
+                        f"{d} != index dims {want}"
+                    )
+        new_dims = {nm: d for nm, d in batch_dims.items() if nm not in dims}
+        # same uuid twice in one batch: the later occurrence wins; the
+        # earlier one is never written (it was never visible)
+        final: dict[str, StorageObject] = {o.uuid: o for o in objs}
+        winners = list(final.values())
+        # one float32 column a target: positions among the winners (their
+        # doc ids are assigned under the lock) and the stacked rows; ragged
+        # token sets ([T, D] a doc) stay a list
+        columns: dict[str, tuple[list[int], Any]] = {}
+        for i, obj in enumerate(winners):
+            if obj.vector is not None:
+                pos, vecs = columns.setdefault(DEFAULT_VECTOR, ([], []))
+                pos.append(i)
+                vecs.append(np.asarray(obj.vector, np.float32))
+            for nm, v in obj.named_vectors.items():
+                pos, vecs = columns.setdefault(nm, ([], []))
+                pos.append(i)
+                vecs.append(np.asarray(v, np.float32))
+        vec_bytes = 0
+        for nm, (pos, vecs) in columns.items():
+            vec_bytes += sum(v.nbytes for v in vecs)
+            if self._config_for(nm).index_type != "multivector":
+                columns[nm] = (pos, np.stack(vecs))
         # memwatch gate (reference memwatch.CheckAlloc on the write path):
         # refuse the batch under memory pressure instead of OOMing mid-write
         from weaviate_tpu.monitoring.memwatch import MONITOR
 
-        est = sum(
-            (len(o.properties) * 64)
-            + (0 if o.vector is None
-               else np.asarray(o.vector).nbytes * 2)
-            + sum(np.asarray(v).nbytes * 2
-                  for v in o.named_vectors.values())
-            for o in objs)
-        MONITOR.check_alloc(est, "batch import")
+        MONITOR.check_alloc(
+            64 * sum(len(o.properties) for o in objs) + 2 * vec_bytes,
+            "batch import")
         deferred_deletes: Optional[np.ndarray] = None
         ragged: list[tuple[str, np.ndarray, list]] = []
         pushed: list[str] = []
@@ -523,54 +567,42 @@ class Shard:
         with self._lock, TRACER.child("shard.durable") as durable:
             lock_wait = time.perf_counter() - t_lock
             self._require_open()
-            # validate up-front so a bad object can't leave a partial batch:
-            # every vector for a target must match the index dims (or, for a
-            # brand-new target, the dims of the first vector in this batch)
-            batch_dims = dict(self._dims)
-            for obj in objs:
-                vec_items = []
-                if obj.vector is not None:
-                    vec_items.append((DEFAULT_VECTOR, obj.vector))
-                vec_items.extend(obj.named_vectors.items())
-                for nm, vec in vec_items:
-                    d = int(np.asarray(vec).shape[-1])
-                    want = batch_dims.setdefault(nm, d)
-                    if d != want:
-                        raise ValueError(
-                            f"object {obj.uuid}: vector {nm or 'default'!r} dims "
-                            f"{d} != index dims {want}"
-                        )
-            new_dims = {nm: d for nm, d in batch_dims.items()
-                        if nm not in self._dims}
+            wal_writes = self._delta.writes + self.store.wal_writes()
             if new_dims:
                 # pin brand-new targets' dims NOW (the index itself builds
                 # lazily at drain time): a later batch with different dims
-                # must fail validation, not poison the drain
+                # must fail validation, not poison the drain. Another
+                # writer may have pinned the target since the snapshot.
                 with self._build_lock:
+                    for nm, d in new_dims.items():
+                        if self._dims.get(nm, d) != d:
+                            raise ValueError(
+                                f"vector {nm or 'default'!r} dims {d} != "
+                                f"index dims {self._dims[nm]}")
                     self._dims = {**self._dims, **new_dims}
                     self._persist_meta()
-            # same uuid twice in one batch: the later occurrence wins; the
-            # earlier one is never written (it was never visible)
-            final: dict[str, StorageObject] = {o.uuid: o for o in objs}
-            old_docids: list[int] = []
             # doc ids are assigned over the DEDUPED set only: burning one
             # per raw element desynced _next_doc_id from the live set when
             # a batch repeated a uuid (dropped earlier duplicates report
             # the winner's id — same uuid, same visible object)
-            for obj in final.values():
-                obj.doc_id = self._next_doc_id
-                self._next_doc_id += 1
-            doc_ids: list[int] = []
-            for obj in objs:
-                winner = final[obj.uuid]
-                if obj is not winner:
-                    obj.doc_id = winner.doc_id
-                doc_ids.append(winner.doc_id)
-            for prev in self.ids.get_many([u.encode() for u in final]):
-                if prev is not None:
-                    # update == new docid, old one tombstoned (reference
-                    # updates reuse uuid but bump docid)
-                    old_docids.append(_DOCID.unpack(prev)[0])
+            first = self._next_doc_id
+            self._next_doc_id += len(winners)
+            new_ids = range(first, self._next_doc_id)
+            for obj, doc_id in zip(winners, new_ids):
+                obj.doc_id = doc_id
+            if len(winners) == len(objs):
+                doc_ids = list(new_ids)
+            else:
+                doc_ids = []
+                for obj in objs:
+                    obj.doc_id = final[obj.uuid].doc_id
+                    doc_ids.append(obj.doc_id)
+            uuid_keys = [u.encode() for u in final]
+            # update == new docid, old one tombstoned (reference updates
+            # reuse uuid but bump docid)
+            old_docids = [_DOCID.unpack(prev)[0]
+                          for prev in self.ids.get_many(uuid_keys)
+                          if prev is not None]
             self._persist_counter()
             # delta-log the adds BEFORE the object writes: a logged docid
             # whose object bytes never landed replays as a no-op, while an
@@ -578,41 +610,32 @@ class Shard:
             self._seq += 1
             t_wal = time.perf_counter()
             self._delta.append(msgpack.packb(
-                {"s": self._seq, "o": "a",
-                 "d": [o.doc_id for o in final.values()]},
+                {"s": self._seq, "o": "a", "d": list(new_ids)},
                 use_bin_type=True))
             self._delta.flush_soft()  # never let objects get durable first
-            wal_ms = (time.perf_counter() - t_wal) * 1000
-
-            batches: dict[str, tuple[list[int], list[np.ndarray]]] = {}
-            # bucket writes accumulate across the batch: one put_many /
-            # roaring_add / postings_put per (prop, key) instead of per
-            # object (segmented mode batches everything; RAM mode ranges)
+            t_store = time.perf_counter()
+            docid_keys = [_DOCID.pack(d) for d in new_ids]
+            self.ids.put_many(uuid_keys, docid_keys)
+            self.objects.put_many(docid_keys,
+                                  [obj.to_bytes() for obj in winners])
+            self._mark_live(new_ids)
+            t_inverted = time.perf_counter()
+            # range-bucket writes accumulate across the batch (segmented
+            # mode batches every bucket family)
             with self.inverted.batched_writes():
-                for obj in final.values():
-                    self._mark_live(obj.doc_id)
-                    self.ids.put(obj.uuid.encode(),
-                                 _DOCID.pack(obj.doc_id))
-                    self.objects.put(_DOCID.pack(obj.doc_id),
-                                     obj.to_bytes())
-                    self.inverted.add_object(obj)
+                self.inverted.add_objects(winners)
+            if len(self.filter_planes):
+                for obj in winners:
                     self.filter_planes.on_put(obj.doc_id, obj.properties)
-                    if obj.vector is not None:
-                        b = batches.setdefault(DEFAULT_VECTOR, ([], []))
-                        b[0].append(obj.doc_id)
-                        b[1].append(np.asarray(obj.vector, np.float32))
-                    for nm, v in obj.named_vectors.items():
-                        b = batches.setdefault(nm, ([], []))
-                        b[0].append(obj.doc_id)
-                        b[1].append(np.asarray(v, np.float32))
+            t_inverted_end = time.perf_counter()
 
             if old_docids:
                 deferred_deletes = self._delete_docids_durable(old_docids)
 
             t_push = time.perf_counter()
-            for nm, (ids, vecs) in batches.items():
-                id_arr = np.asarray(ids, np.int64)
-                if self._config_for(nm).index_type == "multivector":
+            for nm, (pos, vecs) in columns.items():
+                id_arr = first + np.asarray(pos, np.int64)
+                if isinstance(vecs, list):
                     # ragged token sets can't ride the disk queue (it
                     # stores [n, D]); they feed synchronously AFTER the
                     # lock instead
@@ -621,11 +644,16 @@ class Shard:
                     # durable chunk push — a disk write, part of the
                     # durability section; the device feed happens in the
                     # drain below, outside the lock
-                    pushed.append(self.async_queue.push(
-                        nm, id_arr, np.stack(vecs)))
-            durable.set(wal_ms=round(wal_ms, 3), push_ms=round(
-                (time.perf_counter() - t_push) * 1000, 3))
-            self._live_count += len(final)
+                    pushed.append(self.async_queue.push(nm, id_arr, vecs))
+            durable.set(
+                objects=len(winners),
+                wal_writes=self._delta.writes + self.store.wal_writes()
+                - wal_writes,
+                wal_ms=round((t_store - t_wal) * 1000, 3),
+                store_ms=round((t_inverted - t_store) * 1000, 3),
+                inverted_ms=round((t_inverted_end - t_inverted) * 1000, 3),
+                push_ms=round((time.perf_counter() - t_push) * 1000, 3))
+            self._live_count += len(winners)
             self._defer_ops += 1
         try:
             # durability ack barrier (group commit): ONE fsync per WAL
@@ -755,12 +783,15 @@ class Shard:
     def count(self) -> int:
         return self._live_count
 
-    def _mark_live(self, doc_id: int, value: bool = True) -> None:
-        if doc_id >= self._live.shape[0]:
-            grown = np.zeros(max(doc_id + 1, 2 * self._live.shape[0]), bool)
+    def _mark_live(self, doc_ids, value: bool = True) -> None:
+        """One doc id, or a batch's ``range`` of them."""
+        ids = doc_ids if isinstance(doc_ids, range) \
+            else range(doc_ids, doc_ids + 1)
+        if ids.stop > self._live.shape[0]:
+            grown = np.zeros(max(ids.stop, 2 * self._live.shape[0]), bool)
             grown[: self._live.shape[0]] = self._live
             self._live = grown
-        self._live[doc_id] = value
+        self._live[ids.start:ids.stop] = value
 
     def live_mask(self, space: int) -> np.ndarray:
         """Bool mask over the docid space marking live (non-deleted) docs.
@@ -1292,11 +1323,16 @@ class Shard:
             # until the shard reopens
             fresh.ref_resolver = self.inverted.ref_resolver
             n = 0
+            chunk: list[StorageObject] = []
             for _key, raw in self.objects.items():
                 obj = StorageObject.from_bytes(raw)
                 if obj.doc_id < len(self._live) and self._live[obj.doc_id]:
-                    fresh.add_object(obj)
+                    chunk.append(obj)
                     n += 1
+                    if len(chunk) >= _REINDEX_CHUNK:
+                        fresh.add_objects(chunk)
+                        chunk = []
+            fresh.add_objects(chunk)
             self.inverted = fresh
             return n
 

@@ -51,6 +51,12 @@ class _DenseBool:
         self._ensure(doc_id)
         self._arr[doc_id] = value
 
+    def set_many(self, doc_ids) -> None:
+        """``set(d, True)`` for every id of a non-empty sequence."""
+        ids = np.asarray(doc_ids)
+        self._ensure(int(ids.max()))
+        self._arr[ids] = True
+
     def get(self, doc_id: int) -> bool:
         return doc_id < len(self._arr) and bool(self._arr[doc_id])
 
@@ -179,12 +185,13 @@ class PropColumn:
         self.multi = _DenseBool()  # docs that carried >= 2 values
 
     def add_value(self, doc_id: int, v: Any) -> None:
-        if isinstance(v, bool):
-            self.terms.setdefault(v, _IdColumn()).append(doc_id)
+        if isinstance(v, (str, bool)):
+            idc = self.terms.get(v)
+            if idc is None:
+                idc = self.terms[v] = _IdColumn()
+            idc.append(doc_id)
         elif isinstance(v, (int, float)):
             self.num.append(doc_id, float(v))
-        elif isinstance(v, str):
-            self.terms.setdefault(v, _IdColumn()).append(doc_id)
         elif isinstance(v, dict) and "latitude" in v and "longitude" in v:
             self.geo.append(doc_id, float(v["latitude"]),
                             float(v["longitude"]))
@@ -201,20 +208,36 @@ class ColumnarProps:
 
     # -- maintenance ------------------------------------------------------
     def add(self, doc_id: int, properties: dict[str, Any]) -> None:
-        self._live.set(doc_id, True)
-        self._watermark = max(self._watermark, doc_id + 1)
+        self.mark_live((doc_id,))
         for prop, val in properties.items():
-            if val is None:
-                continue
-            col = self.props.get(prop)
-            if col is None:
-                col = self.props[prop] = PropColumn()
-            col.present.set(doc_id, True)
-            vals = val if isinstance(val, list) else [val]
-            if len(vals) > 1:
-                col.multi.set(doc_id, True)
-            for v in vals:
-                col.add_value(doc_id, v)
+            if val is not None:
+                self.add_many(prop, (doc_id,), (val,))
+
+    def mark_live(self, doc_ids) -> None:
+        """The docs of a write batch (a non-empty sequence) are live,
+        whether or not they carry a filterable property."""
+        self._live.set_many(doc_ids)
+        self._watermark = max(self._watermark, max(doc_ids) + 1)
+
+    def add_many(self, prop: str, doc_ids, values) -> None:
+        """One property's column of a write batch: ``values[i]`` (a scalar
+        or a list, never ``None``) is doc ``doc_ids[i]``'s value."""
+        col = self.props.get(prop)
+        if col is None:
+            col = self.props[prop] = PropColumn()
+        col.present.set_many(doc_ids)
+        multi = []
+        add_value = col.add_value
+        for doc_id, val in zip(doc_ids, values):
+            if isinstance(val, list):
+                if len(val) > 1:
+                    multi.append(doc_id)
+                for v in val:
+                    add_value(doc_id, v)
+            else:
+                add_value(doc_id, val)
+        if multi:
+            col.multi.set_many(multi)
 
     def delete(self, doc_id: int) -> None:
         self._live.set(doc_id, False)

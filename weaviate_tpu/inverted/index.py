@@ -17,7 +17,7 @@ from typing import Any, Optional
 
 import numpy as np
 
-from weaviate_tpu.inverted.analyzer import stopword_set, term_frequencies, tokenize
+from weaviate_tpu.inverted.analyzer import stopword_set, tokenize
 from weaviate_tpu.inverted.filters import Filter, like_to_regex
 from weaviate_tpu.schema.config import CollectionConfig, DataType
 from weaviate_tpu.storage.objects import StorageObject
@@ -190,54 +190,97 @@ class InvertedIndex:
 
     # -- write ------------------------------------------------------------
     def add_object(self, obj: StorageObject) -> None:
-        doc_id = obj.doc_id
-        self.doc_count += 1
-        self.columnar.add(
-            doc_id,
-            {p: v for p, v in obj.properties.items()
-             if v is not None and self._filterable(p)},
-        )
-        for prop, val in obj.properties.items():
-            if val is None:
-                continue
+        self.add_objects([obj])
+
+    def add_objects(self, objs: list[StorageObject]) -> None:
+        """Index a write batch (distinct, fresh doc ids: ``put_batch``
+        bumps doc ids and updates tombstone the old one) column by column:
+        the batch's (doc id, value) pairs of each property are walked once,
+        under that property's schema facts resolved once. What comes out is
+        what one object at a time would leave."""
+        if not objs:
+            return
+        self.doc_count += len(objs)
+        columns: dict[str, tuple[list[int], list]] = {}
+        for obj in objs:
+            doc_id = obj.doc_id
+            for prop, val in obj.properties.items():
+                if val is None:
+                    continue
+                col = columns.get(prop)
+                if col is None:
+                    col = columns[prop] = ([], [])
+                col[0].append(doc_id)
+                col[1].append(val)
+        self.columnar.mark_live([obj.doc_id for obj in objs])
+        for prop, (doc_ids, vals) in columns.items():
             if self._filterable(prop):
-                self.values[prop][doc_id] = val
-                self.sketches.add(prop, val)
-            if self._range_indexed(prop) and self._range_eligible(val):
-                if prop in self._range_counts and \
-                        self._range_counts[prop] is not None:
-                    self._range_counts[prop] += 1
-                if self._range_pending is not None:
-                    ids, vals = self._range_pending[prop]
-                    ids.append(doc_id)
-                    vals.append(val)
-                else:
-                    self._range_bucket(prop).put_many([doc_id], [val])
-            if isinstance(val, str) or (
-                isinstance(val, list) and val and isinstance(val[0], str)
-            ):
-                if self._searchable(prop) or self._prop_schema(prop) is None:
-                    texts = val if isinstance(val, list) else [val]
-                    scheme = self._tokenization(prop)
-                    total = 0
-                    combined: dict[str, int] = {}
-                    for t in texts:
-                        tf = term_frequencies(t, scheme, self.stopwords)
-                        total += sum(tf.values())
-                        for term, n in tf.items():
-                            combined[term] = combined.get(term, 0) + n
-                    # one posting write per (term, doc): the doc id is
-                    # fresh (put_batch bumps doc ids; updates tombstone
-                    # the old id), so no membership probe is needed
-                    pp = self.postings[prop]
-                    for term, n in combined.items():
-                        pp[term].add_new(doc_id, n)
-                    prev = self.doc_lengths[prop].set(doc_id, total)
-                    if prev is not None:
-                        self.len_totals[prop] -= prev
-                    self.len_totals[prop] += total
-                    if self.native is not None and combined:
-                        self.native.add_doc(doc_id, prop, combined, total)
+                self.columnar.add_many(prop, doc_ids, vals)
+                self.values[prop].update(zip(doc_ids, vals))
+                self.sketches.add_many(prop, vals)
+            if self._range_indexed(prop):
+                self._add_range_column(prop, doc_ids, vals)
+            if self._searchable(prop) or self._prop_schema(prop) is None:
+                self._add_text_column(prop, doc_ids, vals,
+                                      self._tokenization(prop))
+
+    def _add_range_column(self, prop: str, doc_ids, vals) -> None:
+        pairs = [(d, v) for d, v in zip(doc_ids, vals)
+                 if self._range_eligible(v)]
+        if not pairs:
+            return
+        ids, nums = map(list, zip(*pairs))
+        if self._range_counts.get(prop) is not None:
+            self._range_counts[prop] += len(ids)
+        if self._range_pending is not None:
+            pend_ids, pend_vals = self._range_pending[prop]
+            pend_ids.extend(ids)
+            pend_vals.extend(nums)
+        else:
+            self._range_bucket(prop).put_many(ids, nums)
+
+    def _add_text_column(self, prop: str, doc_ids, vals, scheme: str) -> None:
+        """Postings, document lengths and the native engine for the docs
+        of the column whose value is a string or a list of strings."""
+        stopwords = self.stopwords
+        pp = None  # the property's postings exist from its first text on
+        text_ids: list[int] = []
+        totals: list[int] = []
+        native_ids: list[int] = []
+        native_tfs: list[dict[str, int]] = []
+        native_lens: list[int] = []
+        for doc_id, val in zip(doc_ids, vals):
+            if isinstance(val, str):
+                texts = (val,)
+            elif isinstance(val, list) and val and isinstance(val[0], str):
+                texts = val
+            else:
+                continue
+            total = 0
+            combined: dict[str, int] = {}
+            for text in texts:
+                for term in tokenize(text, scheme):
+                    if term not in stopwords:
+                        combined[term] = combined.get(term, 0) + 1
+                        total += 1
+            # one posting write per (term, doc): the doc id is fresh, so
+            # no membership probe is needed
+            if pp is None:
+                pp = self.postings[prop]
+            for term, n in combined.items():
+                pp[term].add_new(doc_id, n)
+            text_ids.append(doc_id)
+            totals.append(total)
+            if combined:
+                native_ids.append(doc_id)
+                native_tfs.append(combined)
+                native_lens.append(total)
+        if not text_ids:
+            return
+        replaced = self.doc_lengths[prop].set_many(text_ids, totals)
+        self.len_totals[prop] += sum(totals) - replaced
+        if self.native is not None:
+            self.native.add_docs(prop, native_ids, native_tfs, native_lens)
 
     def delete_object(self, obj: StorageObject) -> None:
         doc_id = obj.doc_id

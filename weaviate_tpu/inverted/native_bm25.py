@@ -31,9 +31,10 @@ def _bind():
     lib.bm25_new.restype = ctypes.c_void_p
     lib.bm25_new.argtypes = [ctypes.c_float, ctypes.c_float]
     lib.bm25_free.argtypes = [ctypes.c_void_p]
-    lib.bm25_add_doc.argtypes = [
-        ctypes.c_void_p, ctypes.c_int64, _U64, _U32, ctypes.c_uint32,
-        ctypes.c_uint32]
+    # the five arrays as plain addresses: a typed cast costs more than
+    # the call at the batch lengths the write path hands over
+    lib.bm25_add_docs.argtypes = [
+        ctypes.c_void_p, ctypes.c_uint64] + [ctypes.c_void_p] * 5
     lib.bm25_add_term.argtypes = [
         ctypes.c_void_p, ctypes.c_uint64, _I64, _U32, _U32, ctypes.c_uint64]
     lib.bm25_set_params.argtypes = [
@@ -105,16 +106,27 @@ class NativeBM25:
             self._lib.bm25_free(h)
             self._h = None
 
-    def add_doc(self, doc_id: int, prop: str,
-                term_freqs: dict[str, int], doc_len: int) -> None:
-        n = len(term_freqs)
-        if n == 0:
+    def add_docs(self, prop: str, doc_ids: list[int],
+                 term_freqs: list[dict[str, int]],
+                 doc_lens: list[int]) -> None:
+        """One property's documents of a write batch (``term_freqs[i]``,
+        never empty, and ``doc_lens[i]`` are doc ``doc_ids[i]``'s): one
+        take of the lock and one hand-over of the flattened arrays."""
+        if not doc_ids:
             return
-        ids = (ctypes.c_uint64 * n)(
-            *(term_id(prop, t) for t in term_freqs))
-        tfs = (ctypes.c_uint32 * n)(*term_freqs.values())
+        offsets = np.zeros(len(doc_ids) + 1, np.uint64)
+        np.cumsum([len(tf) for tf in term_freqs], dtype=np.uint64,
+                  out=offsets[1:])
+        ids = np.array([term_id(prop, t) for tf in term_freqs for t in tf],
+                       np.uint64)
+        tfs = np.array([n for tf in term_freqs for n in tf.values()],
+                       np.uint32)
+        docs = np.array(doc_ids, np.int64)
+        lens = np.array(doc_lens, np.uint32)
         with self._lock:
-            self._lib.bm25_add_doc(self._h, doc_id, ids, tfs, n, doc_len)
+            self._lib.bm25_add_docs(
+                self._h, len(docs), docs.ctypes.data, lens.ctypes.data,
+                offsets.ctypes.data, ids.ctypes.data, tfs.ctypes.data)
 
     def add_term(self, prop: str, term: str, doc_ids: np.ndarray,
                  tfs: np.ndarray, doc_lens: np.ndarray) -> None:

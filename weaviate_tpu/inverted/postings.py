@@ -175,6 +175,17 @@ class DocLengths:
             return None
         return prev - 1
 
+    def set_many(self, docs, lengths) -> int:
+        """``set`` for each (doc, length) of a write batch (distinct docs,
+        non-empty); returns the sum of the lengths it replaced."""
+        ids = np.asarray(docs, np.int64)
+        self._ensure(int(ids.max()))
+        prev = self._arr[ids]  # length + 1 where the doc had one, else 0
+        had = int(np.count_nonzero(prev))
+        self._arr[ids] = np.asarray(lengths, np.uint32) + 1
+        self._count += len(ids) - had
+        return int(prev.sum()) - had
+
     def pop(self, doc: int, default=None):
         if 0 <= doc < len(self._arr) and self._arr[doc]:
             prev = int(self._arr[doc])

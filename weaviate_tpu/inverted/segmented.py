@@ -421,6 +421,11 @@ class SegmentedInvertedIndex(InvertedIndex):
         else:
             self._add_object_pending(obj)
 
+    def add_objects(self, objs) -> None:
+        with self.batched_writes():
+            for obj in objs:
+                self.add_object(obj)
+
     def _add_object_pending(self, obj) -> None:
         # stage locally, merge on clean completion: an exception anywhere
         # in this method (bad geo dict, mixed-type list, tokenizer error)

@@ -29,6 +29,7 @@ correctness.
 
 from __future__ import annotations
 
+import hashlib
 import heapq
 import struct
 from typing import Any, Mapping, Optional
@@ -48,8 +49,6 @@ _UNKNOWN_SELECTIVITY = 0.33
 
 def _hash64(value: Any) -> int:
     """Stable 64-bit hash of a filterable scalar (str/num/bool)."""
-    import hashlib
-
     if isinstance(value, bool):
         raw = b"b1" if value else b"b0"
     elif isinstance(value, (int, float)):
@@ -81,27 +80,32 @@ class PropertySketch:
     # -- writes -----------------------------------------------------------
     def add(self, value: Any) -> None:
         """Record one doc's value (scalar or list) for this property."""
-        self.rows += 1
-        vals = value if isinstance(value, list) else (value,)
-        for v in vals:
-            if isinstance(v, (int, float)) and not isinstance(v, bool):
-                f = float(v)
-                if self.vmin is None or f < self.vmin:
-                    self.vmin = f
-                if self.vmax is None or f > self.vmax:
-                    self.vmax = f
-            h = _hash64(v)
-            if h in self._kmv_set:
-                continue
-            if len(self._kmv) < _KMV_K:
-                heapq.heappush(self._kmv, -h)
-                self._kmv_set.add(h)
-            elif h < -self._kmv[0]:
-                self._kmv_set.discard(-heapq.heappushpop(self._kmv, -h))
-                self._kmv_set.add(h)
-                self._exact = False
-            else:
-                self._exact = False
+        self.add_many((value,))
+
+    def add_many(self, values) -> None:
+        """Record the values of a write batch's docs, one a doc, in order."""
+        self.rows += len(values)
+        kmv, kmv_set = self._kmv, self._kmv_set
+        for value in values:
+            for v in (value if isinstance(value, list) else (value,)):
+                if isinstance(v, (int, float)) and not isinstance(v, bool):
+                    f = float(v)
+                    if self.vmin is None or f < self.vmin:
+                        self.vmin = f
+                    if self.vmax is None or f > self.vmax:
+                        self.vmax = f
+                h = _hash64(v)
+                if h in kmv_set:
+                    continue
+                if len(kmv) < _KMV_K:
+                    heapq.heappush(kmv, -h)
+                    kmv_set.add(h)
+                elif h < -kmv[0]:
+                    kmv_set.discard(-heapq.heappushpop(kmv, -h))
+                    kmv_set.add(h)
+                    self._exact = False
+                else:
+                    self._exact = False
 
     def remove(self) -> None:
         """One doc carrying the property was deleted (value-agnostic: the
@@ -158,10 +162,13 @@ class SketchRegistry:
         self.props: dict[str, PropertySketch] = {}
 
     def add(self, prop: str, value: Any) -> None:
+        self.add_many(prop, (value,))
+
+    def add_many(self, prop: str, values) -> None:
         sk = self.props.get(prop)
         if sk is None:
             sk = self.props[prop] = PropertySketch()
-        sk.add(value)
+        sk.add_many(values)
 
     def remove(self, prop: str) -> None:
         sk = self.props.get(prop)

@@ -145,16 +145,21 @@ void bm25_set_params(void* h, float k1, float b) {
 
 void bm25_free(void* h) { delete static_cast<Index*>(h); }
 
-// add one document's term frequencies for one property-term-id space.
-// term_ids are 64-bit ids the caller derives from (property, term).
-void bm25_add_doc(void* h, int64_t doc, const uint64_t* term_ids,
-                  const uint32_t* tfs, uint32_t n_terms, uint32_t doc_len) {
+// add a write batch's documents of one property-term-id space in one
+// call: document i carries term_ids[offsets[i] .. offsets[i+1]) with the
+// matching tfs. term_ids are 64-bit ids the caller derives from
+// (property, term).
+void bm25_add_docs(void* h, uint64_t n_docs, const int64_t* docs,
+                   const uint32_t* doc_lens, const uint64_t* offsets,
+                   const uint64_t* term_ids, const uint32_t* tfs) {
     auto* ix = static_cast<Index*>(h);
-    ix->tombstones.erase(doc);
-    for (uint32_t i = 0; i < n_terms; ++i) {
-        auto& pl = ix->postings[term_ids[i]];
-        pl.entries.push_back({doc, tfs[i], doc_len});
-        pl.dirty = true;
+    for (uint64_t i = 0; i < n_docs; ++i) {
+        ix->tombstones.erase(docs[i]);
+        for (uint64_t j = offsets[i]; j < offsets[i + 1]; ++j) {
+            auto& pl = ix->postings[term_ids[j]];
+            pl.entries.push_back({docs[i], tfs[j], doc_lens[i]});
+            pl.dirty = true;
+        }
     }
 }
 

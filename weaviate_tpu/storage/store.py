@@ -64,6 +64,7 @@ class Bucket:
         self._paused = 0  # maintenance (flush/compact) pause counter
         self._closed = False
         self.compaction_bytes_written = 0  # write-amplification diagnostic
+        self._wal_writes = 0  # write() calls of this bucket's rotated WALs
         self._open(sync)
 
     def _open(self, sync: bool) -> None:
@@ -122,11 +123,22 @@ class Bucket:
 
     # -- public API -------------------------------------------------------
     def put(self, key: bytes, value: bytes) -> None:
+        self.put_many((key,), (value,))
+
+    def put_many(self, keys, values) -> None:
+        """``put`` for each pair of the two sequences, in order, under ONE
+        take of the bucket lock: the records ``put`` would log, concatenated
+        into one WAL ``write()`` (the file comes out byte-identical; a sync
+        WAL is fsynced once, after it and before this returns), then the
+        memtable (a key given twice keeps its last value), then the
+        memtable-to-segment check once."""
         if self.strategy != "replace":
-            raise ValueError("put() requires replace strategy")
+            raise ValueError("put()/put_many() require replace strategy")
+        recs = [msgpack.packb({"k": k, "v": v}, use_bin_type=True)
+                for k, v in zip(keys, values)]
         with self._lock:
-            self._log(key, value)
-            self._apply_mem(key, value)
+            self._wal.append_many(recs)
+            self._mem.update(zip(keys, values))  # replace: last write wins
             self._maybe_flush()
 
     def delete(self, key: bytes) -> None:
@@ -383,6 +395,7 @@ class Bucket:
                 )
             )
             self._mem = {}
+            self._wal_writes += self._wal.writes
             self._wal.close()
             WAL.delete(self._wal.path)
             self._wal = WAL(self._wal.path, sync=self._wal.sync,
@@ -505,6 +518,12 @@ class Bucket:
             if not wal.closed:
                 raise
 
+    def wal_writes(self) -> int:
+        """``write()`` calls handed to this bucket's WAL files since it was
+        opened (``shard.durable`` reports the difference over a batch)."""
+        with self._lock:
+            return self._wal_writes + self._wal.writes
+
     def flush(self) -> None:
         self._wal.flush()
 
@@ -619,6 +638,12 @@ class Store:
             buckets = list(self._buckets.values())
         for b in buckets:
             b.sync_window()
+
+    def wal_writes(self) -> int:
+        """Sum of :meth:`Bucket.wal_writes` over the store's buckets."""
+        with self._lock:
+            buckets = list(self._buckets.values())
+        return sum(b.wal_writes() for b in buckets)
 
     def compaction_debt(self) -> int:
         """Total merge debt across buckets (see Bucket.compaction_debt)."""

@@ -37,6 +37,7 @@ class WAL:
         self.group = group
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
         self._f = open(path, "ab")
+        self.writes = 0  # write() calls handed to the file (tracing)
         # group-commit barrier state: a monotonic append counter, the
         # highest counter an fsync has covered, and whether a leader's
         # fsync is in flight (followers wait instead of stacking fsyncs)
@@ -46,11 +47,21 @@ class WAL:
         self._syncing = False
 
     def append(self, payload: bytes) -> None:
-        rec = _HDR.pack(len(payload), zlib.crc32(payload)) + payload
-        self._f.write(rec)
+        self.append_many((payload,))
+
+    def append_many(self, payloads) -> None:
+        """The records ``append`` would write one by one, framed the same
+        and in the same order, handed to the file in ONE ``write()``; in
+        sync mode one flush + fsync after it, before returning (group mode:
+        every record counts toward the next :meth:`sync_window`)."""
+        recs = [_HDR.pack(len(p), zlib.crc32(p)) + p for p in payloads]
+        if not recs:
+            return
+        self._f.write(b"".join(recs))
+        self.writes += 1
         if self.group:
             with self._sync_cv:
-                self._appended += 1
+                self._appended += len(recs)
             return
         if self.sync:
             self._f.flush()
