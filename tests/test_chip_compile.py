@@ -181,3 +181,28 @@ def test_mesh_flat_search_compiles_on_four_chips(topo):
     assert N * D * 4 // 4 <= per_chip < N * D * 4 // 2
     assert "all-gather" in compiled.as_text()
     _fits(compiled)
+
+
+@pytest.mark.parametrize("candidates", [256, 1024])
+def test_fused_multivector_search_compiles_at_the_cell_s_size(one_chip,
+                                                              candidates):
+    """``msmarco128.multivector_c20``'s one program a request: the scan of a
+    65,536 x 2,560 float32 FDE plane, the gather of the candidates' token
+    sets from [65,536, 192, 128] bfloat16 planes, their exact MaxSim with a
+    [32, 128] query and the top-k. The planes are arguments (3.9 GB), the
+    gathered sets and the [C, Tq, Td] products temporaries."""
+    from weaviate_tpu.modules.device import MaxSimRerank
+    from weaviate_tpu.modules.device.store import TOKEN_DTYPE
+    from weaviate_tpu.ops.device_beam import _fused_flat_rerank
+
+    cap, fde, tokens, dims, tq = 65536, 2560, 192, 128, 32
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = _fused_flat_rerank.lower(
+        MaxSimRerank(), s((1, fde), jnp.float32), s((cap, fde), jnp.float32),
+        s((cap,), jnp.bool_), s((1, tq, dims), jnp.float32),
+        s((1, tq), jnp.bool_), s((cap, tokens, dims), TOKEN_DTYPE),
+        s((cap, tokens), jnp.bool_), fetch=candidates, k=16, metric="dot",
+        precision="bf16").compile()
+    assert compiled.memory_analysis().argument_size_in_bytes >= \
+        cap * fde * 4 + cap * tokens * dims * 2
+    _fits(compiled)

@@ -34,6 +34,10 @@ from weaviate_tpu.serving.qos import QosRejected
 from weaviate_tpu.tiering import ColdStartPending
 
 SERVICE = "weaviate_tpu.v1.WeaviateTpu"
+# a BatchObjects of 100 ColBERT passages is ~4 MB (100 x ~76 tokens x 128-d
+# float32), over gRPC's own 4 MiB default; the reference's default
+# (usecases/config DefaultGRPCMaxMsgSize)
+MAX_MESSAGE_BYTES = 104858000
 
 # admission lane per RPC (mirrors the REST endpoint->lane map): search
 # and aggregation are interactive, bulk mutation rides the batch lane
@@ -178,7 +182,23 @@ def insert_grouped(db: DB, items) -> list[tuple[int, str]]:
 
 
 def _np_from_vec(v: pb.Vector) -> np.ndarray:
+    """[D] from ``values``, or the [T, D] token set of ``token_bytes``."""
+    if v.token_dims:
+        if len(v.token_bytes) % (4 * v.token_dims):
+            raise ValueError(
+                f"token_bytes of {len(v.token_bytes)} bytes is no whole "
+                f"number of float32 rows of {v.token_dims}")
+        return np.frombuffer(v.token_bytes, "<f4").reshape(-1, v.token_dims)
     return np.asarray(v.values, np.float32)
+
+
+def _vec_to_pb(out: pb.Vector, vec) -> None:
+    vec = np.asarray(vec, np.float32)
+    if vec.ndim == 2:
+        out.token_bytes = vec.astype("<f4", copy=False).tobytes()
+        out.token_dims = vec.shape[1]
+    else:
+        out.values.extend(vec.tolist())
 
 
 # authz action + resource for each RPC (mirrors the REST layer's mapping)
@@ -302,6 +322,17 @@ class GrpcAPI:
                 "rerank_query supports a single near_vector per request; "
                 "send one request per query vector")
 
+        if any(v.token_dims for v in req.near_vectors):
+            if len(req.near_vectors) > 1 or req.use_hybrid:
+                raise ValueError(
+                    "a token set (token_bytes) is one late-interaction "
+                    "query: send one near_vector a request, without hybrid")
+            root = getattr(_ingress, "root", None)
+            if root is not None:
+                v = req.near_vectors[0]
+                root.set(query_tokens=len(v.token_bytes)
+                         // (4 * v.token_dims))
+
         if (len(req.near_vectors) > 1 and not req.use_hybrid
                 and not req.bm25_query):
             # the TPU fast path: all query vectors in one device batch
@@ -398,7 +429,7 @@ class GrpcAPI:
         if include_vector:
             vec = obj.named_vectors.get(target) if target else obj.vector
             if vec is not None:
-                hit.vector.values.extend(np.asarray(vec).tolist())
+                _vec_to_pb(hit.vector, vec)
 
     def batch_objects(self, req: pb.BatchObjectsRequest) -> pb.BatchObjectsReply:
         from weaviate_tpu.storage.objects import StorageObject
@@ -416,7 +447,8 @@ class GrpcAPI:
                         properties=json.loads(bo.properties_json)
                         if bo.properties_json else {},
                         vector=_np_from_vec(bo.vector)
-                        if bo.vector.values else None,
+                        if bo.vector.values or bo.vector.token_dims
+                        else None,
                         named_vectors={
                             k: _np_from_vec(v)
                             for k, v in bo.named_vectors.items()
@@ -503,7 +535,8 @@ class GrpcAPI:
         workers = self.max_workers if self.max_workers is not None \
             else max(8, min(64, self.qos.limiter.max_limit))
         self._server = grpc.server(
-            ArrivalStampingPool(max_workers=workers))
+            ArrivalStampingPool(max_workers=workers),
+            options=[("grpc.max_receive_message_length", MAX_MESSAGE_BYTES)])
         # native TPU-first plane + the reference's public weaviate.v1
         # contract, one port (stock clients connect unchanged)
         compat = WeaviateV1Service(self.db, auth=self.auth, rbac=self.rbac,

@@ -73,6 +73,20 @@ def _vector_index_from_rest(index_type: str, cfg: dict) -> VectorIndexConfig:
     q = _quantizer_from_rest(cfg)
     if q:
         d["quantizer"] = q
+    # multi-vector (late interaction) as the reference spells it:
+    # multivector: {enabled, muvera: {ksim, dprojections, repetitions}};
+    # rescoreLimit = the FDE candidates the exact MaxSim rescores
+    muvera = (cfg.get("multivector") or {}).get("muvera") or {}
+    for rest_name, attr in (("ksim", "ksim"), ("dprojections", "dproj"),
+                            ("repetitions", "repetitions")):
+        if rest_name in muvera:
+            d[attr] = int(muvera[rest_name])
+    if "rescoreLimit" in cfg:
+        d["rescore_limit"] = int(cfg["rescoreLimit"])
+    if isinstance(cfg.get("rerank"), dict):
+        # the fused device rerank tier (docs/modules.md), keys as
+        # RerankModuleConfig has them: module, max_tokens, params
+        d["rerank"] = cfg["rerank"]
     return VectorIndexConfig.from_dict(d)
 
 
@@ -252,6 +266,13 @@ def class_to_rest(cfg: CollectionConfig) -> dict:
     ):
         if src in vd:
             vic[dst] = vd[src]
+    if cfg.vector_config.index_type == "multivector":
+        vic["multivector"] = {"enabled": True, "muvera": {
+            "enabled": True, "ksim": vd["ksim"],
+            "dprojections": vd["dproj"], "repetitions": vd["repetitions"]}}
+        vic["rescoreLimit"] = vd["rescore_limit"]
+    if cfg.vector_config.rerank is not None:
+        vic["rerank"] = cfg.vector_config.rerank.to_dict()
     if cfg.vector_config.quantizer is not None:
         qd = cfg.vector_config.quantizer.to_dict()
         vic[qd.pop("kind")] = {"enabled": True, **{

@@ -22,6 +22,7 @@ batched per level the same way.
 
 from __future__ import annotations
 
+import contextlib
 import math
 import os
 from typing import Optional
@@ -1307,6 +1308,7 @@ class HNSWIndex(VectorIndex):
         fetch = self._fetch_width(k, ef)
         mesh_mirror = self._mesh_mirror()
         rr_name = ""  # set for real below; the except path may read it
+        planes_held = contextlib.ExitStack()   # the rerank token planes
         try:
             import jax.numpy as jnp
 
@@ -1341,7 +1343,10 @@ class HNSWIndex(VectorIndex):
                 # same dispatch; query token sets pad like the queries
                 module, rq, rqm = rerank
                 rr_name = getattr(module, "name", type(module).__name__)
-                toks, tmask = self._token_store.sync(min_rows=cap)
+                # held until the walk is enqueued: a later feed of the
+                # planes donates them (modules/device/store.py)
+                toks, tmask = planes_held.enter_context(
+                    self._token_store.planes(min_rows=cap))
                 if b_pad != b:
                     rq = np.concatenate(
                         [rq, np.repeat(rq[:1], b_pad - b, axis=0)])
@@ -1417,6 +1422,7 @@ class HNSWIndex(VectorIndex):
                     **rr_args,
                 )
                 ids, d = out if len(out) == 2 else out[2:]
+            planes_held.close()
             # graftlint: allow[host-sync-in-hot-path] reason=final beam materialization
             ids = np.asarray(ids)[:b].astype(np.int64)
             # graftlint: allow[host-sync-in-hot-path] reason=final beam materialization
@@ -1478,6 +1484,8 @@ class HNSWIndex(VectorIndex):
                 self.graph.dirty_hook = None
                 self._device_beam = None
             return None
+        finally:
+            planes_held.close()
         keep = self._keep_mask(allow_list)
         ok = (ids >= 0) & keep[np.clip(ids, 0, len(keep) - 1)]
         d = np.where(ok, d, _INF)

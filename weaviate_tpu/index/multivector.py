@@ -2,32 +2,47 @@
 
 Reference: ``adapters/repos/db/vector/multivector/muvera.go:26`` (fixed
 dimensional encoding) + ``hnsw/search.go:927`` (late-interaction rescore).
-The reference encodes per-vector in scalar Go loops; here every stage is a
-batched device op:
+The reference encodes per-vector in scalar Go loops. What is batched here,
+and where:
 
-- SimHash bucket assignment: ONE [T, ksim] matmul per repetition (sign bits
-  -> bucket id), vmapped over repetitions.
-- Bucket aggregation: ``segment_sum`` over the token axis.
-- Empty-bucket fill (docs only, as in MUVERA): hamming-nearest token via a
-  popcount table over the [B, T] xor grid.
-- Per-repetition ±1 projection: one [B, D] x [D, dproj] matmul.
-
-The FDE corpus lives in a normal ``FlatIndex`` (dot metric, HBM-resident),
-so the candidate search is the same masked-matmul + two-stage top-k kernel
-as everything else; the final exact MaxSim (Chamfer) rescore over the top
-candidates is a single padded ``[C, Tq, Td]`` einsum.
+- **The encode, on the host, in numpy, a BATCH of passages at a time**
+  (``MuveraEncoder.encode_docs``; a query is a batch of one set without the
+  fill). SimHash bucket ids: one ``[N, T, D] x [R, ksim, D]`` product and
+  its sign bits. Bucket aggregation: a one-hot ``[N, R, B, T] x [N, T, D]``
+  matmul (sums) over counts (means). Empty-bucket fill (docs only, as in
+  MUVERA): the hamming-nearest token from a popcount table over the
+  bucket ids, for the few empty buckets only. Per-repetition +-1
+  projection: one broadcast ``[N, R, B, D] x [R, D, dproj]`` matmul. No
+  Python loop over repetitions, buckets or tokens; an ingest batch of 100
+  passages is one call (its one loop copies each set into the padded
+  block).
+- **The search, on the device, ONE program a request**
+  (``ops/device_beam.fused_flat_rerank``): the FDE corpus lives in a normal
+  ``FlatIndex`` (dot metric, HBM-resident), so the candidate scan is the
+  same masked matmul + two-stage top-k as everything else; the candidates'
+  token sets are gathered from the HBM token planes
+  (``modules/device/store.py``: ``[capacity, T, D]`` bfloat16) and the exact
+  MaxSim (Chamfer) of all of them is a single padded ``[C, Tq, Td]`` einsum,
+  bfloat16 operands, float32 sums; the top-k is taken on the device too.
+  Requests are not batched with each other: each is its own program.
 """
 
 from __future__ import annotations
 
-import functools
+import time
 from typing import Optional
 
 import numpy as np
 
 from weaviate_tpu.index.base import SearchResult, VectorIndex
 from weaviate_tpu.index.flat import FlatIndex
-from weaviate_tpu.schema.config import FlatIndexConfig, MultiVectorIndexConfig
+from weaviate_tpu.modules.device.store import TOKEN_DTYPE
+from weaviate_tpu.monitoring.tracing import TRACER
+from weaviate_tpu.schema.config import (
+    FlatIndexConfig,
+    MultiVectorIndexConfig,
+    RerankModuleConfig,
+)
 
 MUVERA_SEED = 0x532C_A510
 
@@ -57,49 +72,62 @@ class MuveraEncoder:
         # graftlint: allow[host-sync-in-hot-path] reason=one-shot init; jitted encoders close over host copies
         self.proj = np.asarray(
             jax.random.rademacher(kp, (repetitions, dims, self.dproj)),
-            np.float32) / np.sqrt(self.dproj)
+            np.float32) / np.float32(np.sqrt(self.dproj))
         self.fde_dim = repetitions * self.buckets * self.dproj
         self._bit_weights = (1 << np.arange(ksim)).astype(np.int32)
 
-    # -- host-side (numpy): exact, no padding needed ------------------------
-    def _bucket_ids(self, tokens: np.ndarray) -> np.ndarray:
-        """[R, T] bucket ids from sign bits of the gaussian projections."""
-        # [R, ksim, D] x [T, D] -> [R, ksim, T]
-        dots = np.einsum("rkd,td->rkt", self.gaussians, tokens)
-        bits = (dots < 0).astype(np.int32)
-        return np.einsum("rkt,k->rt", bits, self._bit_weights)
+        # popcount of the xor of two bucket ids: their hamming distance
+        grid = np.arange(self.buckets)
+        self._hamming = np.array(
+            [[bin(x ^ y).count("1") for y in grid] for x in grid], np.int32)
+
+    # -- host-side (numpy), a padded batch of token sets at a time ----------
+    def _aggregate(self, tokens: np.ndarray, mask: np.ndarray):
+        """tokens [N, T, D] zero-padded, mask [N, T] -> (bucket ids
+        [N, R, T], per-bucket token sums [N, R, B, D], counts [N, R, B])."""
+        # sign bits of the gaussian projections -> a bucket id a token
+        dots = np.einsum("ntd,rkd->nrkt", tokens, self.gaussians,
+                         optimize=True)
+        ids = np.einsum("nrkt,k->nrt", (dots < 0).astype(np.int32),
+                        self._bit_weights)
+        onehot = (ids[:, :, None, :] == np.arange(self.buckets)[:, None]) \
+            & mask[:, None, None, :]                       # [N, R, B, T]
+        sums = np.matmul(onehot.astype(np.float32), tokens[:, None])
+        return ids, sums, onehot.sum(axis=3)
+
+    def encode_docs(self, token_sets: list[np.ndarray]) -> np.ndarray:
+        """N token sets ([T_i, D] each) -> [N, fde_dim]. Per bucket: MEAN of
+        assigned tokens; empty buckets take the hamming-nearest token, the
+        first of several equally near (MUVERA fill)."""
+        n = len(token_sets)
+        width = max(t.shape[0] for t in token_sets)
+        tokens = np.zeros((n, width, self.dims), np.float32)
+        mask = np.zeros((n, width), bool)
+        for i, t in enumerate(token_sets):
+            tokens[i, : t.shape[0]] = t
+            mask[i, : t.shape[0]] = True
+        ids, out, counts = self._aggregate(tokens, mask)
+        out /= np.maximum(counts, 1)[..., None]
+        # the few empty buckets, one row each: hamming distance of the
+        # bucket's id to every token's, padding beyond any
+        en, er, eb = np.nonzero(counts == 0)
+        if len(en):
+            ham = np.where(mask[en], self._hamming[eb[:, None], ids[en, er]],
+                           self.ksim + 1)                  # [E, T]
+            out[en, er, eb] = tokens[en, ham.argmin(axis=1)]
+        # per-repetition +-1 projection: [N, R, B, D] x [R, D, dproj]
+        return np.matmul(out, self.proj).reshape(n, -1)
 
     def encode_doc(self, tokens: np.ndarray) -> np.ndarray:
-        """[T, D] -> [fde_dim]. Per bucket: MEAN of assigned tokens; empty
-        buckets take the hamming-nearest token (MUVERA fill)."""
-        tokens = np.asarray(tokens, np.float32)
-        ids = self._bucket_ids(tokens)  # [R, T]
-        out = np.zeros((self.repetitions, self.buckets, self.dims), np.float32)
-        for r in range(self.repetitions):
-            counts = np.bincount(ids[r], minlength=self.buckets).astype(np.float32)
-            np.add.at(out[r], ids[r], tokens)
-            nz = counts > 0
-            out[r][nz] /= counts[nz][:, None]
-            if not nz.all():
-                # hamming distance between bucket index bits and token bits
-                empty = np.nonzero(~nz)[0]
-                xor = empty[:, None] ^ ids[r][None, :]  # [E, T]
-                ham = np.vectorize(lambda x: bin(x).count("1"))(xor)
-                nearest = np.argmin(ham, axis=1)
-                out[r][empty] = tokens[nearest]
-        # per-repetition projection: [B, D] @ [D, dp]
-        proj = np.einsum("rbd,rdp->rbp", out, self.proj)
-        return proj.reshape(-1)
+        """[T, D] -> [fde_dim]: a batch of one."""
+        return self.encode_docs([np.asarray(tokens, np.float32)])[0]
 
     def encode_query(self, tokens: np.ndarray) -> np.ndarray:
         """[Tq, D] -> [fde_dim]. SUM per bucket, no fill (paper asymmetry)."""
-        tokens = np.asarray(tokens, np.float32)
-        ids = self._bucket_ids(tokens)
-        out = np.zeros((self.repetitions, self.buckets, self.dims), np.float32)
-        for r in range(self.repetitions):
-            np.add.at(out[r], ids[r], tokens)
-        proj = np.einsum("rbd,rdp->rbp", out, self.proj)
-        return proj.reshape(-1)
+        tokens = np.asarray(tokens, np.float32)[None]
+        _, sums, _ = self._aggregate(
+            tokens, np.ones(tokens.shape[:2], bool))
+        return np.matmul(sums[0], self.proj).reshape(-1)
 
 
 def maxsim_scores(query: np.ndarray, cand_tokens: np.ndarray,
@@ -128,7 +156,7 @@ def maxsim_scores(query: np.ndarray, cand_tokens: np.ndarray,
         if pad:
             cand_tokens = np.concatenate(
                 [cand_tokens, np.zeros((pad, *cand_tokens.shape[1:]),
-                                       np.float32)])
+                                       cand_tokens.dtype)])
             cand_mask = np.concatenate(
                 [cand_mask, np.zeros((pad, cand_mask.shape[1]), bool)])
         import jax
@@ -144,8 +172,10 @@ def maxsim_scores(query: np.ndarray, cand_tokens: np.ndarray,
         # graftlint: allow[host-sync-in-hot-path] reason=final [C] score materialization for host rerank
         return np.asarray(sharded_maxsim(q, toks, mask, mesh=mesh))[:c]
 
-    q = jnp.asarray(query, jnp.float32)
-    c = jnp.asarray(cand_tokens, jnp.float32)
+    # operands in the candidates' dtype (bfloat16 from the token planes:
+    # the query's tokens are rounded to it), float32 sums
+    c = jnp.asarray(cand_tokens)
+    q = jnp.asarray(query, c.dtype)
     m = jnp.asarray(cand_mask, bool)
     sims = jnp.einsum("qd,ctd->cqt", q, c, preferred_element_type=jnp.float32)
     sims = jnp.where(m[:, None, :], sims, -jnp.inf)
@@ -195,7 +225,10 @@ class MultiVectorIndex(VectorIndex):
             tmax = rr_cfg.max_tokens
         else:
             self._rerank_module = build_device_reranker("rerank-maxsim")
-            tmax = 8
+            tmax = RerankModuleConfig.max_tokens
+        # the planes are as wide as the longest token set the collection
+        # expects (``rerank.max_tokens``: ColBERT's doc_maxlen); a longer
+        # one widens them, at the price of a re-feed and a recompile
         self._token_store = CandidateTokenStore(
             dims, max_tokens=tmax,
             cap_fn=lambda: self.inner.store.capacity,
@@ -213,7 +246,9 @@ class MultiVectorIndex(VectorIndex):
         # tokens BEFORE the candidate index: a racing search that sees the
         # new id in the FDE corpus must find its rescore tokens
         self._token_store.put(np.asarray(doc_ids, np.int64), token_sets)
-        fdes = np.stack([self.encoder.encode_doc(t) for t in token_sets])
+        with TRACER.child("mv.encode_docs", docs=len(token_sets),
+                          tokens=sum(t.shape[0] for t in token_sets)):
+            fdes = self.encoder.encode_docs(token_sets)
         self.inner.add_batch(np.asarray(doc_ids, np.int64), fdes)
 
     def _host_token_set(self, doc_id: int) -> Optional[np.ndarray]:
@@ -249,7 +284,9 @@ class MultiVectorIndex(VectorIndex):
         if query_tokens.shape[-1] != self.dims:
             raise ValueError(
                 f"query token dims {query_tokens.shape[-1]} != {self.dims}")
-        fde = self.encoder.encode_query(query_tokens)[None, :]
+        with TRACER.child("mv.encode_query", tokens=len(query_tokens),
+                          fde_dim=self.encoder.fde_dim):
+            fde = self.encoder.encode_query(query_tokens)[None, :]
         cand_k = max(k, self.config.rescore_limit or 4 * k)
         cand_k = min(cand_k, max(1, self.inner.count()))
         if self.inner.store.device_resident and self.inner.store.mesh is None:
@@ -264,6 +301,16 @@ class MultiVectorIndex(VectorIndex):
                 module=self._rerank_module.name,
                 reason="mesh_legacy" if self.inner.store.mesh is not None
                 else "warm_tier")
+        with TRACER.child("mv.search", candidates=cand_k, k=k,
+                          tokens=len(query_tokens), tier="host"):
+            return self._search_multi_host(query_tokens, fde, cand_k, k,
+                                           allow_list)
+
+    def _search_multi_host(self, query_tokens: np.ndarray, fde: np.ndarray,
+                           cand_k: int, k: int,
+                           allow_list: Optional[np.ndarray]) -> SearchResult:
+        """The fallback tier: FDE search, then the candidates' token sets
+        from the host planes through the module's scorer."""
         res = self.inner.search(fde, cand_k, allow_list)
         cand = res.ids[0]
         cand = cand[cand >= 0]
@@ -283,7 +330,8 @@ class MultiVectorIndex(VectorIndex):
             return SearchResult(ids=np.full((1, k), -1, np.int64),
                                 dists=np.full((1, k), np.inf, np.float32))
         tmax = max(s.shape[0] for s in sets)
-        toks = np.zeros((len(sets), tmax, self.dims), np.float32)
+        # in the planes' dtype, so that both tiers round alike
+        toks = np.zeros((len(sets), tmax, self.dims), sets[0].dtype)
         mask = np.zeros((len(sets), tmax), bool)
         for i, s in enumerate(sets):
             toks[i, : s.shape[0]] = s
@@ -315,8 +363,6 @@ class MultiVectorIndex(VectorIndex):
         module score → on-device top-k (``ops/device_beam.
         fused_flat_rerank``). Returns None to use the host path (the
         caller latches the fallback counter)."""
-        import jax.numpy as jnp
-
         from weaviate_tpu.monitoring import tracing
         from weaviate_tpu.monitoring.metrics import (
             RERANK_CANDIDATES,
@@ -328,32 +374,43 @@ class MultiVectorIndex(VectorIndex):
         name = self._rerank_module.name
         corpus, valid, _sqnorms = self.inner.store.snapshot()
         cap = int(corpus.shape[0])
-        toks, tmask = self._token_store.sync(min_rows=cap)
         tq = query_tokens.shape[0]
         tq_pad = 1 << max(0, (tq - 1).bit_length())
         qt = np.zeros((1, tq_pad, self.dims), np.float32)
         qt[0, :tq] = query_tokens
         qm = np.zeros((1, tq_pad), bool)
         qm[0, :tq] = True
-        allow_j = None
+        allow = None
         if allow_list is not None:
-            al = np.asarray(allow_list, bool)
-            if len(al) < cap:
-                al = np.pad(al, (0, cap - len(al)))
-            allow_j = jnp.asarray(al[:cap])
+            allow = np.asarray(allow_list, bool)
+            if len(allow) < cap:
+                allow = np.pad(allow, (0, cap - len(allow)))
+            allow = allow[:cap]
         # pow2 buckets so steady traffic shares a handful of compiles
         fetch = 1 << max(3, (int(cand_k) - 1).bit_length())
         out_k = min(1 << max(3, (int(k) - 1).bit_length()), fetch)
         try:
-            ids_j, d_j = fused_flat_rerank(
-                self._rerank_module, jnp.asarray(fde), corpus, valid,
-                jnp.asarray(qt), jnp.asarray(qm), toks, tmask,
-                fetch=fetch, k=out_k, allow=allow_j, metric="dot",
-                precision=self.config.precision)
-            # graftlint: allow[host-sync-in-hot-path] reason=final reranked top-k materialization
-            ids = np.asarray(ids_j)[0].astype(np.int64)
-            # graftlint: allow[host-sync-in-hot-path] reason=final reranked top-k materialization
-            d = np.asarray(d_j)[0].astype(np.float32)
+            # the request's three arrays go up with the call itself (cheaper
+            # in interpreter time than a device_put of their own: 315
+            # against 281 requests/s, PERF.md PR 35); the planes are held,
+            # with the other requests in flight, until the program is
+            # enqueued, since a later feed donates them
+            t0 = time.perf_counter()
+            with TRACER.child("mv.search", candidates=fetch, k=out_k,
+                              tokens=tq, tier="fused") as span, \
+                    self._token_store.planes(min_rows=cap) as (toks, tmask):
+                # until the planes are this request's to read: the turn at
+                # the store's lock and, after a write, the feed
+                span.set(planes_wait_ms=(time.perf_counter() - t0) * 1e3)
+                ids_j, d_j = fused_flat_rerank(
+                    self._rerank_module, fde, corpus, valid, qt, qm, toks,
+                    tmask, fetch=fetch, k=out_k, allow=allow, metric="dot",
+                    precision=self.config.precision)
+            with TRACER.child("mv.result"):
+                # graftlint: allow[host-sync-in-hot-path] reason=final reranked top-k materialization
+                ids = np.asarray(ids_j)[0].astype(np.int64)
+                # graftlint: allow[host-sync-in-hot-path] reason=final reranked top-k materialization
+                d = np.asarray(d_j)[0].astype(np.float32)
         except Exception as e:
             import logging
 
@@ -409,7 +466,7 @@ class MultiVectorIndex(VectorIndex):
         tmp = path + ".tokens.tmp"
         with open(tmp, "wb") as f:
             f.write(msgpack.packb({
-                "version": 1,
+                "version": 2,   # tokens as the planes hold them: bfloat16
                 "docs": [
                     {"d": int(d),
                      "shape": [int(mask[d].sum()), self.dims],
@@ -436,12 +493,11 @@ class MultiVectorIndex(VectorIndex):
         try:
             with open(tok_path, "rb") as f:
                 d = msgpack.unpackb(f.read(), raw=False)
-            if d.get("version") != 1:
-                return None
+            if d.get("version") != 2:
+                return None     # an older layout: rebuild from source
             ids = [rec["d"] for rec in d["docs"]]
             sets = [
-                np.frombuffer(rec["data"], np.float32)
-                .reshape(rec["shape"]).copy()
+                np.frombuffer(rec["data"], TOKEN_DTYPE).reshape(rec["shape"])
                 for rec in d["docs"]
             ]
         except (OSError, ValueError, KeyError, TypeError, AttributeError):
@@ -490,8 +546,8 @@ class MultiVectorIndex(VectorIndex):
             # serves the rescore tier from host planes — uploading the
             # token planes there would be pure HBM rent for arrays no
             # program reads
-            toks, tmask = self._token_store.sync()
-            gained += sum(a.nbytes for a in (toks, tmask))
+            with self._token_store.planes():
+                gained += self._token_store.nbytes
         return gained
 
     def stats(self) -> dict:

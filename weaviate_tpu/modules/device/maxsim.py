@@ -22,11 +22,13 @@ def batched_maxsim(q_tokens, q_mask, cand_tokens, cand_mask):
     every device module composes (the finite-guard semantics live here
     once): masked doc tokens are -inf before the max; a candidate with
     no live tokens contributes 0 per query token (matching the host
-    ``maxsim_scores`` guard); masked query tokens contribute 0."""
+    ``maxsim_scores`` guard); masked query tokens contribute 0. The
+    product's operands are the candidate planes' dtype (bfloat16: the
+    query's tokens are rounded to it), its sums float32."""
     import jax.numpy as jnp
 
-    sims = jnp.einsum("bqd,bctd->bcqt", q_tokens, cand_tokens,
-                      preferred_element_type=jnp.float32)
+    sims = jnp.einsum("bqd,bctd->bcqt", q_tokens.astype(cand_tokens.dtype),
+                      cand_tokens, preferred_element_type=jnp.float32)
     sims = jnp.where(cand_mask[:, :, None, :], sims, -jnp.inf)
     best = jnp.max(sims, axis=3)                     # [B, C, Tq]
     best = jnp.where(jnp.isfinite(best), best, 0.0)
@@ -36,10 +38,13 @@ def batched_maxsim(q_tokens, q_mask, cand_tokens, cand_mask):
 
 def batched_maxsim_host(q_tokens, q_mask, cand_tokens, cand_mask
                         ) -> np.ndarray:
-    """The numpy twin of :func:`batched_maxsim` (fallback tier)."""
+    """The numpy twin of :func:`batched_maxsim` (fallback tier): the
+    query's tokens rounded to the candidates' dtype, float32 from there."""
+    cand_tokens = np.asarray(cand_tokens)
     sims = np.einsum("bqd,bctd->bcqt",
-                     np.asarray(q_tokens, np.float32),
-                     np.asarray(cand_tokens, np.float32))
+                     np.asarray(q_tokens).astype(cand_tokens.dtype)
+                     .astype(np.float32),
+                     cand_tokens.astype(np.float32))
     sims = np.where(cand_mask[:, :, None, :], sims, -np.inf)
     best = sims.max(axis=3)
     best = np.where(np.isfinite(best), best, 0.0)
