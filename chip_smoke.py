@@ -490,7 +490,9 @@ def run(args) -> dict:
                                  "one sent")
         say(phase="get_by_id", row=probe, vector_bit_equal=True)
 
-        dev = check_device(client.nodes(), chips, n * D * 4, args.rehearse)
+        # the collection's rows are resident in bfloat16 (index/flat.py
+        # resident_dtype: a bf16 l2-squared product)
+        dev = check_device(client.nodes(), chips, n * D * 2, args.rehearse)
         check_nothing_gave_way(http_get(server.base, "/metrics").decode())
         cache = compile_cache_panel(server.base)
         say(phase="compile_cache", dir=cache["dir"], hits=cache["hits"],

@@ -15,6 +15,7 @@ test lives in THIS file so one worker owns the library.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -64,7 +65,8 @@ def _fits(compiled) -> int:
 
 def _flat_args(sharding):
     s = functools.partial(jax.ShapeDtypeStruct, sharding=sharding)
-    return (s((B, D), jnp.float32), s((N, D), jnp.float32),
+    # the rows as a default flat collection keeps them: bfloat16
+    return (s((B, D), jnp.float32), s((N, D), jnp.bfloat16),
             s((N,), jnp.bool_), s((N,), jnp.float32))
 
 
@@ -74,7 +76,7 @@ def _flat_args(sharding):
 def test_flat_search_compiles_at_serving_defaults(one_chip, metric,
                                                   approx_recall):
     """The program a default FlatIndexConfig collection serves with: bf16
-    matmul, exact selection, 131072-row chunks over a 1M x 768 fp32 corpus;
+    matmul, exact selection, 131072-row chunks over a 1M x 768 bfloat16 corpus;
     and the one a collection with ``flat_approx_recall`` set serves with
     (``lax.approx_min_k`` a chunk), the only approximate flat program."""
     from weaviate_tpu.ops.distance import flat_search
@@ -85,8 +87,8 @@ def test_flat_search_compiles_at_serving_defaults(one_chip, metric,
         corpus_sqnorms=sqnorms if metric == "l2-squared" else None,
         chunk_size=CHUNK, precision="bf16",
         approx_recall=approx_recall).compile()
-    # the corpus is an argument, not a temporary: >= 3.2 GB resident
-    assert compiled.memory_analysis().argument_size_in_bytes >= N * D * 4
+    # the corpus is an argument, not a temporary: >= 1.6 GB resident
+    assert compiled.memory_analysis().argument_size_in_bytes >= N * D * 2
     _fits(compiled)
 
 
@@ -100,14 +102,64 @@ def test_flat_search_compiles_with_a_mask_a_row(one_chip, rows):
     cap, dims = 524288, 192
     s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     compiled = flat_search.lower(
-        s((rows, dims), jnp.float32), s((cap, dims), jnp.float32), k=K,
+        s((rows, dims), jnp.float32), s((cap, dims), jnp.bfloat16), k=K,
         metric="l2-squared", valid_mask=s((cap,), jnp.bool_),
         allow_mask=s((rows, cap), jnp.bool_),
         corpus_sqnorms=s((cap,), jnp.float32), chunk_size=CHUNK,
         precision="bf16", approx_recall=0.0).compile()
     assert compiled.memory_analysis().argument_size_in_bytes >= \
-        cap * dims * 4 + rows * cap
+        cap * dims * 2 + rows * cap
     _fits(compiled)
+
+
+def _whole_corpus_ops(compiled, cap: int, dims: int) -> list[str]:
+    """Names of the instructions that convert or copy a ``[cap, dims]``
+    array: a pass over the whole corpus before (or in place of) the scan."""
+    shape = re.compile(rf"%(\S+) = \w+\[{cap},{dims}\]\S* (convert|copy)\(")
+    return [m.group(1) for m in map(shape.search,
+                                    compiled.as_text().splitlines()) if m]
+
+
+# the four flat shapes the benchmark's cells run (capacity, D, metric, B,
+# a mask a row): cohere-768-flat / msmarco-768-hybrid alone and in a batch,
+# yfcc-192-filtered with stacked masks, sift-128-flat
+CELL_SHAPES = [
+    pytest.param(262144, 768, "cosine", 1, False, id="768-cosine-b1"),
+    pytest.param(262144, 768, "cosine", 8, False, id="768-cosine-b8"),
+    pytest.param(524288, 192, "l2-squared", 8, True, id="192-l2-masks-b8"),
+    pytest.param(262144, 128, "l2-squared", 4, False, id="128-l2-b4"),
+]
+
+
+@pytest.mark.parametrize("cap,dims,metric,rows,masked", CELL_SHAPES)
+def test_flat_search_over_bfloat16_rows_has_no_whole_corpus_pass(
+        one_chip, cap, dims, metric, rows, masked):
+    """A flat collection's rows are resident in bfloat16 (``index/flat.py
+    resident_dtype``), so ``_matmul``'s cast of the corpus is the identity:
+    the compiled scan neither converts nor re-lays-out the corpus and keeps
+    no copy of it. Over float32 rows the convert is there, so this test
+    would see it come back."""
+    from weaviate_tpu.ops.distance import flat_search
+
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+
+    def compile_over(dtype):
+        return flat_search.lower(
+            s((rows, dims), jnp.float32), s((cap, dims), dtype), k=K,
+            metric=metric, valid_mask=s((cap,), jnp.bool_),
+            allow_mask=s((rows, cap), jnp.bool_) if masked else None,
+            corpus_sqnorms=(s((cap,), jnp.float32)
+                            if metric == "l2-squared" else None),
+            chunk_size=CHUNK, precision="bf16", approx_recall=0.0).compile()
+
+    narrow = compile_over(jnp.bfloat16)
+    assert _whole_corpus_ops(narrow, cap, dims) == []
+    m = narrow.memory_analysis()
+    assert m.temp_size_in_bytes < cap * dims * 2 // 100, m
+    assert cap * dims * 2 <= m.argument_size_in_bytes < cap * dims * 4
+    _fits(narrow)
+    wide = _whole_corpus_ops(compile_over(jnp.float32), cap, dims)
+    assert any(name.startswith("convert") for name in wide), wide
 
 
 def _graph_args(sharding):
@@ -170,7 +222,7 @@ def test_mesh_flat_search_compiles_on_four_chips(topo):
     flat = NamedSharding(mesh, P(SHARD_AXIS))
     repl = NamedSharding(mesh, P(None, None))
     compiled = _sharded_flat_search_jit.lower(
-        jax.ShapeDtypeStruct((N, D), jnp.float32, sharding=row),
+        jax.ShapeDtypeStruct((N, D), jnp.bfloat16, sharding=row),
         jax.ShapeDtypeStruct((N,), jnp.bool_, sharding=flat),
         jax.ShapeDtypeStruct((B, D), jnp.float32, sharding=repl),
         k=K, metric="l2-squared", mesh=mesh, precision="bf16",
@@ -178,7 +230,7 @@ def test_mesh_flat_search_compiles_on_four_chips(topo):
         chunk_size=CHUNK, approx_recall=0.0).compile()
     # memory_analysis is per device: each chip holds a quarter of the rows
     per_chip = compiled.memory_analysis().argument_size_in_bytes
-    assert N * D * 4 // 4 <= per_chip < N * D * 4 // 2
+    assert N * D * 2 // 4 <= per_chip < N * D * 2 // 2
     assert "all-gather" in compiled.as_text()
     _fits(compiled)
 

@@ -66,6 +66,47 @@ def test_flat_store_is_row_sharded(tmp_dbdir):
         db.close()
 
 
+@pytest.mark.parametrize("metric", ["cosine", "l2-squared"])
+def test_mesh_flat_rows_are_bfloat16_and_answer_as_one_chip(metric):
+    """The mesh store follows the single-chip rule (``index/flat.py
+    resident_dtype``): a bf16 product keeps its rows in bfloat16, rounded
+    once on the write path, and the sharded scan answers what the one-chip
+    scan answers over the float32 rows."""
+    import jax.numpy as jnp
+
+    from weaviate_tpu.index.flat import FlatIndex
+    from weaviate_tpu.ops.distance import flat_search, normalize
+
+    rng = np.random.default_rng(5)
+    n, d, k = 1500, 32, 10
+    vecs = rng.standard_normal((n, d)).astype(np.float32)
+    idx = FlatIndex(d, FlatIndexConfig(distance=metric))
+    idx.add_batch(np.arange(n), vecs)
+    corpus, valid, sqnorms = idx.store.snapshot()
+    assert idx.store.mesh is not None and corpus.dtype == jnp.bfloat16
+    assert len(corpus.sharding.device_set) == 8
+    queries = vecs[:4] + 0.1 * rng.standard_normal((4, d)).astype(np.float32)
+    got = idx.search(queries, k, approx_recall=0.0)
+
+    rows, qj = jnp.asarray(vecs), jnp.asarray(queries)
+    if metric == "cosine":
+        rows, qj = normalize(rows), normalize(qj)
+    np.testing.assert_array_equal(
+        np.asarray(corpus[:n]).view(np.uint16),
+        np.asarray(rows.astype(jnp.bfloat16)).view(np.uint16))
+    cap = corpus.shape[0]
+    full = jnp.zeros((cap, d), jnp.float32).at[:n].set(rows)
+    want_d, want_ids = flat_search(
+        qj, full, k=k, metric=metric,
+        valid_mask=jnp.zeros((cap,), jnp.bool_).at[:n].set(True),
+        corpus_sqnorms=(jnp.sum(full ** 2, axis=-1)
+                        if metric == "l2-squared" else None),
+        precision="bf16")
+    np.testing.assert_array_equal(got.ids, np.asarray(want_ids))
+    np.testing.assert_allclose(got.dists, np.asarray(want_d),
+                               rtol=1e-5, atol=1e-5)
+
+
 @pytest.mark.parametrize("index_config", [
     FlatIndexConfig(distance="l2-squared", precision="fp32"),
     HNSWIndexConfig(distance="l2-squared", ef=64, ef_construction=64,

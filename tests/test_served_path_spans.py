@@ -188,6 +188,9 @@ def test_search_gives_one_trace_with_the_tables_children(served, vectors):
     assert by_name["flat.dispatch"][0]["attributes"]["batch"] == \
         {1: 1, 3: 4}[vectors]
     assert by_name["flat.dispatch"][0]["attributes"]["capacity"] >= 100
+    # a bf16 cosine product: the rows are resident as the scan reads them
+    assert by_name["flat.dispatch"][0]["attributes"]["corpus_dtype"] == \
+        "bfloat16"
     fetch = by_name["objects.fetch"][0]["attributes"]
     assert fetch["objects"] == 10 * vectors
     # 100 rows never flushed: the memtable answers every key of the

@@ -1121,6 +1121,12 @@ class Shard:
             return sum(idx.host_tier_bytes()
                        for idx in self._vector_indexes.values())
 
+    def promote_bytes(self) -> int:
+        """HBM a promotion of the demoted indexes would charge."""
+        with self._lock:
+            return sum(idx.promote_bytes()
+                       for idx in self._vector_indexes.values())
+
     def device_resident(self) -> bool:
         """Whether every demotable index is on device (an all-host-tier
         shard — e.g. no vector indexes yet — counts as resident: there is

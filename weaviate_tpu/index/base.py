@@ -112,6 +112,12 @@ class VectorIndex(abc.ABC):
         """Host-RAM rent of demoted device arrays (warm tier)."""
         return 0
 
+    def promote_bytes(self) -> int:
+        """HBM ``promote_device`` would charge (0 while resident). The
+        host mirror's own size unless an index keeps it at another width
+        than its device arrays."""
+        return self.host_tier_bytes()
+
     def demote_device(self) -> int:
         """Move device arrays to host RAM (warm tier); returns HBM bytes
         released. Callers MUST feed the returned delta to the tiering

@@ -71,7 +71,10 @@ class DynamicIndex(VectorIndex):
         self._flat_cfg = FlatIndexConfig(**{**base, **flat_overrides})
         hnsw_overrides = self.config.hnsw or {}
         self._hnsw_cfg = HNSWIndexConfig(**{**base, **hnsw_overrides})
-        self._inner: VectorIndex = FlatIndex(dims, self._flat_cfg)
+        # float32 rows: the store outlives this index (the upgrade hands it
+        # to the HNSW backend, which takes l2 from the rows at full width)
+        self._inner: VectorIndex = FlatIndex(dims, self._flat_cfg,
+                                             float32_rows=True)
         self._upgraded = False
         # background cutover machinery. _swap_lock brackets every inner
         # MUTATION (one store put / delete — fast) so the builder's

@@ -72,8 +72,25 @@ def _bucket_rows(rows: int) -> int:
     return rows
 
 
+def resident_dtype(config: FlatIndexConfig):
+    """Width of a flat collection's resident rows, from what its own
+    configuration says the scan does with them: a ``bf16`` product of a
+    matmul metric reads every row rounded to bfloat16 (``ops/distance.py
+    _matmul``), so the rows are stored that way, rounded once when they
+    are written, and the scan converts nothing. ``fp32`` takes the product
+    from the float32 rows at full precision, and manhattan / hamming read
+    the float32 rows themselves: those stay float32."""
+    if config.precision == "bf16" \
+            and config.distance in ("cosine", "dot", "l2-squared"):
+        return jnp.bfloat16
+    return jnp.float32
+
+
 class FlatIndex(VectorIndex):
-    def __init__(self, dims: int, config: Optional[FlatIndexConfig] = None):
+    def __init__(self, dims: int, config: Optional[FlatIndexConfig] = None,
+                 float32_rows: bool = False):
+        """``float32_rows`` (internal, no schema field): the owner needs the
+        rows resident at full width whatever ``resident_dtype`` says."""
         from weaviate_tpu.parallel.runtime import default_mesh
 
         self.dims = dims
@@ -85,9 +102,13 @@ class FlatIndex(VectorIndex):
         self.store = DeviceVectorStore(
             dims,
             capacity=self.config.initial_capacity,
+            dtype=(jnp.float32 if float32_rows
+                   else resident_dtype(self.config)),
             normalized=(self.metric == "cosine"),
             mesh=default_mesh(),
         )
+        # carried by every ``flat.dispatch`` span
+        self._corpus_dtype = np.dtype(self.store.dtype).name
         # bumped on every demote/promote: the dispatcher keys batch
         # grouping on it, so a request enqueued against one residency
         # generation never rides a batch of another
@@ -250,7 +271,7 @@ class FlatIndex(VectorIndex):
 
                 qj = normalize(qj)
         with TRACER.child("flat.dispatch", capacity=self.store.capacity,
-                         batch=padded):
+                         batch=padded, corpus_dtype=self._corpus_dtype):
             d, ids = self._dispatch(qj, k, masks, rows, approx_recall)
         # the wait for the device and the copy out (both arrays' copies
         # started before either is waited for); padded rows are dropped
@@ -345,6 +366,9 @@ class FlatIndex(VectorIndex):
 
     def host_tier_bytes(self) -> int:
         return self.store.host_bytes
+
+    def promote_bytes(self) -> int:
+        return self.store.attach_bytes
 
     def demote_device(self) -> int:
         freed = self.store.detach()

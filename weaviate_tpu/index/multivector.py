@@ -201,7 +201,10 @@ class MultiVectorIndex(VectorIndex):
             precision=self.config.precision,
             flat_approx_recall=self.config.flat_approx_recall,
         )
-        self.inner = FlatIndex(self.encoder.fde_dim, inner_cfg)
+        # float32 rows: the configuration states a float32 FDE plane, and
+        # the fused scan + rerank reads it as such (ROADMAP S14 halves it)
+        self.inner = FlatIndex(self.encoder.fde_dim, inner_cfg,
+                               float32_rows=True)
         # device rerank tier (modules/device/): the exact MaxSim rescore
         # IS a rerank module here, fused with the FDE candidate scan into
         # ONE dispatch (ops/device_beam.fused_flat_rerank) — candidates

@@ -29,8 +29,11 @@ _PAGE = 4096
 # buffers — donation would invalidate them mid-flight ("Buffer has been
 # deleted or donated"). Copy-on-write keeps readers safe: they retain the
 # old arrays, writers swap in the new ones atomically via Python refs.
+# ``vecs`` come in float32, metric-prepped; the cast to the corpus dtype is
+# the ONE rounding a bfloat16 store's rows ever see (the identity for a
+# float32 store).
 def _scatter_impl(corpus, valid, sqnorms, ids, vecs, norms):
-    corpus = corpus.at[ids].set(vecs)
+    corpus = corpus.at[ids].set(vecs.astype(corpus.dtype))
     valid = valid.at[ids].set(True)
     sqnorms = sqnorms.at[ids].set(norms)
     return corpus, valid, sqnorms
@@ -83,7 +86,14 @@ def _mesh_fns(mesh):
 
 
 class DeviceVectorStore(TieredResidency):
-    """Doc-id-addressed [capacity, D] device array + validity mask + sq-norms."""
+    """Doc-id-addressed [capacity, D] device array + validity mask + sq-norms.
+
+    ``dtype`` is the width of the RESIDENT rows only (float32 or bfloat16).
+    Everything a caller hands in or reads back on the host is float32: a
+    bfloat16 store rounds each row once, in the scatter, after the
+    normalisation and the squared norms were taken from the float32 values
+    (so ``sqnorms`` do not depend on ``dtype``), and widens exactly on the
+    way out (``get``, the warm mirror)."""
 
     def __init__(
         self,
@@ -151,20 +161,26 @@ class DeviceVectorStore(TieredResidency):
             return 0
         corpus, valid, sqnorms = self._state
         freed = sum(a.nbytes for a in self._state)
-        self._host_state = (np.asarray(corpus), np.asarray(valid),
-                            np.asarray(sqnorms))
+        # the mirror is float32 whatever the resident width (an exact
+        # widening): the warm tier scores with numpy, which must not run
+        # on ml_dtypes arrays
+        self._host_state = (np.asarray(corpus).astype(np.float32, copy=False),
+                            np.asarray(valid), np.asarray(sqnorms))
         self._state = None
         self._warm_live_cache = None  # rebuilt lazily for THIS demotion
         return freed
 
     def attach(self) -> int:
-        """Promote back to HBM. Shapes and dtypes are identical to the
-        detached arrays, so every compiled program keyed on them (scatter,
+        """Promote back to HBM. Shapes and dtypes are those the store had
+        before ``detach``, so every compiled program keyed on them (scatter,
         flat scan, fused beam) hits its cache — promotion costs one
         upload, zero recompiles. Returns HBM bytes charged."""
         if self._host_state is None:
             return 0
         corpus, valid, sqnorms = self._host_state
+        # narrowed on the host (exact: the mirror holds the resident values
+        # widened), so the upload is already the resident width
+        corpus = self._narrow(corpus)
         if self.mesh is not None:
             state = tuple(
                 jax.device_put(np.asarray(s), sh)
@@ -174,7 +190,7 @@ class DeviceVectorStore(TieredResidency):
             # only built when actually used: promotion runs exactly when
             # the budget is tight, so a discarded extra upload here would
             # transiently double the tenant's HBM rent
-            state = (jnp.asarray(corpus, self.dtype), jnp.asarray(valid),
+            state = (jnp.asarray(corpus), jnp.asarray(valid),
                      jnp.asarray(sqnorms))
         self._state = state
         self._host_state = None
@@ -226,6 +242,17 @@ class DeviceVectorStore(TieredResidency):
         if hs is None:
             return 0
         return sum(a.nbytes for a in hs)
+
+    @property
+    def attach_bytes(self) -> int:
+        """HBM ``attach`` would charge (0 while device-resident): the
+        mirror's rows at the resident width, the mask and the norms."""
+        hs = self._host_state
+        if hs is None:
+            return 0
+        corpus, valid, sqnorms = hs
+        return (corpus.size * np.dtype(self.dtype).itemsize
+                + valid.nbytes + sqnorms.nbytes)
 
     def snapshot(self) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
         """Consistent (corpus, valid, sqnorms) triple — the ONLY safe way
@@ -292,10 +319,11 @@ class DeviceVectorStore(TieredResidency):
             return
         self._require_device()  # ingest promotes the tenant first
         self.ensure_capacity(int(doc_ids.max()) + 1)
-        vj = jnp.asarray(vectors, self.dtype)
+        # float32 up to the scatter, which rounds to ``dtype``
+        vj = jnp.asarray(vectors)
         if self.normalized:
             vj = normalize(vj)
-        norms = jnp.sum(vj.astype(jnp.float32) ** 2, axis=-1)
+        norms = jnp.sum(vj ** 2, axis=-1)
         prev_valid = self._host_valid[doc_ids]
         self._state = self._scatter_fn(
             *self._state, jnp.asarray(doc_ids), vj, norms)
@@ -317,18 +345,25 @@ class DeviceVectorStore(TieredResidency):
         self._live -= int(was.sum())
 
     def get(self, doc_ids: np.ndarray) -> np.ndarray:
-        """Host gather (debug/rescore path; serves from either tier)."""
+        """Host gather, float32 (debug/rescore path; serves from either
+        tier)."""
         ids = np.asarray(doc_ids, np.int32)
         hs = self._host_state
         if hs is not None:
-            return np.asarray(hs[0][ids], np.float32)
+            return hs[0][ids]
         # graftlint: allow[host-sync-in-hot-path] reason=explicitly host-facing accessor
-        return np.asarray(self._device_state()[0][jnp.asarray(ids)])
+        rows = np.asarray(self._device_state()[0][jnp.asarray(ids)])
+        return rows.astype(np.float32, copy=False)
 
     def contains(self, doc_id: int) -> bool:
         if doc_id >= self.capacity:
             return False
         return bool(self._host_valid[doc_id])
+
+    def _narrow(self, rows: np.ndarray) -> np.ndarray:
+        """Host rows at the resident width (round-to-nearest-even, as the
+        device's convert; the identity where they already are)."""
+        return rows.astype(np.dtype(self.dtype), copy=False)
 
     # -- checkpoint ---------------------------------------------------------
     # Reference analogue: hnsw/startup.go replays a commit log; here the HBM
@@ -341,7 +376,8 @@ class DeviceVectorStore(TieredResidency):
         corpus, valid, sqnorms = (self._host_state if self._host_state
                                   is not None else self._state)
         wm = self._watermark
-        host = np.asarray(corpus[:wm])
+        # the file holds the resident width (the warm mirror is float32)
+        host = self._narrow(np.asarray(corpus[:wm]))
         norms = np.asarray(sqnorms[:wm])
         tmp = path + ".tmp"
         with open(tmp, "wb") as f:
@@ -349,8 +385,7 @@ class DeviceVectorStore(TieredResidency):
                 "version": 1,
                 "meta": meta or {},
                 "dims": self.dims,
-                "dtype": str(np.dtype(self.dtype)) if self.dtype != jnp.bfloat16
-                else "bfloat16",
+                "dtype": np.dtype(self.dtype).name,
                 "watermark": wm,
                 "live": self._live,
                 "normalized": self.normalized,
@@ -381,7 +416,10 @@ class DeviceVectorStore(TieredResidency):
                 host = np.frombuffer(d["corpus"], ml_dtypes.bfloat16)
             else:
                 host = np.frombuffer(d["corpus"], np.dtype(d["dtype"]))
-            host = host.reshape(wm, self.dims)
+            # a float32 file into a bfloat16 store (a checkpoint from
+            # before the rows were resident in bfloat16) is rounded here as
+            # every scan used to round it; its sqnorms are the float32 ones
+            host = self._narrow(host.reshape(wm, self.dims))
             norms = np.frombuffer(d["sqnorms"], np.float32)
             hv = np.unpackbits(
                 np.frombuffer(d["valid"], np.uint8), count=wm).astype(bool)
@@ -402,12 +440,10 @@ class DeviceVectorStore(TieredResidency):
             # default backend (it may be a different/broken platform)
             state = tuple(
                 jax.device_put(s, sh)
-                for s, sh in zip(
-                    (full.astype(self.dtype), fv, fn), self._shardings)
+                for s, sh in zip((full, fv, fn), self._shardings)
             )
         else:
-            state = (jnp.asarray(full, self.dtype), jnp.asarray(fv),
-                     jnp.asarray(fn))
+            state = (jnp.asarray(full), jnp.asarray(fv), jnp.asarray(fn))
         self._state = state
         self._host_state = None  # a restored store is device-resident
         self._host_valid = fv.copy()

@@ -45,7 +45,10 @@ def _matmul(q: jnp.ndarray, c: jnp.ndarray, precision: str) -> jnp.ndarray:
     """[B, D] x [N, D] -> [B, N] inner products on the MXU.
 
     ``precision='bf16'`` casts operands to bfloat16 with float32 accumulation —
-    the MXU-native mode (2x flops vs fp32 inputs).
+    the MXU-native mode (2x flops vs fp32 inputs). Over a corpus that is
+    already bfloat16 (a flat collection's resident rows) the cast is the
+    identity; over float32 rows XLA hoists it out of the chunk loop as one
+    pass over the whole corpus, every call.
     """
     if precision == "bf16":
         q = q.astype(jnp.bfloat16)
@@ -216,7 +219,7 @@ def flat_search(
     """Brute-force top-k: the TPU-native flat index (reference ``flat/index.go:49``).
 
     queries      [B, D] float
-    corpus       [N, D] float (padded to capacity; see valid_mask)
+    corpus       [N, D] float32 or bfloat16 (padded to capacity; see valid_mask)
     valid_mask   [N] bool — False for pad slots / tombstoned ids
     allow_mask   [N] bool — optional filter allowlist (reference AllowList),
                  or [B, N]: row i of the mask filters row i of the queries

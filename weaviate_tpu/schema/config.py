@@ -259,7 +259,12 @@ class VectorIndexConfig:
     # fused device rerank module (docs/modules.md); None = no rerank tier
     rerank: Optional[RerankModuleConfig] = None
     # device placement / batching
-    precision: str = "bf16"  # matmul precision on TPU: bf16 | fp32
+    # matmul precision on TPU: bf16 | fp32. It also decides the resident
+    # width of a plain flat collection's rows: where the scan takes a bf16
+    # product (cosine, dot, l2-squared) they are stored in bfloat16,
+    # rounded once on the write path; fp32 keeps float32 rows
+    # (index/flat.py resident_dtype)
+    precision: str = "bf16"
     initial_capacity: int = 1024
     search_chunk_size: int = 131072
     # Flat-scan selection: -1 = unset (follows the runtime-config fleet

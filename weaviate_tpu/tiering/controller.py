@@ -70,7 +70,7 @@ class ColdStartPending(RuntimeError):
 
 class _Tenant:
     __slots__ = ("key", "state", "score", "last_access", "last_decay",
-                 "hbm_bytes", "host_bytes", "disk_bytes")
+                 "hbm_bytes", "host_bytes", "promote_bytes", "disk_bytes")
 
     def __init__(self, key: TenantKey, state: str, now: float):
         self.key = key
@@ -80,7 +80,15 @@ class _Tenant:
         self.last_decay = now
         self.hbm_bytes = 0  # last-known device footprint (hot: live)
         self.host_bytes = 0  # warm-tier host RAM (detached arrays)
+        # HBM a warm -> hot promotion would charge: the detached arrays at
+        # their RESIDENT width (a flat store's mirror is float32 whatever
+        # its rows are on the device)
+        self.promote_bytes = 0
         self.disk_bytes = 0  # cold-tier on-disk size, measured at release
+
+    def measure_warm(self, shard) -> None:
+        self.host_bytes = shard.host_tier_bytes()
+        self.promote_bytes = shard.promote_bytes()
 
 
 class TieringController:
@@ -306,7 +314,7 @@ class TieringController:
         t0 = self._clock()
         with self._lock:
             ent0 = self._entries.get(key)
-            est = max(ent0.hbm_bytes, ent0.host_bytes) if ent0 else 0
+            est = max(ent0.hbm_bytes, ent0.promote_bytes) if ent0 else 0
         # make room FIRST with the last-known footprint, so the attach
         # never lands the ledger past the budget (a tenant never seen
         # before has no estimate — the post-open rebalance covers it)
@@ -331,7 +339,7 @@ class TieringController:
                 # WARM -> HOT leg: re-upload the detached arrays, but only
                 # when they fit under both the tenant cap and the global
                 # budget — otherwise the tenant keeps serving from host
-                need = shard.host_tier_bytes()
+                need = shard.promote_bytes()
                 if ((per_tenant <= 0 or need <= per_tenant)
                         and not self.accountant.would_exceed(
                             max(0, need - self.accountant.charged(key)))):
@@ -361,7 +369,7 @@ class TieringController:
             if ent is not None:
                 ent.state = HOT if shard.device_resident() else WARM
                 ent.hbm_bytes = hbm
-                ent.host_bytes = shard.host_tier_bytes()
+                ent.measure_warm(shard)
             self._promote_ewma_s = 0.8 * self._promote_ewma_s + 0.2 * dt
         _sp.set(promote_ms=round(dt * 1000, 3), hbm_bytes=hbm,
                 device_resident=shard.device_resident())
@@ -392,7 +400,7 @@ class TieringController:
         gained = 0
         with self._attach_lock:
             if not shard.device_resident():
-                need = shard.host_tier_bytes()
+                need = shard.promote_bytes()
                 self._make_room(
                     max(0, need - self.accountant.charged(key)),
                     exclude=key)
@@ -404,7 +412,7 @@ class TieringController:
             if ent is not None:
                 ent.state = HOT if shard.device_resident() else WARM
                 ent.hbm_bytes = hbm
-                ent.host_bytes = shard.host_tier_bytes()
+                ent.measure_warm(shard)
         if gained:
             TIER_PROMOTIONS.inc(from_tier=WARM)
         self._refresh_tier_gauges()
@@ -476,9 +484,9 @@ class TieringController:
             if col is None:
                 continue
             per_tenant = self._tenant_budget(col)
-            if per_tenant > 0 and ent.host_bytes > per_tenant:
+            if per_tenant > 0 and ent.promote_bytes > per_tenant:
                 continue  # pinned warm by its own cap
-            if self.accountant.would_exceed(ent.host_bytes):
+            if self.accountant.would_exceed(ent.promote_bytes):
                 # budget full: promote only by SWAP — when this tenant is
                 # decisively hotter than the coldest hot incumbent (the
                 # one the promotion's make-room pass will evict). Without
@@ -516,7 +524,7 @@ class TieringController:
                 return
             freed = shard.demote_device()
             ent.hbm_bytes = shard.hbm_bytes()  # 0 unless a tier can't demote
-            ent.host_bytes = shard.host_tier_bytes()
+            ent.measure_warm(shard)
             ent.state = WARM if ent.hbm_bytes == 0 else HOT
             self.accountant.charge(ent.key, ent.hbm_bytes)
         if ent.state == WARM:
@@ -539,6 +547,7 @@ class TieringController:
         ent.state = COLD
         ent.hbm_bytes = 0
         ent.host_bytes = 0
+        ent.promote_bytes = 0
         ent.disk_bytes = _dir_bytes(
             os.path.join(col.dir, f"tenant-{ent.key[1]}"))
         self.accountant.release(ent.key)
