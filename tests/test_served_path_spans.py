@@ -34,7 +34,7 @@ SEARCH_TREE = {
     "flat.result": "dispatch.batch",
     "objects.fetch": "grpc.Search",
     "grpc.encode": "grpc.Search",
-    "grpc.serialize": "grpc.Search",
+    "grpc.send": "grpc.Search",
 }
 BATCH_TREE = {
     "grpc.BatchObjects": None,
@@ -48,7 +48,7 @@ BATCH_TREE = {
     "ingest.drain": "shard.drain_wait",
     "index.add_batch": "ingest.drain",
     "grpc.encode": "grpc.BatchObjects",
-    "grpc.serialize": "grpc.BatchObjects",
+    "grpc.send": "grpc.BatchObjects",
 }
 # what a filter adds: its resolution to an allow mask, on the request's own
 # thread, and the mask's way to the device, once a batch
@@ -106,14 +106,31 @@ def _search(vectors=1, limit=10):
     return req
 
 
+def _clear() -> None:
+    """Empty the buffer once every call made so far is retired: a call's
+    ``grpc.send`` is recorded AFTER the client has its reply, and one that
+    arrived after the buffer was emptied would stand there without its
+    root."""
+    deadline = time.monotonic() + 5.0
+    while time.monotonic() < deadline:
+        spans = TRACER.recent(limit=TRACER.max_spans)
+        roots = {s["spanId"] for s in spans if s["parentSpanId"] is None
+                 and s["name"].startswith("grpc.")}
+        if roots <= {s["parentSpanId"] for s in spans
+                     if s["name"] == "grpc.send"}:
+            break
+        time.sleep(0.005)
+    TRACER.clear()
+
+
 def _one_trace(root: str) -> list[dict]:
     """The spans of the only trace in the buffer, once its late
-    ``grpc.serialize`` (recorded after the client has its reply) is in."""
+    ``grpc.send`` (recorded after the client has its reply) is in."""
     deadline = time.monotonic() + 5.0
     while time.monotonic() < deadline:
         traces = TRACER.traces(limit=10)
         if len(traces) == 1 and any(
-                s["name"] == "grpc.serialize" for s in traces[0]["spans"]):
+                s["name"] == "grpc.send" for s in traces[0]["spans"]):
             break
         time.sleep(0.01)
     assert len(traces) == 1, [t["root"] for t in traces]
@@ -124,23 +141,31 @@ def _one_trace(root: str) -> list[dict]:
 
 def _check_tree(spans: list[dict], tree: dict, root: str) -> dict:
     """Every span has the parent the table gives it and lies inside that
-    parent's interval, except the documented late ``grpc.serialize``,
-    which starts once the root has closed. Returns spans by name (lists)."""
+    parent's interval, except the documented late ``grpc.send``, which
+    starts where the root closed and ends when gRPC retired the call: its
+    ``resident_ms`` (arrival at the pool -> retirement) holds the pool wait
+    and the whole root. Returns spans by name (lists)."""
     by_id = {s["spanId"]: s for s in spans}
     by_name: dict[str, list[dict]] = {}
     for s in spans:
         by_name.setdefault(s["name"], []).append(s)
         assert s["name"] in tree, f"a span the table does not have: {s}"
         assert s["status"] == "OK"
-        if tracing.THREAD_CLOCK:
+        if s["name"] == "grpc.send":    # recorded after the fact
+            assert "cpu_ms" not in s["attributes"]
+        elif tracing.THREAD_CLOCK:
             assert s["attributes"]["cpu_ms"] >= 0
         if tree[s["name"]] is None:
             assert s["parentSpanId"] is None
             continue
         parent = by_id[s["parentSpanId"]]
         assert parent["name"] == tree[s["name"]], (s["name"], parent["name"])
-        if s["name"] == "grpc.serialize":
-            assert s["startTimeUnixNano"] >= parent["endTimeUnixNano"]
+        if s["name"] == "grpc.send":
+            assert s["startTimeUnixNano"] == parent["endTimeUnixNano"]
+            assert s["endTimeUnixNano"] >= s["startTimeUnixNano"]
+            assert s["attributes"]["resident_ms"] >= (
+                parent["attributes"]["pool_wait_ms"] + parent["durationMs"])
+            assert s["attributes"]["serialize_ms"] >= 0
         else:
             assert parent["startTimeUnixNano"] <= s["startTimeUnixNano"]
             assert s["endTimeUnixNano"] <= parent["endTimeUnixNano"]
@@ -158,7 +183,7 @@ def test_search_gives_one_trace_with_the_tables_children(served, vectors):
     assert not client.batch_objects(_batch(100)).errors
     # the first search at a capacity compiles every row bucket under one
     # more span, flat.warm, whose synthetic scans open no span of their own
-    TRACER.clear()
+    _clear()
     client.search(_search(vectors))
     first = _check_tree(_one_trace("grpc.Search"), SEARCH_TREE, "grpc.Search")
     assert {n: len(v) for n, v in first.items()} == dict.fromkeys(
@@ -166,7 +191,7 @@ def test_search_gives_one_trace_with_the_tables_children(served, vectors):
     assert first["flat.warm"][0]["attributes"]["buckets"] == [1, 4, 8]
     assert first["flat.warm"][0]["endTimeUnixNano"] <= \
         first["flat.prepare"][0]["startTimeUnixNano"]
-    TRACER.clear()
+    _clear()
     reply = client.search(_search(vectors))
     assert [len(r.hits) for r in reply.results] == [10] * vectors
     spans = _one_trace("grpc.Search")
@@ -198,7 +223,7 @@ def test_search_gives_one_trace_with_the_tables_children(served, vectors):
     assert (fetch["lock_takes"], fetch["mem_hits"], fetch["records_read"]) \
         == (1, 10 * vectors, 0)
     assert by_name["grpc.encode"][0]["attributes"]["hits"] == 10 * vectors
-    assert by_name["grpc.serialize"][0]["attributes"]["reply_bytes"] > 0
+    assert by_name["grpc.send"][0]["attributes"]["reply_bytes"] > 0
     # the three scan spans follow each other on the leader's thread
     order = [by_name[n][0] for n in
              ("flat.prepare", "flat.dispatch", "flat.result")]
@@ -221,7 +246,7 @@ def test_hits_over_flushed_segments_cost_one_record_and_one_lock_take(
                 shard.objects.flush_memtable()
         assert len(shard.objects._segments) == 3
         client.search(_search(vectors))      # compiles (flat.warm)
-        TRACER.clear()
+        _clear()
         reply = client.search(_search(vectors))
         assert [len(r.hits) for r in reply.results] == [10] * vectors
         spans = _one_trace("grpc.Search")
@@ -250,14 +275,14 @@ def test_a_filtered_search_adds_two_spans_inside_its_budget(served):
     req.where_json = json.dumps({"operator": "ContainsAll", "path": ["tags"],
                                  "valueText": ["t1", "all"]})
     # the first filtered search compiles the masked programs (flat.warm)
-    TRACER.clear()
+    _clear()
     client.search(req)
     first = _one_trace("grpc.Search")
     assert {n: len(v) for n, v in _check_tree(
         first, FILTERED_TREE, "grpc.Search").items()} == dict.fromkeys(
             FILTERED_TREE, 1)
     assert len(first) <= FILTERED_BUDGET
-    TRACER.clear()
+    _clear()
     reply = client.search(req)
     assert all("t1" in json.loads(h.properties_json)["tags"]
                for h in reply.results[0].hits)
@@ -295,7 +320,7 @@ def test_a_hybrid_search_adds_its_legs_and_fusion_under_the_root(
     if fusion != "relativeScoreFusion":     # else: the server's default
         req.fusion = fusion
     client.search(req)      # compiles the k = 20 scans and the fusion
-    TRACER.clear()
+    _clear()
     (result,) = client.search(req).results
     assert len(result.hits) == 10
     spans = _one_trace("grpc.Search")
@@ -336,7 +361,7 @@ def test_a_hybrid_search_adds_its_legs_and_fusion_under_the_root(
 def test_batch_objects_gives_one_trace_with_the_tables_children(served):
     client, group_commit = served
     assert not client.batch_objects(_batch(100)).errors   # schema, dims
-    TRACER.clear()
+    _clear()
     reply = client.batch_objects(_batch(100, start=100))
     assert not reply.errors and len(reply.uuids) == 100
     spans = _one_trace("grpc.BatchObjects")
@@ -375,7 +400,7 @@ def test_a_grow_is_named_on_the_feed_that_paid_for_it(served):
     """``grew`` comes from ``DeviceVectorStore.ensure_capacity``: the first
     rows past the store's capacity (4,096 rows a page) say so."""
     client, _ = served
-    TRACER.clear()
+    _clear()
     for start in range(0, 4200, 700):
         assert not client.batch_objects(_batch(700, start=start)).errors
     feeds = [s for s in TRACER.recent(limit=TRACER.max_spans)
@@ -384,31 +409,57 @@ def test_a_grow_is_named_on_the_feed_that_paid_for_it(served):
     assert sum(1 for s in feeds if s["attributes"]["grew"]) == 1
 
 
-def test_an_aborted_call_leaves_no_root_for_the_next_serializer(tmp_dbdir):
-    """One worker thread: the ``grpc.serialize`` of a reply hangs under its
-    own call's root, never under that of an aborted call before it."""
+def test_an_aborted_call_leaves_no_root_for_the_next_serializer(
+        tmp_dbdir, monkeypatch):
+    """One worker thread: the ``grpc.send`` of a reply hangs under its own
+    call's root and carries its own reply's bytes; the aborted call before
+    it gets a ``grpc.send`` of its own with no reply in it. Both are
+    recorded from gRPC's polling thread, when it retires the call."""
+    import threading
+
     import grpc
 
+    recorders = []
+    record = TRACER.record
+
+    def recording(name, *args, **kwargs):
+        recorders.append((name, threading.current_thread().name))
+        return record(name, *args, **kwargs)
+
+    monkeypatch.setattr(TRACER, "record", recording)
     db, api, client = _serve(tmp_dbdir, max_workers=1)
     try:
         assert not client.batch_objects(_batch(20)).errors
-        TRACER.clear()
+        _clear()
+        recorders.clear()
         bad = _search()
         bad.collection = "Nowhere"
         with pytest.raises(grpc.RpcError):
             client.search(bad)
         client.search(_search(limit=5))
         deadline = time.monotonic() + 5.0
-        while time.monotonic() < deadline and not any(
-                s["name"] == "grpc.serialize"
-                for s in TRACER.recent(limit=100)):
+        while time.monotonic() < deadline and sum(
+                s["name"] == "grpc.send"
+                for s in TRACER.recent(limit=100)) < 2:
             time.sleep(0.01)
         spans = TRACER.recent(limit=100)
         roots = [s for s in spans if s["name"] == "grpc.Search"]
         assert [r["status"] for r in roots] == ["ERROR", "OK"]
-        (ser,) = [s for s in spans if s["name"] == "grpc.serialize"]
-        assert ser["parentSpanId"] == roots[1]["spanId"]
-        assert ser["traceId"] == roots[1]["traceId"]
+        sends = {s["parentSpanId"]: s for s in spans
+                 if s["name"] == "grpc.send"}
+        assert set(sends) == {r["spanId"] for r in roots}
+        for root in roots:
+            send = sends[root["spanId"]]
+            assert send["traceId"] == root["traceId"]
+            assert send["startTimeUnixNano"] == root["endTimeUnixNano"]
+            assert send["attributes"]["resident_ms"] >= (
+                root["attributes"]["pool_wait_ms"] + root["durationMs"])
+        aborted, answered = (sends[r["spanId"]]["attributes"] for r in roots)
+        assert "reply_bytes" not in aborted and "serialize_ms" not in aborted
+        assert answered["reply_bytes"] > 0 and answered["serialize_ms"] >= 0
+        assert [n for n, _ in recorders] == ["grpc.send"] * 2
+        assert all("_serve" in thread and "ThreadPoolExecutor" not in thread
+                   for _, thread in recorders), recorders
     finally:
         client.close()
         api.shutdown()
@@ -423,7 +474,7 @@ def test_v1_compat_plane_shares_the_ingress_attributes(tmp_dbdir):
     db, api, client = _serve(tmp_dbdir)
     try:
         assert not client.batch_objects(_batch(20)).errors
-        TRACER.clear()
+        _clear()
         call = client.channel.unary_unary(
             f"/{SERVICE_V1}/Search",
             request_serializer=lambda m: m.SerializeToString(),
@@ -437,8 +488,9 @@ def test_v1_compat_plane_shares_the_ingress_attributes(tmp_dbdir):
         assert root["attributes"]["plane"] == "v1_compat"
         assert root["attributes"]["pool_wait_ms"] >= 0
         assert root["attributes"]["request_bytes"] > 0
-        (ser,) = [s for s in spans if s["name"] == "grpc.serialize"]
-        assert ser["parentSpanId"] == root["spanId"]
+        (send,) = [s for s in spans if s["name"] == "grpc.send"]
+        assert send["parentSpanId"] == root["spanId"]
+        assert send["attributes"]["reply_bytes"] > 0
     finally:
         client.close()
         api.shutdown()
@@ -482,7 +534,7 @@ def test_unsampled_requests_open_no_annotation_and_read_no_thread_clock(
         assert "grpc.Search" in counting.opened
         TRACING_SAMPLE_RATE.set_override(0.0)
         try:
-            TRACER.clear()
+            _clear()
             assert not client.batch_objects(_batch(50, start=50)).errors
             assert len(client.search(_search()).results[0].hits) == 10
             api.shutdown()      # the workers are done, serializers too
@@ -528,7 +580,9 @@ def test_span_names_land_on_a_host_line_of_a_profiler_trace(tmp_path,
         db.close()
     path = xplane.find_trace(str(tmp_path))
     assert path is not None
-    want = set(SEARCH_TREE) - {"flat.warm"}     # compiled outside
+    # flat.warm compiled outside; grpc.send is recorded after the fact and
+    # so is no annotation: it stands on the host's clock alone
+    want = set(SEARCH_TREE) - {"flat.warm", "grpc.send"}
     lines = [
         {ev.name: (int(ev.start_ns), int(ev.start_ns) + int(ev.duration_ns))
          for ev in line.events}
@@ -536,15 +590,13 @@ def test_span_names_land_on_a_host_line_of_a_profiler_trace(tmp_path,
         if plane.name == "/host:CPU" for line in plane.lines]
     held = [events for events in lines if want <= set(events)]
     assert len(held) == 1, [sorted(set(e) & want) for e in lines]
+    assert not any("grpc.send" in events for events in lines)
     events = held[0]
     for name, parent in SEARCH_TREE.items():
         if parent is None or name not in want:
             continue
-        if name == "grpc.serialize":
-            assert events[name][0] >= events[parent][1]
-        else:
-            assert events[parent][0] <= events[name][0]
-            assert events[name][1] <= events[parent][1]
+        assert events[parent][0] <= events[name][0]
+        assert events[name][1] <= events[parent][1]
     # a device call made inside flat.prepare / flat.dispatch lies inside it
     # on that line: "the innermost host event that covers the gap" is then
     # the jax call where there is one and the program's span where not
@@ -556,7 +608,8 @@ def test_span_names_land_on_a_host_line_of_a_profiler_trace(tmp_path,
 
 
 def test_the_buffer_keeps_a_cells_traced_segment():
-    """~1,200 requests x 9 spans in the search cell's traced 4 s."""
+    """~2,400 requests x 9 spans and ~430 ticks in the busiest search
+    cell's traced ~4.3 s."""
     assert TRACER.max_spans >= 16384
     tr = Tracer()
     n = tr.max_spans + 100

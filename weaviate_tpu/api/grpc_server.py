@@ -27,7 +27,7 @@ from weaviate_tpu.api.graphql import where_to_filter
 from weaviate_tpu.api.proto import pb
 from weaviate_tpu.cluster.resilience import Deadline, DeadlineExceeded
 from weaviate_tpu.core.db import DB
-from weaviate_tpu.monitoring.tracing import TRACER
+from weaviate_tpu.monitoring.tracing import TRACER, annotate
 from weaviate_tpu.query import Explorer, HybridParams, QueryParams
 from weaviate_tpu.serving.context import RequestContext, request_scope
 from weaviate_tpu.serving.qos import QosRejected
@@ -93,7 +93,8 @@ def qos_admit(qos, name: str, context, tenant: str = ""):
 
 
 # what the worker thread of the call in hand knows of its own ingress: when
-# gRPC handed the call to the pool, and (after the handler) the root span
+# gRPC handed the call to the pool (``_run_stamped``), and the box that the
+# reply's serializer fills for the call's ``grpc.send`` span
 _ingress = threading.local()
 
 
@@ -101,7 +102,7 @@ class ArrivalStampingPool(futures.ThreadPoolExecutor):
     """The pool gRPC hands every call to. ``submit`` runs on gRPC's polling
     thread as the call arrives; the stamp travels to the worker thread that
     takes the call, where ``traced_unary_handler`` turns it into
-    ``pool_wait_ms``."""
+    ``pool_wait_ms`` and, when the call is retired, ``resident_ms``."""
 
     def submit(self, fn, /, *args, **kwargs):
         return super().submit(_run_stamped, time.perf_counter_ns(), fn,
@@ -110,7 +111,6 @@ class ArrivalStampingPool(futures.ThreadPoolExecutor):
 
 def _run_stamped(arrived_ns: int, fn, *args, **kwargs):
     _ingress.arrived_ns = arrived_ns
-    _ingress.root = None
     return fn(*args, **kwargs)
 
 
@@ -123,7 +123,13 @@ def traced_unary_handler(name: str, run, req_cls, **root_attrs):
     pool -> handler start: the hand-off to a worker, the wait for the
     request message and its decode), ``decode_ms`` and ``request_bytes``
     (``FromString``, which gRPC runs on its one polling thread) — and what
-    happens after it as a late child, ``grpc.serialize``."""
+    happens after it as ONE late child, ``grpc.send``: from the root's end
+    until gRPC retires the call (the reply serialized on the worker,
+    handed to the transport, the polling thread's turn), recorded from
+    that polling thread by ``context.add_callback``. Its ``resident_ms`` =
+    arrival at the pool -> retirement is the call's whole residency on the
+    server's clock; ``serialize_ms`` and ``reply_bytes`` are absent where
+    the call ended without a reply (abort, cancellation)."""
     span_name = f"grpc.{name}"
 
     def decode(data: bytes):
@@ -136,24 +142,35 @@ def traced_unary_handler(name: str, run, req_cls, **root_attrs):
         request, decode_ms, request_bytes = decoded
         arrived_ns = getattr(_ingress, "arrived_ns", started_ns)
         md = dict(context.invocation_metadata() or [])
-        with TRACER.ingress(
-                span_name, traceparent=md.get("traceparent", ""), rpc=name,
-                pool_wait_ms=round((started_ns - arrived_ns) / 1e6, 3),
-                decode_ms=round(decode_ms, 3), request_bytes=request_bytes,
-                **root_attrs) as root:
-            # a reply that is never serialized (abort) leaves this to the
-            # next call's _run_stamped
-            _ingress.root = root
+        root = TRACER.ingress(
+            span_name, traceparent=md.get("traceparent", ""), rpc=name,
+            pool_wait_ms=round((started_ns - arrived_ns) / 1e6, 3),
+            decode_ms=round(decode_ms, 3), request_bytes=request_bytes,
+            **root_attrs)
+        _ingress.sent = sent = {} if root.sampled else None
+        if sent is not None:
+            def retired():
+                # on gRPC's polling thread, after the client has its reply
+                now_ns, now_unix = time.perf_counter_ns(), time.time_ns()
+                TRACER.record(
+                    "grpc.send", root.end_ns or now_unix, now_unix,
+                    parent=root,
+                    resident_ms=round((now_ns - arrived_ns) / 1e6, 3),
+                    **sent)
+
+            context.add_callback(retired)
+        with root:
             return run(request, context)
 
     def serialize(reply) -> bytes:
-        root, _ingress.root = getattr(_ingress, "root", None), None
-        if root is None:
+        # after the root closed, on the same worker thread
+        sent = getattr(_ingress, "sent", None)
+        if sent is None:
             return reply.SerializeToString()
-        # after the root closed, on the same worker thread, as its child
-        with TRACER.span("grpc.serialize", parent=root) as span:
-            data = reply.SerializeToString()
-            span.set(reply_bytes=len(data))
+        t0 = time.perf_counter_ns()
+        data = reply.SerializeToString()
+        sent["serialize_ms"] = round((time.perf_counter_ns() - t0) / 1e6, 3)
+        sent["reply_bytes"] = len(data)
         return data
 
     return grpc.unary_unary_rpc_method_handler(
@@ -327,11 +344,9 @@ class GrpcAPI:
                 raise ValueError(
                     "a token set (token_bytes) is one late-interaction "
                     "query: send one near_vector a request, without hybrid")
-            root = getattr(_ingress, "root", None)
-            if root is not None:
-                v = req.near_vectors[0]
-                root.set(query_tokens=len(v.token_bytes)
-                         // (4 * v.token_dims))
+            v = req.near_vectors[0]
+            # the ingress root is the span under way here
+            annotate(query_tokens=len(v.token_bytes) // (4 * v.token_dims))
 
         if (len(req.near_vectors) > 1 and not req.use_hybrid
                 and not req.bm25_query):

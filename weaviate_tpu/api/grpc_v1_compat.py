@@ -829,7 +829,7 @@ class WeaviateV1Service:
                 except RuntimeError as e:
                     context.abort(grpc.StatusCode.FAILED_PRECONDITION,
                                   str(e))
-            # ingress span, attributes and the grpc.serialize child shared
+            # ingress span, attributes and the grpc.send child shared
             # with the native plane (the two planes must not drift)
             return traced_unary_handler(name, run, req_cls,
                                         plane="v1_compat")

@@ -29,7 +29,11 @@ class _Metric:
         self.name = name
         self.help = help_
         self.kind = kind
-        self._lock = threading.Lock()
+        # re-entrant: a collection can start between two bytecodes of a
+        # thread that holds this lock, and the collector's callback
+        # (monitoring/interp.py) finishes a span on that same thread,
+        # which counts it in TRACE_SPANS
+        self._lock = threading.RLock()
 
 
 class Counter(_Metric):
@@ -506,6 +510,24 @@ DEVICE_TIME_SECONDS = REGISTRY.histogram(
 TRACE_SPANS = REGISTRY.counter(
     "weaviate_tpu_trace_spans_total",
     "sampled spans recorded into the bounded trace buffer, by span name")
+
+# the interpreter's own readings (monitoring/interp.py, started by
+# server.main): how long a thread that wants the interpreter lock waits for
+# it, and what the collector costs (the upstream's go_gc_duration_seconds)
+INTERPRETER_WAKE = REGISTRY.histogram(
+    "weaviate_tpu_interpreter_wake_seconds",
+    "overshoot of a 10 ms sleep on the sampler's thread: the timer's "
+    "slack plus the wait for the interpreter lock that every thread "
+    "pays when it comes back from a blocking call",
+    buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
+             0.05, 0.1, 0.25, 0.5, 1.0))
+GC_PAUSE_SECONDS = REGISTRY.counter(
+    "weaviate_tpu_gc_pause_seconds_total",
+    "time inside Python's cyclic collector, interpreter lock held, by "
+    "generation")
+GC_COLLECTIONS = REGISTRY.counter(
+    "weaviate_tpu_gc_collections_total",
+    "collections Python's cyclic collector ran, by generation")
 
 # elastic scale-out instruments (cluster/rebalance.py + gossip capacity
 # advertisement): every shard migration's outcome and duration, the

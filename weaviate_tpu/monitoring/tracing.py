@@ -31,7 +31,11 @@ xplane.py`` names a device's idle gaps from those lines). With no session
 the annotation is a level check and a return. Where the thread's CPU clock
 is cheap (``THREAD_CLOCK``), the span also records the thread's CPU time
 between enter and exit as ``cpu_ms``: wall minus CPU is what the layer
-spent waiting (interpreter lock, device, disk).
+spent waiting (interpreter lock, device, disk). A span whose ends lie on
+different threads, or before the recording thread ran, is written after
+the fact with ``Tracer.record`` (``grpc.send``, ``interp.tick``): it has
+the host's clock only — an annotation cannot be backdated — and no
+``cpu_ms``.
 """
 
 from __future__ import annotations
@@ -54,8 +58,9 @@ _current_span: contextvars.ContextVar[Optional["Span"]] = \
 
 _UNSET = object()
 
-# the traced segment of a benchmark cell has to fit whole: ~1,200 search
-# requests x 9 spans in 4 s today (PERF.md section 3)
+# the traced segment of a benchmark cell has to fit whole: ~2,400 search
+# requests x 9 spans and ~430 ``interp.tick`` in the ~4.3 s of the busiest
+# cell today, ~22,000 in all (PERF.md section 3)
 MAX_SPANS = 32768
 
 
@@ -153,7 +158,8 @@ class Span:
         self.status = "OK"
         self._token = None
         self._annotation = None
-        self._cpu_ns = 0
+        # None: recorded after the fact, no CPU reading (Tracer.record)
+        self._cpu_ns: Optional[int] = 0
 
     def set(self, **attrs) -> "Span":
         if self.sampled:
@@ -229,7 +235,8 @@ class Span:
         return (end - self.start_ns) / 1e6
 
     def to_dict(self) -> dict:
-        if THREAD_CLOCK and self.end_ns is not None:
+        if (THREAD_CLOCK and self.end_ns is not None
+                and self._cpu_ns is not None):
             self.attributes["cpu_ms"] = round(self._cpu_ns / 1e6, 3)
         out = {
             "traceId": self.trace_id,
@@ -370,6 +377,30 @@ class Tracer:
         if self.enabled:
             TRACE_SPANS.inc(name=span.name)
             self._spans.append(span)
+
+    def record(self, name: str, start_ns: int, end_ns: int,
+               parent: Optional[Span] = None, **attrs) -> None:
+        """A span with given ends (unix ns), recorded after the fact: for
+        an interval whose ends lie on different threads or before the
+        recording thread ran. A child of ``parent`` (open or closed) under
+        the parent's sampling verdict, or with none the root of a one-span
+        trace under a verdict of its own. Counted and retained as
+        ``_finish`` does; no ``TraceAnnotation`` and no ``cpu_ms`` (neither
+        can be backdated), and the context stack is left alone."""
+        if not self.enabled:
+            return
+        if parent is None:
+            if not self._sample():
+                return
+            span = Span(self, name, _trace_id(), None)
+        elif parent.sampled:
+            span = Span(self, name, parent.trace_id, parent.span_id)
+        else:
+            return
+        span.start_ns, span.end_ns, span._cpu_ns = start_ns, end_ns, None
+        span.attributes = attrs
+        TRACE_SPANS.inc(name=name)
+        self._spans.append(span)
 
     # -- export ------------------------------------------------------------
     def recent(self, limit: int = 100,

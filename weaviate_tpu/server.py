@@ -149,6 +149,12 @@ def main() -> int:
         port = grpc_api.serve(host="0.0.0.0", port=int(cfg["grpc_port"]))
         print(f"gRPC listening on :{port}", file=sys.stderr)
 
+    # the interpreter's own readings (lock wait, collector pauses): only
+    # a serving process gets the thread, and only once it can serve
+    from weaviate_tpu.monitoring.interp import SAMPLER
+
+    SAMPLER.start()
+
     stop = threading.Event()
 
     def _sig(*_):
@@ -159,6 +165,7 @@ def main() -> int:
     stop.wait()
 
     print("shutting down", file=sys.stderr)
+    SAMPLER.stop()
     rest.shutdown()
     if grpc_api is not None:
         grpc_api.shutdown()
