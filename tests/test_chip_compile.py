@@ -78,7 +78,8 @@ def test_flat_search_compiles_at_serving_defaults(one_chip, metric,
     """The program a default FlatIndexConfig collection serves with: bf16
     matmul, exact selection, 131072-row chunks over a 1M x 768 bfloat16 corpus;
     and the one a collection with ``flat_approx_recall`` set serves with
-    (``lax.approx_min_k`` a chunk), the only approximate flat program."""
+    (``lax.approx_min_k`` a chunk), the only approximate flat program.
+    Cosine as ``FlatIndex`` asks for it: the scan normalises its queries."""
     from weaviate_tpu.ops.distance import flat_search
 
     q, corpus, valid, sqnorms = _flat_args(one_chip)
@@ -86,7 +87,8 @@ def test_flat_search_compiles_at_serving_defaults(one_chip, metric,
         q, corpus, k=K, metric=metric, valid_mask=valid,
         corpus_sqnorms=sqnorms if metric == "l2-squared" else None,
         chunk_size=CHUNK, precision="bf16",
-        approx_recall=approx_recall).compile()
+        approx_recall=approx_recall,
+        normalize_queries=metric == "cosine").compile()
     # the corpus is an argument, not a temporary: >= 1.6 GB resident
     assert compiled.memory_analysis().argument_size_in_bytes >= N * D * 2
     _fits(compiled)
@@ -150,7 +152,8 @@ def test_flat_search_over_bfloat16_rows_has_no_whole_corpus_pass(
             allow_mask=s((rows, cap), jnp.bool_) if masked else None,
             corpus_sqnorms=(s((cap,), jnp.float32)
                             if metric == "l2-squared" else None),
-            chunk_size=CHUNK, precision="bf16", approx_recall=0.0).compile()
+            chunk_size=CHUNK, precision="bf16", approx_recall=0.0,
+            normalize_queries=metric == "cosine").compile()
 
     narrow = compile_over(jnp.bfloat16)
     assert _whole_corpus_ops(narrow, cap, dims) == []

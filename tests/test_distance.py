@@ -163,6 +163,46 @@ def test_flat_search_a_mask_a_row_equals_a_call_a_mask(rng, metric, chunk,
     assert len(i2[1][i2[1] >= 0]) == (2 if with_valid else 3)
 
 
+@pytest.mark.parametrize("precision", ["fp32", "bf16"])
+@pytest.mark.parametrize("b", [1, 4, 8])
+def test_flat_search_normalizes_its_own_queries_when_told_to(rng, b,
+                                                             precision):
+    """``normalize_queries`` on RAW queries, handed over as a numpy array
+    as ``FlatIndex`` does, answers as the eager ``normalize`` before the
+    call did: the same float32 arithmetic, launched by the scan. Under a
+    mask a row, with a zero query row (divided by eps: it stays zero)."""
+    n, k = 96, 5
+    q = (3.0 * rng.standard_normal((b, 16))).astype(np.float32)
+    q[b - 1] = 0.0
+    c = np.asarray(normalize(rng.standard_normal((n, 16)).astype(np.float32)))
+    if precision == "bf16":
+        # resident as a flat index keeps its rows
+        c = jnp.asarray(c).astype(jnp.bfloat16)
+    masks = rng.random((b, n)) < 0.5
+    valid = np.ones(n, bool)
+    valid[90:] = False
+    for allow in (jnp.asarray(masks), None):
+        common = dict(k=k, metric="cosine", precision=precision,
+                      valid_mask=jnp.asarray(valid), allow_mask=allow,
+                      chunk_size=32)
+        d0, i0 = flat_search(normalize(jnp.asarray(q)), jnp.asarray(c),
+                             **common)
+        d1, i1 = flat_search(q, jnp.asarray(c), normalize_queries=True,
+                             **common)
+        np.testing.assert_array_equal(np.asarray(i1), np.asarray(i0))
+        np.testing.assert_allclose(np.asarray(d1), np.asarray(d0),
+                                   rtol=0, atol=1e-6)
+        # the zero row scores 1 - 0 against every allowed row
+        live = np.asarray(d1)[b - 1] < 1e30
+        assert live.any()
+        np.testing.assert_array_equal(np.asarray(d1)[b - 1][live], 1.0)
+    if b > 1:
+        # the default leaves raw queries raw
+        d_raw, _ = flat_search(q, jnp.asarray(c), **common)
+        assert not np.allclose(np.asarray(d_raw)[0], np.asarray(d1)[0],
+                               atol=1e-3)
+
+
 def test_gather_distance(rng):
     q = rng.standard_normal((2, 8)).astype(np.float32)
     c = rng.standard_normal((30, 8)).astype(np.float32)

@@ -562,3 +562,85 @@ def test_every_bucket_is_compiled_at_the_first_search(metric, filtered,
     for rows in sizes:
         idx.search(queries[:rows], 7, masks[rows])
     assert compiles["n"] == regrown
+
+
+@pytest.mark.parametrize("metric", ["cosine", "l2-squared"])
+def test_a_batch_makes_no_eager_call_on_its_way_to_the_device(
+        metric, monkeypatch):
+    """The one-chip path hands the scan a host array and nothing else:
+    ``index.flat`` without a ``jax.numpy`` to upload with (no
+    ``jnp.asarray``) and no eager ``normalize`` (cosine: the jitted scan
+    normalises; the programs are compiled by the first search, so nothing
+    traces it again), yet the answers are those of normalised queries."""
+    import types
+
+    from weaviate_tpu.ops import distance
+
+    idx, queries = _index(metric)
+    raw = (5.0 * queries).astype(np.float32)    # far from unit length
+    want = idx.search(raw[:8], 5)               # compiles every bucket
+    if metric == "cosine":
+        # scale leaves a cosine answer alone: the scan normalised
+        _assert_same_answers(want, idx.search(queries[:8], 5))
+
+    def no_normalize(*_a, **_kw):
+        raise AssertionError("eager normalize on the search path")
+
+    monkeypatch.setattr(distance, "normalize", no_normalize)
+    monkeypatch.setattr(flat, "jnp", types.SimpleNamespace())
+    handed = []
+    real_scan = flat.flat_search
+
+    def scan(q, *a, **kw):
+        handed.append((type(q), q.dtype, kw["normalize_queries"]))
+        return real_scan(q, *a, **kw)
+
+    monkeypatch.setattr(flat, "flat_search", scan)
+    for rows in (1, 3, 8):
+        got = idx.search(raw[:rows], 5)
+        np.testing.assert_array_equal(got.ids, want.ids[:rows])
+    assert handed == [(np.ndarray, np.float32, metric == "cosine")] * 3
+
+
+@pytest.mark.parametrize("filtered", [False, True],
+                         ids=["plain", "filtered"])
+@pytest.mark.parametrize("metric", ["cosine", "l2-squared"])
+def test_the_warm_up_asks_for_the_forms_a_batch_asks_for(metric, filtered,
+                                                         compiles,
+                                                         monkeypatch):
+    """``_warm_buckets`` goes through ``_scan``: every later group of 1, 3
+    or 8 rows, one mask or a mask a member, calls ``flat_search`` in a
+    form (query argument's type and shape, the mask's rank, the static
+    flag) the first search already called it in, and compiles nothing."""
+    idx, queries = _index(metric)   # k = 11: no other test's programs
+    rng = np.random.default_rng(11)
+    masks = [rng.random(ROWS - 5 * i) < 0.5 if filtered else None
+             for i in range(9)]
+    forms = []
+    real_scan = flat.flat_search
+
+    def scan(q, corpus, **kw):
+        allow = kw["allow_mask"]
+        forms.append((type(q), q.shape, corpus.shape[0],
+                      None if allow is None else allow.ndim,
+                      kw["k"], kw["normalize_queries"]))
+        return real_scan(q, corpus, **kw)
+
+    monkeypatch.setattr(flat, "flat_search", scan)
+    idx.search(queries[:1], 11, masks[0])
+    warmed, after_first = set(forms), compiles["n"]
+    assert after_first > 0
+    assert {f[0] for f in warmed} == {np.ndarray}
+    assert {f[5] for f in warmed} == {metric == "cosine"}
+    forms.clear()
+    for rows in (1, 3, 8):
+        assert idx.search(queries[:rows], 11,
+                          masks[rows]).ids.shape == (rows, 11)
+    for waiting in (2, 7):      # groups of 3 and 8, a mask a member
+        calls = _behind_a_held_batch(
+            idx, lambda: idx.search(queries[0][None], 11, masks[0]),
+            [lambda i=i: idx.search(queries[i][None], 11, masks[i])
+             for i in range(1, waiting + 1)])
+        assert [c["rows"] for c in calls] == [1, waiting]
+    assert forms and set(forms) <= warmed, set(forms) - warmed
+    assert compiles["n"] == after_first

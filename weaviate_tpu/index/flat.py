@@ -52,8 +52,9 @@ def make_flat(dims: int, config: Optional[FlatIndexConfig] = None,
 # Row counts a coalesced group is padded to (``flat_search`` is jitted on
 # the query shape); the largest is the dispatcher's ``max_batch``. The scan
 # is bound by reading the corpus, so padded rows cost selection only
-# (~0.01 ms a row at 262,144 x 768) and every size is one more program to
-# compile per (capacity, k). Why no more than 8 (chip runs, PERF.md PR 27):
+# (~0.01 ms a row at 262,144 x 768; their zeros go up, and cosine are
+# normalised, inside the same one launch) and every size is one more
+# program to compile per (capacity, k). Why no more than 8 (chip runs, PERF.md PR 27):
 # 8 rows a scan is already 3,200 vectors/s of scan capacity against the
 # ~430 a second the host's interpreter lock lets through, while a batch
 # releases all its replies at once: with 64, twenty closed-loop clients
@@ -191,8 +192,9 @@ class FlatIndex(VectorIndex):
     def _run_batch(self, queries: np.ndarray, k: int, masks,
                    tier_key: tuple, rows: Optional[list[int]] = None):
         """Single-flight batch runner behind the coalescing dispatcher:
-        one upload, one normalise, one scan and one copy-out for the
-        whole group. ``masks`` is None or the members' allow masks in
+        one launch (the scan takes the group's queries up with it and,
+        cosine, normalises them itself) and one copy-out for the whole
+        group. ``masks`` is None or the members' allow masks in
         request order, ``rows`` their row counts. Returns (ids, dists) of
         the group's rows."""
         if rows is None:
@@ -234,8 +236,9 @@ class FlatIndex(VectorIndex):
         whichever later batch happens to be the first of its kind: every
         row bucket and, filtered, the stacked-mask form of the buckets a
         group of unequal masks can fill (it has at least two rows). Zero
-        queries through the whole path: the eager normalise is shaped by
-        the rows too."""
+        queries through ``_scan`` itself, so what is warmed is what a
+        batch asks for: a host array as the query argument and, cosine,
+        the program that normalises it."""
         capacity, k, filtered, approx_recall = program
         forms = [(b, [b]) for b in ROW_BUCKETS]
         if filtered and self._dispatcher.per_row_masks:
@@ -262,17 +265,14 @@ class FlatIndex(VectorIndex):
               approx_recall: float):
         n = queries.shape[0]
         padded = _bucket_rows(n)
-        if padded != n:
-            queries = np.pad(queries, ((0, padded - n), (0, 0)))
+        # all the host does to the queries: the pad to a row bucket. They
+        # go up as an argument of the scan's own launch
         with TRACER.child("flat.prepare"):
-            qj = jnp.asarray(queries)
-            if self.metric == "cosine":
-                from weaviate_tpu.ops.distance import normalize
-
-                qj = normalize(qj)
+            if padded != n:
+                queries = np.pad(queries, ((0, padded - n), (0, 0)))
         with TRACER.child("flat.dispatch", capacity=self.store.capacity,
                          batch=padded, corpus_dtype=self._corpus_dtype):
-            d, ids = self._dispatch(qj, k, masks, rows, approx_recall)
+            d, ids = self._dispatch(queries, k, masks, rows, approx_recall)
         # the wait for the device and the copy out (both arrays' copies
         # started before either is waited for); padded rows are dropped
         # before hand-back
@@ -280,14 +280,20 @@ class FlatIndex(VectorIndex):
             ids, d = jax.device_get((ids, d))
             return ids[:n], d[:n]
 
-    def _dispatch(self, qj, k: int, masks, rows: list[int],
-                  approx_recall: float):
-        """Start the scan of one device-resident query batch; returns the
-        (distances, ids) device arrays without waiting for them."""
+    def _dispatch(self, queries: np.ndarray, k: int, masks,
+                  rows: list[int], approx_recall: float):
+        """Start the scan of one padded float32 host query batch; returns
+        the (distances, ids) device arrays without waiting for them. On
+        one chip the batch makes ONE call into the runtime: the jitted
+        scan uploads its own query argument and, cosine, normalises it."""
         shared = None if masks is None else one_mask(masks)
         if self.store.mesh is not None:
+            from weaviate_tpu.ops.distance import normalize
             from weaviate_tpu.parallel.sharded_search import mesh_flat_topk
 
+            qj = jnp.asarray(queries)
+            if self.metric == "cosine":
+                qj = normalize(qj)
             # one mask a batch: this index's dispatcher groups by mask
             # equality (see __init__), so ``shared`` is the group's mask
             return mesh_flat_topk(
@@ -308,12 +314,12 @@ class FlatIndex(VectorIndex):
         elif masks is not None:
             # members with different masks: one mask a query ROW, uploaded
             # once a batch; ``flat_search`` applies row i to row i
-            with TRACER.child("flat.mask", bytes=qj.shape[0] * cap):
+            with TRACER.child("flat.mask", bytes=queries.shape[0] * cap):
                 allow = jnp.asarray(
-                    _stack_masks(masks, rows, qj.shape[0], cap))
+                    _stack_masks(masks, rows, queries.shape[0], cap))
         chunk = self.config.search_chunk_size
         return flat_search(
-            qj,
+            queries,
             corpus,
             k=k,
             metric=self.metric,
@@ -323,6 +329,7 @@ class FlatIndex(VectorIndex):
             chunk_size=chunk if cap > chunk else 0,
             precision=self.config.precision,
             approx_recall=approx_recall,
+            normalize_queries=self.metric == "cosine",
         )
 
     def search_by_distance(

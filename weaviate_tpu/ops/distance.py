@@ -202,7 +202,8 @@ def select_topk(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("metric", "k", "chunk_size", "precision", "approx_recall"),
+    static_argnames=("metric", "k", "chunk_size", "precision", "approx_recall",
+                     "normalize_queries"),
 )
 def flat_search(
     queries: jnp.ndarray,
@@ -215,10 +216,11 @@ def flat_search(
     chunk_size: int = 0,
     precision: str = "fp32",
     approx_recall: float = 0.0,
+    normalize_queries: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Brute-force top-k: the TPU-native flat index (reference ``flat/index.go:49``).
 
-    queries      [B, D] float
+    queries      [B, D] float; a numpy array goes up with this launch
     corpus       [N, D] float32 or bfloat16 (padded to capacity; see valid_mask)
     valid_mask   [N] bool — False for pad slots / tombstoned ids
     allow_mask   [N] bool — optional filter allowlist (reference AllowList),
@@ -231,10 +233,15 @@ def flat_search(
                  ``lax.approx_min_k`` with this recall target (see
                  ``select_topk``); candidates are collected via ``scan``
                  and merged ONCE — two-stage selection, no per-chunk sort.
+    normalize_queries  the queries arrive raw and this program L2-normalises
+                 them itself (``normalize``, float32, before ``_matmul``'s
+                 cast): a cosine caller's whole batch is then ONE launch.
 
     Returns (distances [B, k], ids [B, k]); masked/empty slots have distance
     MASK_DISTANCE and id -1.
     """
+    if normalize_queries:
+        queries = normalize(queries)
     n = corpus.shape[0]
     b = queries.shape[0]
     mask = None
