@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from weaviate_tpu.index.store import DeviceVectorStore
+from weaviate_tpu.monitoring.tracing import TRACER
 from weaviate_tpu.ops.distance import (
     MASK_DISTANCE,
     candidate_pairwise,
@@ -597,7 +598,10 @@ class QuantizedBackend:
 
         if not self.codes.device_resident:
             return self.host_topk(queries, k, allow)
-        qrep = self.prep_queries(queries)
+        kind = self.quantizer.kind
+        # normalise, rotate / pack, and the query's upload
+        with TRACER.child("qflat.prepare", quantizer=kind):
+            qrep = self.prep_queries(queries)
         if qrep.code is None:
             # pre-fit: exact over the (tiny) host corpus
             live = np.flatnonzero(self.originals.valid)
@@ -625,14 +629,20 @@ class QuantizedBackend:
             rescore_limit = getattr(self.quantizer.config, "rescore_limit", 0)
             fetch = max(4 * k, rescore_limit, k)
             chunk = self.config.search_chunk_size
-            _, ids = self.quantizer.search(
-                qrep.code, self.codes, fetch, mask,
-                chunk if self.codes.capacity > chunk else 0,
-            )
-            res = exact_rescore(
+            # the code scan's launch up to its candidate ids on the host
+            with TRACER.child("qflat.scan", quantizer=kind, fetch=fetch,
+                              capacity=self.codes.capacity):
+                _, ids = self.quantizer.search(
+                    qrep.code, self.codes, fetch, mask,
+                    chunk if self.codes.capacity > chunk else 0,
+                )
                 # graftlint: allow[host-sync-in-hot-path] reason=candidate ids cross to the host rescore tier by design
-                qrep.host, np.asarray(ids), self.originals, self.metric, k
-            )
+                ids = np.asarray(ids)
+            # the originals' gather and their exact distances
+            with TRACER.child("qflat.rescore", quantizer=kind,
+                              candidates=ids.shape[1]):
+                res = exact_rescore(
+                    qrep.host, ids, self.originals, self.metric, k)
         d = res.dists.astype(np.float32).copy()
         ids = res.ids.astype(np.int64)
         d[ids < 0] = _INF
