@@ -165,6 +165,28 @@ def test_flat_search_over_bfloat16_rows_has_no_whole_corpus_pass(
     assert any(name.startswith("convert") for name in wide), wide
 
 
+@pytest.mark.parametrize("rows", [1, 4, 8])
+def test_rq_scan_compiles_at_every_row_bucket(one_chip, rows):
+    """The code scan a compressed flat collection runs for a batch of each
+    row bucket (``index/flat.py ROW_BUCKETS``) at ``cohere768.rq_c20``'s
+    shape: 262,144 x 768 uint8 RQ codes, cosine, the top 40 in 131,072-row
+    chunks. The codes are read where they lie: the program keeps no
+    temporary of a chunk's size, whatever the batch."""
+    from weaviate_tpu.ops.quantized import rq_search
+
+    cap, dims = 262144, 768
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = rq_search.lower(
+        s((rows, dims), jnp.float32), s((cap, dims), jnp.uint8),
+        s((cap,), jnp.float32), s((cap,), jnp.float32),
+        s((cap,), jnp.float32), s((cap,), jnp.bool_), metric="cosine",
+        k=40, chunk=CHUNK).compile()
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < CHUNK * dims // 100, m
+    assert m.argument_size_in_bytes >= cap * dims
+    _fits(compiled)
+
+
 def _graph_args(sharding):
     s = functools.partial(jax.ShapeDtypeStruct, sharding=sharding)
     levels, slots, m_upper = 3, N // 32, M0 // 2

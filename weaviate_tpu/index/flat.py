@@ -483,6 +483,12 @@ class QuantizedFlatIndex(VectorIndex):
     chunk (``ops/quantized.py``). Storage, fit policy, code search and the
     rescore tier all live in ``hnsw.backend.QuantizedBackend`` — this class
     is the VectorIndex adapter over it (same backend HNSW traversal uses).
+
+    Concurrent searches share one code scan: the backend's candidate stage
+    goes through a ``CoalescingDispatcher`` whose leader preps and scans
+    every compatible pending request as one block, and each request
+    rescores its own candidates on its own thread. The scan is bound by
+    reading the codes, so a batch costs about what one query costs.
     """
 
     def __init__(self, dims: int, config: FlatIndexConfig,
@@ -492,7 +498,67 @@ class QuantizedFlatIndex(VectorIndex):
         self.config = config
         self.metric = config.distance
         self.dims = dims
-        self.backend = QuantizedBackend(dims, config, raw_path=raw_path)
+        self.backend = QuantizedBackend(dims, config, raw_path=raw_path,
+                                        code_stage=self._coalesced_stage)
+        # bumped on every demote/promote: the dispatcher keys batch
+        # grouping on it, so a request enqueued against one residency
+        # generation never rides a batch of another
+        self._residency_epoch = 0
+        # ``quantizer.search`` applies one allow mask a batch: filtered
+        # requests share a scan only when their masks are equal
+        self._dispatcher = CoalescingDispatcher(
+            self._run_batch, max_batch=ROW_BUCKETS[-1])
+        # (code capacity, fetch) whose every row bucket is compiled;
+        # written by the dispatcher's leader only (single-flight)
+        self._warm_programs: set[tuple] = set()
+
+    def _coalesced_stage(self, host: np.ndarray, k: int, allow):
+        """The backend's candidate stage for this request's prepped rows,
+        run by whichever request leads the batch they join."""
+        ids, d = self._dispatcher.search(
+            host, k, allow,
+            tier_key=(self._residency_epoch, isolation_key()))
+        return d, ids
+
+    def _run_batch(self, queries: np.ndarray, k: int, allow):
+        """Single-flight batch runner behind the coalescing dispatcher:
+        one prep, one code scan and one copy-out for the whole group.
+        Returns (candidate ids, distances) [B, fetch] of the group's
+        rows; each member rescores its own."""
+        backend = self.backend
+        fetch = backend.flat_fetch(k)
+        if not backend.device_resident:
+            # a demotion landed while this group was queued: exact
+            # candidates from the host originals
+            d, ids = backend.host_topk(queries, fetch, allow)
+            return ids, d
+        program = (backend.codes.capacity, fetch)
+        if queries.shape[0] <= ROW_BUCKETS[-1] \
+                and program not in self._warm_programs:
+            self._warm_buckets(program, k)
+        d, ids = backend.code_candidates(
+            queries, k, allow, pad_to=_bucket_rows(queries.shape[0]))
+        return ids, d
+
+    def _warm_buckets(self, program: tuple, k: int) -> None:
+        """Compile every row bucket of one (capacity, fetch) at its first
+        search, never under whichever later batch is the first of its
+        size: zero queries through the batch's own stage."""
+        capacity, fetch = program
+        with TRACER.child("qflat.warm", capacity=capacity, fetch=fetch,
+                          buckets=list(ROW_BUCKETS)):
+            # child spans of the synthetic scans would read as requests'
+            token = tracing.detach()
+            try:
+                for b in ROW_BUCKETS:
+                    self.backend.code_candidates(
+                        np.zeros((b, self.dims), np.float32), k, None)
+            finally:
+                tracing.deactivate(token)
+        # programs of a capacity the codes have outgrown are never asked
+        # for again
+        self._warm_programs = {p for p in self._warm_programs
+                               if p[0] == capacity} | {program}
 
     @property
     def quantizer(self):
@@ -558,10 +624,16 @@ class QuantizedFlatIndex(VectorIndex):
         return self.backend.host_tier_bytes()
 
     def demote_device(self) -> int:
-        return self.backend.demote_device()
+        freed = self.backend.demote_device()
+        if freed:
+            self._residency_epoch += 1
+        return freed
 
     def promote_device(self) -> int:
-        return self.backend.promote_device()
+        gained = self.backend.promote_device()
+        if gained:
+            self._residency_epoch += 1
+        return gained
 
     def stats(self) -> dict:
         return {

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 from typing import Any, NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -387,7 +388,12 @@ class QuantizedBackend:
 
     quantized = True
 
-    def __init__(self, dims: int, config, raw_path: Optional[str] = None):
+    def __init__(self, dims: int, config, raw_path: Optional[str] = None,
+                 code_stage=None):
+        """``code_stage``: what ``flat_topk`` calls for a block's
+        candidates, in the signature of ``code_candidates`` (the
+        default); an owner that coalesces concurrent searches into one
+        scan hands in its own."""
         from weaviate_tpu.compression import (
             DeviceArraySet,
             HostVectorStore,
@@ -422,6 +428,7 @@ class QuantizedBackend:
             self.quantizer.fields(), capacity=config.initial_capacity,
             mesh=default_mesh(),
         )
+        self.code_stage = code_stage
 
     def _prep_vectors(self, vectors: np.ndarray) -> np.ndarray:
         v = np.asarray(vectors, np.float32)
@@ -598,11 +605,8 @@ class QuantizedBackend:
 
         if not self.codes.device_resident:
             return self.host_topk(queries, k, allow)
-        kind = self.quantizer.kind
-        # normalise, rotate / pack, and the query's upload
-        with TRACER.child("qflat.prepare", quantizer=kind):
-            qrep = self.prep_queries(queries)
-        if qrep.code is None:
+        host = self._prep_vectors(np.atleast_2d(queries))
+        if not self.quantizer.fitted:
             # pre-fit: exact over the (tiny) host corpus
             live = np.flatnonzero(self.originals.valid)
             if allow is not None:
@@ -610,39 +614,21 @@ class QuantizedBackend:
                 live = live[(live < len(al))]
                 live = live[al[live]]
             if len(live) == 0:
-                b = qrep.host.shape[0]
+                b = host.shape[0]
                 return (
                     np.full((b, k), _INF, np.float32),
                     np.full((b, k), -1, np.int64),
                 )
-            ids = np.broadcast_to(live[None, :], (qrep.host.shape[0], len(live)))
+            ids = np.broadcast_to(live[None, :], (host.shape[0], len(live)))
             res = exact_rescore(
-                qrep.host, ids, self.originals, self.metric, min(k, len(live))
+                host, ids, self.originals, self.metric, min(k, len(live))
             )
         else:
-            mask = self.codes.valid_mask
-            if allow is not None:
-                al = np.asarray(allow, bool)
-                if len(al) < self.codes.capacity:
-                    al = np.pad(al, (0, self.codes.capacity - len(al)))
-                mask = mask & jnp.asarray(al[: self.codes.capacity])
-            rescore_limit = getattr(self.quantizer.config, "rescore_limit", 0)
-            fetch = max(4 * k, rescore_limit, k)
-            chunk = self.config.search_chunk_size
-            # the code scan's launch up to its candidate ids on the host
-            with TRACER.child("qflat.scan", quantizer=kind, fetch=fetch,
-                              capacity=self.codes.capacity):
-                _, ids = self.quantizer.search(
-                    qrep.code, self.codes, fetch, mask,
-                    chunk if self.codes.capacity > chunk else 0,
-                )
-                # graftlint: allow[host-sync-in-hot-path] reason=candidate ids cross to the host rescore tier by design
-                ids = np.asarray(ids)
+            _, ids = (self.code_stage or self.code_candidates)(host, k, allow)
             # the originals' gather and their exact distances
-            with TRACER.child("qflat.rescore", quantizer=kind,
+            with TRACER.child("qflat.rescore", quantizer=self.quantizer.kind,
                               candidates=ids.shape[1]):
-                res = exact_rescore(
-                    qrep.host, ids, self.originals, self.metric, k)
+                res = exact_rescore(host, ids, self.originals, self.metric, k)
         d = res.dists.astype(np.float32).copy()
         ids = res.ids.astype(np.int64)
         d[ids < 0] = _INF
@@ -651,6 +637,49 @@ class QuantizedBackend:
             ids = np.pad(ids, ((0, 0), (0, pad)), constant_values=-1)
             d = np.pad(d, ((0, 0), (0, pad)), constant_values=_INF)
         return d, ids
+
+    def flat_fetch(self, k: int) -> int:
+        """Candidates a query row of ``flat_topk`` fetches from code
+        space for the exact rescore."""
+        rescore_limit = getattr(self.quantizer.config, "rescore_limit", 0)
+        return max(4 * k, rescore_limit, k)
+
+    def code_candidates(self, host: np.ndarray, k: int,
+                        allow: Optional[np.ndarray],
+                        pad_to: Optional[int] = None
+                        ) -> tuple[np.ndarray, np.ndarray]:
+        """``flat_topk``'s candidate stage for prepped query rows: ONE
+        ``quantizer.prep`` of the whole block (zero rows added up to
+        ``pad_to``, a size a batch runner compiles for), one code scan
+        of the top ``flat_fetch(k)``, one copy out. Returns (code
+        distances, candidate ids) [B, fetch] on the host, padded rows
+        dropped."""
+        kind = self.quantizer.kind
+        n = host.shape[0]
+        # rotate / pack, and the block's upload
+        with TRACER.child("qflat.prepare", quantizer=kind):
+            if pad_to is not None and pad_to > n:
+                host = np.pad(host, ((0, pad_to - n), (0, 0)))
+            code = self.quantizer.prep(host)
+        mask = self.codes.valid_mask
+        if allow is not None:
+            al = np.asarray(allow, bool)
+            if len(al) < self.codes.capacity:
+                al = np.pad(al, (0, self.codes.capacity - len(al)))
+            mask = mask & jnp.asarray(al[: self.codes.capacity])
+        fetch = self.flat_fetch(k)
+        chunk = self.config.search_chunk_size
+        # the code scan's launch up to its candidates on the host
+        with TRACER.child("qflat.scan", quantizer=kind, fetch=fetch,
+                          capacity=self.codes.capacity, batch=n):
+            d, ids = self.quantizer.search(
+                code, self.codes, fetch, mask,
+                chunk if self.codes.capacity > chunk else 0,
+            )
+            # the candidates cross to the host rescore tier: one copy out
+            # of both arrays
+            d, ids = jax.device_get((d, ids))
+        return d[:n], ids[:n]
 
     def rescore_topk(
         self, queries: np.ndarray, cand_ids: np.ndarray, cand_d: np.ndarray, k: int
